@@ -37,16 +37,10 @@ def _rank_divergent_buckets(grads):
 
 
 def shardcheck_entry():
-    from tpu_dist.parallel import mesh as mesh_lib
-
     devices = jax.devices()[:2]
     mesh = Mesh(devices, (AXIS,))
-    shard_map = mesh_lib.get_shard_map()
     kw = dict(mesh=mesh, in_specs=({"w": P(), "b": P()},),
               out_specs={"w": P(), "b": P()})
-    try:
-        mapped = shard_map(_rank_divergent_buckets, check_vma=False, **kw)
-    except TypeError:
-        mapped = shard_map(_rank_divergent_buckets, check_rep=False, **kw)
+    mapped = jax.shard_map(_rank_divergent_buckets, check_vma=False, **kw)
     grads = {"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}
     return mapped, (grads,)

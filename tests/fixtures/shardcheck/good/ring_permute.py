@@ -14,14 +14,8 @@ def _rotate(x):
 
 
 def shardcheck_entry():
-    from tpu_dist.parallel import mesh as mesh_lib
-
     devices = jax.devices()[:2]
     mesh = Mesh(devices, (AXIS,))
-    shard_map = mesh_lib.get_shard_map()
     kw = dict(mesh=mesh, in_specs=(P(),), out_specs=P())
-    try:
-        mapped = shard_map(_rotate, check_vma=False, **kw)
-    except TypeError:
-        mapped = shard_map(_rotate, check_rep=False, **kw)
+    mapped = jax.shard_map(_rotate, check_vma=False, **kw)
     return mapped, (jnp.ones((4,)),)

@@ -485,30 +485,30 @@ class TestReplicatedDeterminismGuard:
     is rejected, anything else warns."""
 
     def test_unseeded_shuffle_rejected(self):
-        from tpu_dist.data.distribute import check_replicated_determinism
+        from tpu_dist.data.distribute import require_replicated_determinism
 
         ds = _range_ds(32).shuffle(8).batch(4)
         with pytest.raises(ValueError, match="unseeded shuffle"):
-            check_replicated_determinism(ds, 1, 2, "AutoShardPolicy.DATA")
+            require_replicated_determinism(ds, 1, 2, "AutoShardPolicy.DATA")
 
     def test_seeded_shuffle_warns_only(self, caplog):
         import logging
 
-        from tpu_dist.data.distribute import check_replicated_determinism
+        from tpu_dist.data.distribute import require_replicated_determinism
 
         ds = _range_ds(32).shuffle(8, seed=5).batch(4)
         with caplog.at_level(logging.WARNING, logger="tpu_dist.data"):
-            check_replicated_determinism(ds, 1, 2, "AutoShardPolicy.DATA")
+            require_replicated_determinism(ds, 1, 2, "AutoShardPolicy.DATA")
         assert any("identical batches" in r.message for r in caplog.records)
 
     def test_spanning_data_axis_is_silent(self, caplog):
         import logging
 
-        from tpu_dist.data.distribute import check_replicated_determinism
+        from tpu_dist.data.distribute import require_replicated_determinism
 
         ds = _range_ds(32).shuffle(8).batch(4)  # unseeded is FINE here
         with caplog.at_level(logging.WARNING, logger="tpu_dist.data"):
-            check_replicated_determinism(ds, 2, 2, "AutoShardPolicy.OFF")
+            require_replicated_determinism(ds, 2, 2, "AutoShardPolicy.OFF")
         assert not caplog.records
 
     def test_sharded_path_guarded(self, eight_devices, monkeypatch):
@@ -543,12 +543,12 @@ class TestReplicatedDeterminismGuard:
         # code-review r5: shuffle(8, reshuffle_each_iteration=False) draws
         # its fixed seed independently PER PROCESS — just as divergent as
         # seed=None, and the spec records auto_seeded so the guard sees it.
-        from tpu_dist.data.distribute import check_replicated_determinism
+        from tpu_dist.data.distribute import require_replicated_determinism
 
         ds = _range_ds(32).shuffle(
             8, reshuffle_each_iteration=False).batch(4)
         with pytest.raises(ValueError, match="unseeded shuffle"):
-            check_replicated_determinism(ds, 1, 2, "AutoShardPolicy.OFF")
+            require_replicated_determinism(ds, 1, 2, "AutoShardPolicy.OFF")
 
     def test_shuffle_replays_through_file_autoshard(self):
         # code-review r5 regression: the auto_seeded record-only marker

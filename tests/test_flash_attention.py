@@ -119,6 +119,31 @@ def test_use_flash_env_off(monkeypatch):
     assert not fa.use_flash(x)
 
 
+@pytest.mark.parametrize("shape,reason", [
+    pytest.param((2, 4, 200, 64), "L=200 is not a multiple of 128",
+                 id="ragged-length"),
+    pytest.param((2, 256, 64), "rank 3", id="rank"),
+    pytest.param((1, 1, 1024, 8192), "VMEM budget", id="vmem"),
+])
+def test_decline_on_tpu_is_logged_once_with_shape_and_reason(
+        monkeypatch, caplog, shape, reason):
+    """A TPU backend that leaves the fused kernel says so — once per shape,
+    with the reason; off the TPU the dense path is the design and says
+    nothing."""
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    fa.log_declined.cache_clear()
+    with caplog.at_level("WARNING", logger=fa.logger.name):
+        assert not fa.use_flash(x)                    # CPU: silent
+        assert not caplog.records
+        monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+        assert not fa.use_flash(x)
+        assert not fa.use_flash(x)
+    (record,) = caplog.records
+    assert str(shape) in record.getMessage()
+    assert reason in record.getMessage()
+    assert reason in fa.decline_reason(x)
+
+
 def test_mha_layer_unchanged_on_cpu():
     """The default MHA path on CPU still routes to dense (use_flash False
     off-TPU), so existing layer numerics are untouched."""

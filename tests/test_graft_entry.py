@@ -1,9 +1,8 @@
 """Driver-seat tests for ``__graft_entry__``.
 
-Round 1 failed precisely here (MULTICHIP_r01.json: ok=false): the driver calls
-``dryrun_multichip(8)`` directly in a fresh process where JAX is already
-initialized with one real device — it does NOT go through the module's
-``__main__`` path. These tests reproduce that exact call pattern (fresh
+Round 1 failed precisely here: the driver calls ``dryrun_multichip(8)``
+directly in a fresh process where JAX is already initialized with one real
+device — it does NOT go through the module's ``__main__`` path. These tests reproduce that exact call pattern (fresh
 subprocess, plain import, direct call, no XLA_FLAGS pre-set) so the fix is
 pinned against regression.
 """

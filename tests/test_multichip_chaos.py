@@ -315,7 +315,6 @@ class TestReinitializeCollectives:
         body = f"""
 import numpy as np
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_dist.cluster import bootstrap
@@ -338,8 +337,8 @@ addr0 = coord_addr()
 
 assert jax.device_count() == _want, jax.device_count()
 mesh = Mesh(np.array(jax.devices()), ("d",))
-fn = jax.jit(shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                       in_specs=P("d"), out_specs=P(), check_rep=False))
+fn = jax.jit(jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                           in_specs=P("d"), out_specs=P(), check_vma=False))
 before = float(fn(jnp.arange(8.0))[0])
 
 gen1 = bootstrap.reinitialize(generation=gen0 + 1,

@@ -134,10 +134,6 @@ class TestCollectives:
         np.testing.assert_allclose(np.asarray(g_dist), g_ref, rtol=1e-5)
 
     def test_all_reduce_ops_under_shard_map(self, eight_devices):
-        from tpu_dist.parallel.mesh import get_shard_map
-
-        shard_map = get_shard_map()
-
         mesh = make_mesh()
         x = np.arange(8, dtype=np.float32)
 
@@ -148,8 +144,8 @@ class TestCollectives:
                 all_reduce(x, "data", ReduceOp.MAX),
             )
 
-        smap = shard_map(f, mesh=mesh, in_specs=PartitionSpec("data"),
-                         out_specs=PartitionSpec("data"))
+        smap = jax.shard_map(f, mesh=mesh, in_specs=PartitionSpec("data"),
+                             out_specs=PartitionSpec("data"))
         ssum, smean, smax = jax.jit(smap)(x)
         np.testing.assert_allclose(np.asarray(ssum), np.full(8, x.sum()))
         np.testing.assert_allclose(np.asarray(smean), np.full(8, x.mean()))
@@ -157,10 +153,6 @@ class TestCollectives:
 
     def test_mean_is_sum_div_group_size(self, eight_devices):
         # MEAN = SUM / group_size (tf:...cross_device_ops.py:1170-1180).
-        from tpu_dist.parallel.mesh import get_shard_map
-
-        shard_map = get_shard_map()
-
         mesh = make_mesh()
         x = np.random.RandomState(2).randn(8).astype(np.float32)
 
@@ -169,8 +161,8 @@ class TestCollectives:
             m = all_reduce(x, "data", ReduceOp.MEAN)
             return s / 8.0 - m
 
-        smap = shard_map(f, mesh=mesh, in_specs=PartitionSpec("data"),
-                         out_specs=PartitionSpec("data"))
+        smap = jax.shard_map(f, mesh=mesh, in_specs=PartitionSpec("data"),
+                             out_specs=PartitionSpec("data"))
         np.testing.assert_allclose(np.asarray(jax.jit(smap)(x)),
                                    np.zeros(8), atol=1e-6)
 

@@ -317,16 +317,10 @@ class TestCostModel:
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from tpu_dist.parallel import mesh as mesh_lib
-
         mesh = Mesh(jax.devices()[:4], ("data",))
-        shard_map = mesh_lib.get_shard_map()
         kw = dict(mesh=mesh, in_specs=(P("data"),) * n_in,
                   out_specs=P("data"))
-        try:
-            mapped = shard_map(body, check_vma=False, **kw)
-        except TypeError:
-            mapped = shard_map(body, check_rep=False, **kw)
+        mapped = jax.shard_map(body, check_vma=False, **kw)
         return jax.make_jaxpr(mapped)(
             *(jnp.ones((8, 4)) for _ in range(n_in)))
 

@@ -85,8 +85,6 @@ class TestBucketedAllReduce:
                                       bucket_bytes):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from tpu_dist.parallel.mesh import get_shard_map
-
         mesh = Mesh(np.array(eight_devices), ("data",))
         tree = {"w": jnp.arange(16.0).reshape(4, 4),
                 "b": jnp.arange(4.0) + 1.0}
@@ -98,15 +96,11 @@ class TestBucketedAllReduce:
         def fused(t):
             return collectives.all_reduce(t, "data", op)
 
-        shard_map = get_shard_map()
         kw = dict(mesh=mesh, in_specs=({"w": P(), "b": P()},),
                   out_specs={"w": P(), "b": P()})
         outs = []
         for fn in (bucketed, fused):
-            try:
-                mapped = shard_map(fn, check_vma=False, **kw)
-            except TypeError:
-                mapped = shard_map(fn, check_rep=False, **kw)
+            mapped = jax.shard_map(fn, check_vma=False, **kw)
             outs.append(jax.jit(mapped)(tree))
         for k in tree:
             np.testing.assert_allclose(outs[0][k], outs[1][k],
@@ -301,7 +295,6 @@ class TestLatencyCostModel:
         from jax.sharding import Mesh, PartitionSpec as P
 
         from tpu_dist.analysis import costmodel
-        from tpu_dist.parallel.mesh import get_shard_map
 
         mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
 
@@ -312,12 +305,8 @@ class TestLatencyCostModel:
             out, _ = jax.lax.scan(step, x, None, length=5)
             return out
 
-        shard_map = get_shard_map()
         kw = dict(mesh=mesh, in_specs=(P(),), out_specs=P())
-        try:
-            mapped = shard_map(body, check_vma=False, **kw)
-        except TypeError:
-            mapped = shard_map(body, check_rep=False, **kw)
+        mapped = jax.shard_map(body, check_vma=False, **kw)
         closed = jax.make_jaxpr(mapped)(jnp.zeros((4,)))
         report = costmodel.analyze_jaxpr(closed, entry="scan_probe")
         assert report.latency.launches == 5

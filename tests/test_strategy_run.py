@@ -15,18 +15,6 @@ import tpu_dist as td
 from tpu_dist.parallel.strategy import InputContext
 
 
-def _shard_map_lacks_vma() -> bool:
-    """True on jax versions whose shard_map predates the varying-manual-axes
-    (check_vma) rework — there, replication tracking stops at an inner
-    jax.grad and the implicit cotangent psum never happens."""
-    import inspect
-
-    from tpu_dist.parallel import mesh as mesh_lib
-
-    return "check_vma" not in inspect.signature(
-        mesh_lib.get_shard_map()).parameters
-
-
 class TestStrategyRun:
     def test_per_replica_loss_and_reduce(self, eight_devices):
         strategy = td.MirroredStrategy()
@@ -82,14 +70,6 @@ class TestStrategyRun:
         # Per-replica array outputs stack as [replicas, local_batch, ...].
         assert out["batch2"].shape == (8, 1, 2)
 
-    @pytest.mark.xfail(
-        condition=_shard_map_lacks_vma(), strict=True,
-        reason="jax < 0.5 shard_map rep-tracking does not extend into an "
-               "inner jax.grad: the transpose of the replicated-w broadcast "
-               "never inserts the implicit psum, so each replica returns "
-               "only its LOCAL gradient (verified empirically with both "
-               "check_rep settings). Fixed upstream by the varying-manual-"
-               "axes (check_vma) rework; see ROADMAP 'Known gaps'.")
     def test_gradient_step_matches_full_batch(self, eight_devices):
         # The canonical custom loop (TF guidance: scale per-replica loss by
         # 1/num_replicas, then all-reduce SUM). Here the all-reduce is

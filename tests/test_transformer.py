@@ -167,6 +167,38 @@ class TestTransformerLM:
         hist = model.fit(ds, epochs=4, steps_per_epoch=5, verbose=0)
         assert hist.history["accuracy"][-1] > 0.9, hist.history
 
+    def test_fit_outside_scope_traces_under_the_models_strategy(
+            self, eight_devices):
+        # The reference's own script compiles inside strategy.scope() and
+        # calls fit() outside it. Layers pick kernels from the ACTIVE mesh
+        # at trace time, so the trainer must put its strategy in scope:
+        # on a multi-chip TPU mesh an unscoped trace leaves the flash
+        # kernel unmapped, and the partitioner refuses a bare Mosaic call.
+        from tpu_dist.models.transformer import _dense_attention
+        from tpu_dist.parallel import get_strategy, has_strategy
+
+        seen = []
+
+        def attn(q, k, v, *, causal):
+            seen.append(get_strategy() if has_strategy() else None)
+            return _dense_attention(q, k, v, causal=causal,
+                                    scale=1.0 / math.sqrt(q.shape[-1]))
+
+        strategy = td.MirroredStrategy()
+        with strategy.scope():
+            model = build_transformer_lm(11, 16, d_model=32, depth=1,
+                                         num_heads=2, attention_fn=attn)
+            model.compile(
+                loss=td.ops.SparseCategoricalCrossentropy(from_logits=True),
+                optimizer="sgd")
+        x = (np.arange(8 * 16).reshape(8, 16) % 11).astype(np.int32)
+        ds = td.data.Dataset.from_tensor_slices((x, x)).batch(8)
+        assert not has_strategy()
+        model.fit(ds, epochs=1, steps_per_epoch=1, verbose=0)
+        model.evaluate(ds, steps=1, verbose=0)
+        model.predict(x)
+        assert len(seen) >= 3 and all(s is strategy for s in seen), seen
+
     def test_ring_attention_lm_trains_on_hybrid_mesh(self, eight_devices):
         # Combined data x sequence parallelism END TO END through fit():
         # batches shard over 'data' (2 replicas), attention runs as a ring

@@ -30,7 +30,12 @@ Reference example, ported (tf_dist_example.py:1-59):
     model.fit(dataset, epochs=10, steps_per_epoch=20)
 """
 
-from tpu_dist import (cluster, data, models, observe, ops, parallel,
+from tpu_dist.utils import compile_cache as _compile_cache
+
+# Before anything can compile: see utils/compile_cache.py for the rule.
+_compile_cache.configure()
+
+from tpu_dist import (cluster, data, models, observe, ops, parallel,  # noqa: E402
                       training, utils)
 from tpu_dist.cluster import ClusterConfig, barrier, initialize, is_chief
 from tpu_dist.data import AutoShardPolicy, Dataset, Options

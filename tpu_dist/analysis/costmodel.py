@@ -20,8 +20,8 @@ traces the SC2xx pass already produces; nothing compiles, nothing runs.
 * the formula is the standard ring cost per device: all-reduce (psum/
   pmax/pmin) ``2*(P-1)/P``, all_gather ``(P-1)`` (of the per-shard
   input), reduce_scatter/all_to_all ``(P-1)/P``, ppermute ``1`` (one
-  neighbor send). ``pbroadcast``/``pvary`` are the replication-type casts
-  jax's check_rep/check_vma rewriter inserts — no bytes move — and cost 0;
+  neighbor send). ``pvary``/``pcast`` are the replication-type casts
+  jax's check_vma rewriter inserts — no bytes move — and cost 0;
 * the ``multiplier`` folds in control flow: a collective inside a
   ``lax.scan`` of length L launches L times; ``cond``/``switch`` branches
   are all counted (a deliberate conservative over-count — branch
@@ -49,10 +49,9 @@ import dataclasses
 import math
 from typing import Iterable, Mapping, Optional
 
-#: Replication-type casts, not communication: jax's check_rep (0.4.x,
-#: ``pbroadcast``) / check_vma (0.5+, ``pvary``/``pcast``) rewriters insert
-#: these to move values between replicated and device-varying types. Every
-#: device already holds the bytes; nothing crosses a link.
+#: Replication-type casts, not communication: jax's check_vma rewriter
+#: inserts these to move values between replicated and device-varying
+#: types. Every device already holds the bytes; nothing crosses a link.
 ZERO_COST_FRAGMENTS = ("pbroadcast", "pvary", "pcast")
 
 

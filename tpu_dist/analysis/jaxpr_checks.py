@@ -33,8 +33,8 @@ against rank B's f32[4,4] — hang or garbage), and ``ppermute``
 permutations invalid for the axis in effect (out-of-range index,
 duplicate source, duplicate destination — all trace fine today).
 
-Note on ``pbroadcast``/``pvary``: jax's check_rep (0.4.x) / check_vma
-(0.5+) rewriter inserts these replication-type casts into traced bodies,
+Note on ``pvary``/``pcast``: jax's check_vma rewriter inserts these
+replication-type casts into traced bodies,
 *including asymmetrically into cond branches whose values differ in
 replication only*. They move no bytes and launch nothing, so they are NOT
 collectives for any rule here — treating them as real traffic made SC201
@@ -346,14 +346,10 @@ def _pipe_mesh_or_none():
 
 
 def _shard_mapped(body, mesh, in_specs, out_specs):
-    from tpu_dist.parallel import mesh as mesh_lib
+    import jax
 
-    shard_map = mesh_lib.get_shard_map()
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return shard_map(body, check_vma=False, **kw)
-    except TypeError:  # pragma: no cover - older jax spells it check_rep
-        return shard_map(body, check_rep=False, **kw)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _trace_gpipe():

@@ -52,24 +52,13 @@ _GENERATION: Optional[int] = None
 GENERATION_ENV = "TPU_DIST_GANG_GENERATION"
 
 
-def _dist_init(**kwargs):
-    # jax < 0.5 has no heartbeat_timeout_seconds (or other newer)
-    # kwargs on jax.distributed.initialize; drop what this version
-    # doesn't accept rather than failing bring-up.
+def _dist_init(*, allow_live_backend: bool = False, **kwargs):
     global _DIST_PARAMS
-    import inspect
-
     import jax
+    from jax._src import distributed as _dist
+    from jax._src import xla_bridge
 
-    allow_live_backend = kwargs.pop("allow_live_backend", False)
-    sig = inspect.signature(jax.distributed.initialize)
-    try:
-        jax.distributed.initialize(**{
-            k: v for k, v in kwargs.items() if k in sig.parameters})
-    except RuntimeError as exc:
-        if (not allow_live_backend
-                or "before any JAX computations" not in str(exc)):
-            raise
+    if allow_live_backend and xla_bridge.backends_are_initialized():
         # Mid-process RE-dial: a gang-reform survivor has been computing
         # for epochs, so its backend is necessarily live, and the public
         # API refuses re-init categorically. The coordination service
@@ -78,14 +67,12 @@ def _dist_init(**kwargs):
         # sets ``allow_live_backend`` — a FIRST bring-up after
         # computations still fails loudly, since there the backend's
         # process/device view really would be stale.
-        from jax._src import distributed as _dist
-
-        state_sig = inspect.signature(_dist.global_state.initialize)
-        _dist.global_state.initialize(**{
-            k: v for k, v in kwargs.items() if k in state_sig.parameters})
+        _dist.global_state.initialize(**kwargs)
         logger.info(
             "tpu_dist: re-dialed coordination service at %s under a live "
             "backend", kwargs.get("coordinator_address"))
+    else:
+        jax.distributed.initialize(**kwargs)
     if kwargs.get("coordinator_address") and kwargs.get("num_processes"):
         _DIST_PARAMS = {k: kwargs.get(k) for k in
                         ("coordinator_address", "num_processes",

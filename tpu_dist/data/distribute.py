@@ -52,7 +52,7 @@ def _find_unseeded_shuffle(dataset) -> bool:
     return False
 
 
-def check_replicated_determinism(dataset, num_shards: int,
+def require_replicated_determinism(dataset, num_shards: int,
                                  num_processes: int, path: str) -> None:
     """Guard for meshes whose data axis does not span all processes.
 
@@ -112,7 +112,7 @@ class DistributedDataset:
             # Reference mode: full stream per worker, local batch as produced.
             self._local = dataset
             self._policy = AutoShardPolicy.OFF
-            check_replicated_determinism(
+            require_replicated_determinism(
                 dataset, self._num_shards, self._num_processes,
                 "AutoShardPolicy.OFF")
         else:
@@ -120,7 +120,7 @@ class DistributedDataset:
             # ADVICE r4: same-data-coordinate processes get the same shard
             # id, so the sharded stream they build must be deterministic too
             # — the hazard is not OFF-specific.
-            check_replicated_determinism(
+            require_replicated_determinism(
                 dataset, self._num_shards, self._num_processes,
                 f"AutoShardPolicy.{self._policy.name}")
             self._local = shard_dataset(

@@ -133,7 +133,7 @@ class Dataset:
         #: compiled step (trainer plumbing): lets a pipeline ship compact
         #: wire dtypes (uint8) and run normalization on device, where it
         #: fuses into the step for free (SURVEY hard-part #5; the H2D link
-        #: is the scarce resource, esp. on a tunneled runtime).
+        #: is the scarce resource).
         self._device_transform: Callable | None = None
 
     # -- constructors --------------------------------------------------------

@@ -162,19 +162,14 @@ def _mesh_mapped_flash(q, *, causal: bool, scale: float,
     if not fa.supported(shard):
         return None
 
-    shard_map = mesh_lib.get_shard_map()
     spec = P(d_axis, m_axis, None, None)
     body = functools.partial(fa.flash_attention, causal=causal, scale=scale,
                              interpret=interpret)
-    try:
-        # pallas_call's out_shape carries no varying-mesh-axes type, so the
-        # vma checker can't see through the custom call; the body is
-        # per-shard pure, which is exactly what disabling the check asserts.
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    # pallas_call's out_shape carries no varying-mesh-axes type, so the
+    # vma checker can't see through the custom call; the body is
+    # per-shard pure, which is exactly what disabling the check asserts.
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)
-    except TypeError:  # pragma: no cover - older jax spells it check_rep
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_rep=False)
 
 
 def _unwrapped_flash_safe() -> bool:
@@ -215,6 +210,10 @@ def _default_attention(q, k, v, *, causal: bool, scale: float):
             return mapped(q, k, v)
         if _unwrapped_flash_safe():
             return fa.flash_attention(q, k, v, causal=causal, scale=scale)
+        fa.log_declined(
+            tuple(q.shape), jnp.dtype(q.dtype).name,
+            "no data/model shard mapping applies on this mesh and the "
+            "unmapped kernel would be all-gathered")
     return _dense_attention(q, k, v, causal=causal, scale=scale)
 
 

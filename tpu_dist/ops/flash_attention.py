@@ -59,10 +59,13 @@ asserts fwd + grads match the dense reference).
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
 
 #: Tile-size candidates, largest first. Square [T, T] score tiles: the v5e
 #: sweep showed causal skipping needs TK <= TQ to bite, and MXU efficiency
@@ -94,8 +97,7 @@ def _compiler_params(interpret):
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    cp = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cp(vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _fits(g, t, ln, d, itemsize, n_score):
@@ -456,15 +458,35 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 from tpu_dist.ops.pallas_kernels import _on_tpu
 
 
-def supported(q) -> bool:
-    """Whether the fused kernel handles this shape: [B, H, L, D] with L a
-    tile multiple and the streamed operands within the VMEM budget."""
+def decline_reason(q) -> str | None:
+    """Why the fused kernel does not take this shape, or None when it does:
+    it handles [B, H, L, D] with L a tile multiple and the streamed
+    operands within the VMEM budget."""
     if q.ndim != 4:
-        return False
+        return f"rank {q.ndim}, the kernel takes [B, H, L, D]"
     b, h, ln, d = q.shape
+    if ln % _T_CANDIDATES[-1]:
+        return f"L={ln} is not a multiple of {_T_CANDIDATES[-1]}"
     isz = jnp.dtype(q.dtype).itemsize
-    return (_pick_layout(b * h, ln, d, isz, 2.5) is not None
-            and _pick_layout(b * h, ln, d, isz, 4.0) is not None)
+    if (_pick_layout(b * h, ln, d, isz, 2.5) is None
+            or _pick_layout(b * h, ln, d, isz, 4.0) is None):
+        return (f"no (head group, tile) layout of D={d} fits the "
+                f"{_VMEM_BUDGET >> 20} MiB VMEM budget")
+    return None
+
+
+def supported(q) -> bool:
+    """Whether the fused kernel handles this shape (see decline_reason)."""
+    return decline_reason(q) is None
+
+
+@functools.lru_cache(maxsize=None)
+def log_declined(shape: tuple, dtype: str, reason: str) -> None:
+    """Say ONCE per (shape, dtype, reason) that attention on a TPU left the
+    fused kernel — the cache is the once. Dense attention materializes the
+    [B, H, L, L] scores, so a silent switch reads as a slow chip."""
+    logger.warning("flash attention declined for %s %s on TPU: %s; "
+                   "running dense attention", dtype, shape, reason)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale: float,
@@ -526,7 +548,14 @@ def analytic_train_flops(batch: int, heads: int, seq_len: int,
 
 def use_flash(q) -> bool:
     """Dispatch predicate for the default attention path: fused kernel on
-    TPU for supported shapes unless TPU_DIST_FLASH=0 (A/B escape hatch)."""
-    if os.environ.get("TPU_DIST_FLASH", "").strip() == "0":
+    TPU for supported shapes unless TPU_DIST_FLASH=0 (A/B escape hatch).
+    A decline on TPU is logged once with the shape and the reason."""
+    if not _on_tpu():
         return False
-    return _on_tpu() and supported(q)
+    reason = ("TPU_DIST_FLASH=0"
+              if os.environ.get("TPU_DIST_FLASH", "").strip() == "0"
+              else decline_reason(q))
+    if reason is None:
+        return True
+    log_declined(tuple(q.shape), jnp.dtype(q.dtype).name, reason)
+    return False

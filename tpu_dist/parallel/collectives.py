@@ -29,6 +29,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+# The public jax.core alias was removed; mesh.manual_axes_state reads the
+# same private module.
+from jax._src.core import trace_state_clean
 
 logger = logging.getLogger("tpu_dist.collectives")
 
@@ -69,13 +72,8 @@ def fire_fault_hook(op: str) -> None:
     hook = _FAULT_HOOK
     if hook is None:
         return
-    try:
-        from jax.core import trace_state_clean
-
-        if not trace_state_clean():
-            return
-    except ImportError:  # pragma: no cover - older/newer jax layout
-        pass
+    if not trace_state_clean():
+        return
     hook(op)
 
 
@@ -128,14 +126,7 @@ def fire_observe_hook(op: str, tree: Any = None, *,
     hook = _OBSERVE_HOOK
     if hook is None:
         return
-    phase = "eager"
-    try:
-        from jax.core import trace_state_clean
-
-        if not trace_state_clean():
-            phase = "trace"
-    except ImportError:  # pragma: no cover - older/newer jax layout
-        pass
+    phase = "eager" if trace_state_clean() else "trace"
     leaves, nbytes = (0, 0) if tree is None else _tree_payload(tree)
     try:
         hook(op, phase=phase, leaves=leaves, nbytes=nbytes, seconds=seconds)

@@ -240,8 +240,6 @@ class MixtureOfExperts(Layer):
                 {"aux_loss": self.aux_loss_weight * aux})
 
     def _apply_sharded(self, params, state, x, mesh, strategy, groups):
-        from tpu_dist.parallel import mesh as mesh_lib
-
         data_axis = strategy.data_axis
         d_size = mesh.shape.get(data_axis, 1)
         p_size = mesh.shape[self.axis_name]
@@ -272,12 +270,8 @@ class MixtureOfExperts(Layer):
         param_specs = {"router": P(), "w1": espec, "b1": espec,
                        "w2": espec, "b2": espec}
         x_spec = P(batch_axes, *([None] * (len(lead) - 1 + 1)))
-        shard_map = mesh_lib.get_shard_map()
         kw = dict(mesh=mesh, in_specs=(param_specs, x_spec),
                   out_specs=(x_spec, P()))
-        try:
-            mapped = shard_map(body, check_vma=False, **kw)
-        except TypeError:  # pragma: no cover - older jax: check_rep
-            mapped = shard_map(body, check_rep=False, **kw)
+        mapped = jax.shard_map(body, check_vma=False, **kw)
         y, aux = mapped(params, x)
         return y, {"aux_loss": self.aux_loss_weight * aux}

@@ -29,17 +29,6 @@ import numpy as np
 from tpu_dist.parallel.axes import DATA_AXIS, MODEL_AXIS  # noqa: F401 - canonical home
 
 
-def get_shard_map():
-    """The shard_map entry point across jax generations (moved from
-    jax.experimental to the top level in jax 0.8) — one shim for every
-    call site."""
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def manual_axes_state(mesh) -> bool | None:
     """Whether any of ``mesh``'s axis names is already bound in the current
     trace (inside a shard_map over it, e.g. a model applied within

@@ -214,7 +214,6 @@ def make_1f1b_train_step(model, loss, *, strategy=None):
     """
     from tpu_dist.models.layers import apply_chain
     from tpu_dist.models.policy import compute_dtype
-    from tpu_dist.parallel import mesh as mesh_lib
     from tpu_dist.parallel.strategy import get_strategy
 
     strategy = strategy or get_strategy()
@@ -282,14 +281,10 @@ def make_1f1b_train_step(model, loss, *, strategy=None):
 
     stage_spec = P(pb.axis_name)
     x_spec = P(data_axis) if data_size > 1 else P()
-    shard_map = mesh_lib.get_shard_map()
     kw = dict(mesh=mesh,
               in_specs=(P(), stage_spec, P(), x_spec, x_spec),
               out_specs=(P(), P(), stage_spec, P()))
-    try:
-        mapped = shard_map(body, check_vma=False, **kw)
-    except TypeError:  # pragma: no cover - older jax spells it check_rep
-        mapped = shard_map(body, check_rep=False, **kw)
+    mapped = jax.shard_map(body, check_vma=False, **kw)
 
     def step(params, x, y):
         if (x.shape[0] % (data_size * m)) != 0:

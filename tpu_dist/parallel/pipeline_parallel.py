@@ -231,12 +231,10 @@ class PipelinedBlocks(Layer):
                 f, x, (stacked, keys) if rng is not None else stacked)
             return y, state
 
-        from tpu_dist.parallel import mesh as mesh_lib
         from tpu_dist.parallel.strategy import get_strategy
 
         strategy = get_strategy()
         data_axis = strategy.data_axis
-        shard_map = mesh_lib.get_shard_map()
         m = self.microbatches
 
         def body(stacked_local, x_local):
@@ -267,14 +265,8 @@ class PipelinedBlocks(Layer):
                              jnp.zeros((), outs.dtype))
             return jax.lax.psum(outs * keep, self.axis_name)
 
-        try:
-            mapped = shard_map(
-                body_and_bcast, mesh=mesh,
-                in_specs=(param_spec, x_spec), out_specs=x_spec,
-                check_vma=False)
-        except TypeError:  # pragma: no cover - older jax spells it check_rep
-            mapped = shard_map(
-                body_and_bcast, mesh=mesh,
-                in_specs=(param_spec, x_spec), out_specs=x_spec,
-                check_rep=False)
+        mapped = jax.shard_map(
+            body_and_bcast, mesh=mesh,
+            in_specs=(param_spec, x_spec), out_specs=x_spec,
+            check_vma=False)
         return mapped(stacked, x), state

@@ -62,16 +62,7 @@ def _online_merge(m, l, acc, scores, v):
 
 def _mark_varying(x, axes):
     """Mark ``x`` as device-varying over ``axes`` (shard_map type system)."""
-    try:
-        return jax.lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        pass
-    try:
-        return jax.lax.pvary(x, axes)
-    except AttributeError:
-        # jax without varying-type annotations (< 0.5, e.g. 0.4.37): the
-        # rep checker is disabled by the shard_map shim, so no mark needed.
-        return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 #: Within-shard K/V chunking threshold/size: shards longer than the
@@ -218,9 +209,6 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis_name: str = SEQ_AXIS,
     ``softmax(q k^T * scale [+ causal mask]) v`` on the gathered arrays —
     asserted by tests/test_sequence.py against the dense reference.
     """
-    from tpu_dist.parallel.mesh import get_shard_map
-
-    shard_map = get_shard_map()
     axis_size = mesh.shape[axis_name]
     # Self-attention contract (ADVICE r2): the causal kv_pos computation
     # derives K/V global positions from q's per-shard length, so a K/V with
@@ -244,8 +232,8 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis_name: str = SEQ_AXIS,
         _ring_attention_shard, axis_name=axis_name, axis_size=axis_size,
         varying_axes=varying, causal=causal, scale=scale,
         kv_chunk=kv_chunk)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)
 
 

@@ -301,9 +301,9 @@ class Strategy:
             # warn otherwise. Checked HERE only when the rebatch wrapper
             # below is about to hide the combinator chain — otherwise the
             # DistributedDataset OFF branch walks the same chain itself.
-            from tpu_dist.data.distribute import check_replicated_determinism
+            from tpu_dist.data.distribute import require_replicated_determinism
 
-            check_replicated_determinism(
+            require_replicated_determinism(
                 dataset, num_pipelines, jax.process_count(),
                 "distribute_datasets_from_function")
             from tpu_dist.data.pipeline import _concat_structure
@@ -434,8 +434,6 @@ class Strategy:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        shard_map = mesh_lib.get_shard_map()
-
         def body(*leaves):
             a, k = jax.tree.unflatten(treedef, leaves)
             out = fn(*a, **k)
@@ -444,8 +442,9 @@ class Strategy:
             # PerReplica-stack convention reduce() consumes.
             return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
-        return jax.jit(shard_map(body, mesh=self._mesh, in_specs=in_specs,
-                                 out_specs=P(self.data_axis)))
+        return jax.jit(jax.shard_map(body, mesh=self._mesh,
+                                     in_specs=in_specs,
+                                     out_specs=P(self.data_axis)))
 
     def reduce(self, op: ReduceOp | str, value):
         """Host-side reduction of per-replica values to single results,

@@ -223,7 +223,6 @@ def build_audit_checksum(mesh, leaf_shapes_dtypes, leaf_specs=None):
     exactly 0 bytes, replicated and sharded alike.
     """
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     names = tuple(mesh.axis_names)
@@ -240,9 +239,9 @@ def build_audit_checksum(mesh, leaf_shapes_dtypes, leaf_specs=None):
                 dtype=jnp.uint32))
         return jnp.stack(sums).reshape(1, n_leaves)
 
-    shmapped = shard_map(per_device, mesh=mesh,
-                         in_specs=tuple(leaf_specs),
-                         out_specs=P(names), check_rep=False)
+    shmapped = jax.shard_map(per_device, mesh=mesh,
+                             in_specs=tuple(leaf_specs),
+                             out_specs=P(names), check_vma=False)
     return jax.jit(shmapped)
 
 
