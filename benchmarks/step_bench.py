@@ -119,7 +119,6 @@ def _fit_run(*, bucket_bytes: int, prefetch: int, epochs: int, steps: int,
         "final_loss": float(h.history["loss"][-1]),
         "data_wait_sum_s": round(float(data_wait.get("sum", 0.0)), 6),
         "data_wait": data_wait,
-        "overlap": dists.get("step.overlap"),
         "comm_wait": dists.get("step.comm_wait_s"),
         "prefetch_hits": counters.get("data.prefetch.hits", 0),
         "prefetch_misses": counters.get("data.prefetch.misses", 0),

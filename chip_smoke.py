@@ -89,33 +89,8 @@ MESH_LOSS_RTOL = 2e-2
 REPO = pathlib.Path(__file__).resolve().parent
 
 
-class CompileMeter:
-    """What jax reports about compilation, summed since construction."""
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.requests = self.hits = 0
-        self.compile_s = 0.0
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def read(self):
-        return self.requests, self.hits, self.compile_s
-
-
 @contextlib.contextmanager
-def phase(name: str, meter: CompileMeter, report: dict):
+def phase(name: str, meter, report: dict):
     """Time one phase and print its line when it ends WELL; a phase that
     raises prints nothing here and takes the run down with it."""
     import jax
@@ -500,7 +475,7 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 1
         cfg = SIZES[args.size]
-        meter = CompileMeter()
+        meter = compile_cache.meter()
         print(json.dumps({"chip_smoke": vars(args), "device": device,
                           "compile_cache_dir": compile_cache.configure()}),
               flush=True)

@@ -261,6 +261,9 @@ class TestTelemetryCallback:
         timer = StepTimer(reg)
         timer.record_execution(steps=4, data_wait_s=0.4, dispatch_s=0.8,
                                device_block_s=1.2)
+        # The step's wall time is the epoch's over its steps: the host
+        # cannot see it per execution on a free-running device.
+        timer.record_epoch(wall_s=2.4, loss_wait_s=0.4)
         snap = reg.snapshot()
         assert snap["counters"]["step.count"] == 4
         assert snap["distributions"]["step.total_s"]["p50"] == pytest.approx(

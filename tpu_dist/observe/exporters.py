@@ -45,9 +45,13 @@ class JsonlExporter:
 
     def write(self, snapshot: dict, **stamp) -> dict:
         """Write one record: ``{"schema", "ts", **stamp, "metrics"}``.
-        Extra stamp fields (epoch=, rank=, kind=) label the record."""
+        Extra stamp fields (epoch=, rank=, kind=) label the record. The
+        span ring goes out once, with the ``kind="final"`` record: the
+        series is cumulative, and the ring is the bulk of a snapshot."""
         if self._fh is None:
             raise RuntimeError(f"exporter for {self.path} is closed")
+        if stamp.get("kind") != "final" and "spans" in snapshot:
+            snapshot = {k: v for k, v in snapshot.items() if k != "spans"}
         record = {"schema": SCHEMA, "ts": time.time(), **stamp,
                   "metrics": snapshot}
         self._fh.write(json.dumps(record) + "\n")

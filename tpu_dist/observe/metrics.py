@@ -24,6 +24,8 @@ the unit tests pin.
 
 from __future__ import annotations
 
+import collections
+import itertools
 import random
 import threading
 from typing import Optional
@@ -34,6 +36,14 @@ SNAPSHOT_QUANTILES = (0.5, 0.95, 0.99)
 #: Reservoir size: exact quantiles up to this many observations, uniform
 #: subsampling beyond it. 1024 doubles are 8 KiB per distribution.
 DEFAULT_RESERVOIR_SIZE = 1024
+
+#: Span records the registry keeps (the oldest is dropped first). A serving
+#: round leaves about eight and a request three, so this holds the last
+#: minute or so of a busy engine.
+SPAN_RING_SIZE = 4096
+
+#: Field order of one span record in the ring and in ``snapshot()["spans"]``.
+SPAN_FIELDS = ("id", "name", "start", "end", "parent", "ident")
 
 
 def quantile(sorted_values: list, q: float) -> float:
@@ -155,6 +165,9 @@ class MetricsRegistry:
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._distributions: dict[str, Distribution] = {}
+        self._spans: collections.deque = collections.deque(
+            maxlen=SPAN_RING_SIZE)
+        self._span_ids = itertools.count(1)
 
     def enable(self) -> None:
         self.enabled = True
@@ -180,12 +193,31 @@ class MetricsRegistry:
             self._distributions, name,
             lambda: Distribution(self, self._reservoir_size))
 
+    def next_span_id(self) -> int:
+        """Identity of a span about to open: what its children and its own
+        ring record name it by."""
+        return next(self._span_ids)
+
+    def record_span(self, name: str, start: float, end: float, *,
+                    parent: Optional[int] = None, ident=None,
+                    span_id: Optional[int] = None) -> None:
+        """Append one finished span to the ring: ``start``/``end`` on the
+        recorder's monotonic clock, ``parent`` the id of the span that
+        enclosed it, ``ident`` what the spans of one round or one request
+        share. A span's self time is its duration less what its children
+        cover."""
+        if self.enabled:
+            self._spans.append((
+                span_id if span_id is not None else next(self._span_ids),
+                name, start, end, parent, ident))
+
     def reset(self) -> None:
         """Drop every instrument (a fresh run's clean slate)."""
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
             self._distributions.clear()
+            self._spans.clear()
 
     def snapshot(self) -> dict:
         """Point-in-time JSON-ready view of every instrument."""
@@ -193,11 +225,13 @@ class MetricsRegistry:
             counters = dict(self._counters)
             gauges = dict(self._gauges)
             dists = dict(self._distributions)
+            spans = list(self._spans)
         return {
             "counters": {k: c.value for k, c in sorted(counters.items())},
             "gauges": {k: g.value for k, g in sorted(gauges.items())},
             "distributions": {k: d.snapshot()
                               for k, d in sorted(dists.items())},
+            "spans": [dict(zip(SPAN_FIELDS, rec)) for rec in spans],
         }
 
 
@@ -208,6 +242,16 @@ _default = MetricsRegistry(enabled=False)
 
 def get_registry() -> MetricsRegistry:
     return _default
+
+
+def install_registry(registry: MetricsRegistry) -> MetricsRegistry:
+    """Make ``registry`` the process default and return the one it
+    replaces. A ``Telemetry`` handed a registry of its own installs it for
+    its fit, so that the spans and counters of the program land where its
+    step timer reads them."""
+    global _default
+    previous, _default = _default, registry
+    return previous
 
 
 def enabled() -> bool:
@@ -238,3 +282,7 @@ def observe_value(name: str, v: float) -> None:
 
 def set_gauge(name: str, v: float) -> None:
     _default.gauge(name).set(v)
+
+
+def record_span(name: str, start: float, end: float, **fields) -> None:
+    _default.record_span(name, start, end, **fields)

@@ -4,19 +4,23 @@
 This is the glue between the passive :mod:`~tpu_dist.observe.metrics`
 registry and the places time is actually spent:
 
-* :class:`StepTimer` — the trainer's hot loop (training/trainer.py) splits
-  each compiled execution into **data-wait** (host input pipeline),
-  **dispatch** (host->device launch of the jitted program) and **device**
-  (blocking ``block_until_ready``) and records per-step means here. The
-  trainer finds the timer through :func:`active_step_timer` — a module
-  global, not a callback argument — so the hot loop pays one global read
-  when telemetry is off.
+* :class:`StepTimer` — the trainer's hot loop (training/trainer.py) wraps
+  each compiled execution's **fetch** (host input pipeline) and
+  **dispatch** (host->device launch of the jitted program) in
+  ``utils.profiler.span`` and hands the two durations here, with the time
+  the host really **waited** for the device: the bounded-dispatch wait
+  where the backend needs one, else nothing per execution — telemetry
+  adds no ``block_until_ready`` of its own, so the program it measures is
+  the program that runs without it. The trainer finds the timer through
+  :func:`active_step_timer` — a module global, not a callback argument —
+  so the hot loop pays one global read when telemetry is off.
 * :func:`registry_collective_hook` — plugs into the observe-hook seam in
   ``parallel/collectives.py`` (the sibling of the resilience fault hook)
   and turns every wrapper call into per-op counters (calls, payload
   bytes) and host-wall-time distributions.
 * :class:`Telemetry` — the built-in callback that arms all of the above
-  for one ``fit`` span, exchanges per-rank step times through
+  for one ``fit`` span, times the epoch-end wait for the loss, exchanges
+  per-rank step times (epoch wall time over steps) through
   ``collectives.host_all_gather`` at each epoch end, runs straggler
   detection on the chief, emits ``step_timing`` / ``straggler_detected``
   records into the resilience :mod:`~tpu_dist.resilience.events` log,
@@ -33,6 +37,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 from pathlib import Path
 from typing import Optional
 
@@ -65,12 +70,14 @@ def set_active_step_timer(timer: Optional["StepTimer"]):
 
 
 class StepTimer:
-    """Per-execution timing split, recorded as per-step means.
+    """Per-execution host timing, recorded as per-step means.
 
     One compiled execution covers ``steps`` train steps (1, or K under
     ``steps_per_execution``); the split is divided by ``steps`` before
     recording so the distributions are per-step regardless of K. Epoch
-    aggregates accumulate alongside for the straggler exchange.
+    aggregates accumulate alongside for the ``step_timing`` event. What
+    the host cannot see per execution — the step's wall time on a
+    free-running device — is taken once an epoch (:meth:`record_epoch`).
     """
 
     def __init__(self, registry: Optional[metrics_lib.MetricsRegistry] = None):
@@ -81,50 +88,48 @@ class StepTimer:
         self._data = r.distribution("step.data_wait_s")
         self._dispatch = r.distribution("step.dispatch_s")
         self._device = r.distribution("step.device_block_s")
-        # Overlap health of the step schedule: host-side collective wait
-        # (instrumented wrappers report it via comm_wait_s; in-program
-        # collectives are invisible to the host and land in device_block)
-        # and the fraction of execution wall time the device was actually
-        # busy — double-buffered input drives this toward 1.0 by taking
-        # data_wait out of the denominator's stall share.
-        self._comm = r.distribution("step.comm_wait_s")
-        self._overlap = r.distribution("step.overlap")
         self.reset_epoch()
 
     def reset_epoch(self) -> None:
         self.epoch_steps = 0
-        self.epoch_total_s = 0.0
+        self.epoch_wall_s = 0.0
         self.epoch_data_wait_s = 0.0
         self.epoch_dispatch_s = 0.0
         self.epoch_device_s = 0.0
-        self.epoch_comm_wait_s = 0.0
 
     def record_execution(self, *, steps: int, data_wait_s: float,
-                         dispatch_s: float, device_block_s: float,
-                         comm_wait_s: float = 0.0) -> None:
+                         dispatch_s: float,
+                         device_block_s: float = 0.0) -> None:
+        """One execution's fetch and dispatch (the two spans' durations)
+        and the time the host waited for the device inside the loop."""
         if steps <= 0:
             return
-        total = data_wait_s + dispatch_s + device_block_s
         per = 1.0 / steps
         self._count.inc(steps)
-        self._total.observe(total * per)
         self._data.observe(data_wait_s * per)
         self._dispatch.observe(dispatch_s * per)
-        self._device.observe(device_block_s * per)
-        self._comm.observe(comm_wait_s * per)
-        if total > 0:
-            self._overlap.observe(device_block_s / total)
+        if device_block_s:
+            self._device.observe(device_block_s * per)
         self.epoch_steps += steps
-        self.epoch_total_s += total
         self.epoch_data_wait_s += data_wait_s
         self.epoch_dispatch_s += dispatch_s
         self.epoch_device_s += device_block_s
-        self.epoch_comm_wait_s += comm_wait_s
+
+    def record_epoch(self, *, wall_s: float, loss_wait_s: float) -> None:
+        """The epoch as the host clock saw it: ``wall_s`` from its first
+        fetch to the arrival of its loss, of which ``loss_wait_s`` was the
+        wait for that loss (the device finishing what the host had
+        dispatched ahead)."""
+        self.epoch_wall_s = wall_s
+        self.epoch_device_s += loss_wait_s
+        if self.epoch_steps:
+            self._total.observe(wall_s / self.epoch_steps)
+            self._device.observe(loss_wait_s / self.epoch_steps)
 
     def epoch_mean_step_s(self) -> float:
         if self.epoch_steps == 0:
             return 0.0
-        return self.epoch_total_s / self.epoch_steps
+        return self.epoch_wall_s / self.epoch_steps
 
 
 def registry_collective_hook(
@@ -179,8 +184,10 @@ class Telemetry(Callback):
         self._exporter: Optional[exporters.JsonlExporter] = None
         self._prev_hook = None
         self._prev_timer = None
+        self._prev_registry = None
         self._was_enabled = False
         self._armed = False
+        self._t_epoch = 0.0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -190,6 +197,9 @@ class Telemetry(Callback):
         self._was_enabled = self.registry.enabled
         self.registry.reset()
         self.registry.enable()
+        # The program's spans and counters go to the default registry:
+        # for this fit that is the one the step timer reads.
+        self._prev_registry = metrics_lib.install_registry(self.registry)
         self._prev_hook = collectives.install_observe_hook(
             registry_collective_hook(self.registry))
         self.timer = StepTimer(self.registry)
@@ -206,6 +216,7 @@ class Telemetry(Callback):
         self._export(kind="final", epoch=None)
         collectives.install_observe_hook(self._prev_hook)
         set_active_step_timer(self._prev_timer)
+        metrics_lib.install_registry(self._prev_registry)
         if not self._was_enabled:
             self.registry.disable()
         if self._exporter is not None:
@@ -216,6 +227,7 @@ class Telemetry(Callback):
     def on_epoch_begin(self, epoch: int) -> None:
         if self.timer is not None:
             self.timer.reset_epoch()
+        self._t_epoch = time.perf_counter()
 
     # -- per-epoch aggregation -----------------------------------------------
 
@@ -229,14 +241,21 @@ class Telemetry(Callback):
 
         r = self.registry
         timer = self.timer
-        epoch_time = float(logs.get("epoch_time", 0.0) or 0.0)
-        if "loss" in logs:
-            r.gauge("epoch.last_loss").set(float(logs["loss"]))
-        r.gauge("epoch.last_time_s").set(epoch_time)
-        steps = timer.epoch_steps if timer is not None else 0
+        # The one wait telemetry makes, and one the fit makes anyway when
+        # anything reads the epoch's loss: until the device has finished
+        # what the host dispatched ahead. ``logs["epoch_time"]`` stops
+        # before it, so the epoch's wall time is taken here, from
+        # on_epoch_begin to the loss's arrival.
+        t_wait = time.perf_counter()
+        if hasattr(logs, "materialize"):
+            logs.materialize()
+        now = time.perf_counter()
+        epoch_time = now - self._t_epoch
+        timer.record_epoch(wall_s=epoch_time, loss_wait_s=now - t_wait)
+        steps = timer.epoch_steps
         if steps and epoch_time > 0:
             r.gauge("epoch.steps_per_s").set(steps / epoch_time)
-        mean_step = timer.epoch_mean_step_s() if timer is not None else 0.0
+        mean_step = timer.epoch_mean_step_s()
 
         # Cross-rank exchange of this epoch's mean step time. Runs through
         # the instrumented host collective, so even a single-process run
@@ -253,10 +272,9 @@ class Telemetry(Callback):
         events.maybe_log(
             "step_timing", rank=rank, epoch=epoch, steps=steps,
             mean_step_s=round(mean_step, 6),
-            data_wait_s=round(timer.epoch_data_wait_s, 6) if timer else 0.0,
-            dispatch_s=round(timer.epoch_dispatch_s, 6) if timer else 0.0,
-            device_s=round(timer.epoch_device_s, 6) if timer else 0.0,
-            comm_wait_s=round(timer.epoch_comm_wait_s, 6) if timer else 0.0)
+            data_wait_s=round(timer.epoch_data_wait_s, 6),
+            dispatch_s=round(timer.epoch_dispatch_s, 6),
+            device_s=round(timer.epoch_device_s, 6))
 
         from tpu_dist.cluster import bootstrap
 
@@ -272,6 +290,8 @@ class Telemetry(Callback):
         self._export(kind="epoch", epoch=epoch)
 
     def _export(self, *, kind: str, epoch: Optional[int]) -> None:
+        if self._exporter is None and self.prometheus_path is None:
+            return
         snapshot = self.registry.snapshot()
         stamp = {"kind": kind}
         if epoch is not None:

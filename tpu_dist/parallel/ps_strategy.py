@@ -188,11 +188,9 @@ class ParameterServerStrategy(Strategy):
         leaf checksums (transport-level SDC: a torn or bit-flipped
         snapshot must never train).
         """
-        from tpu_dist.observe import metrics
         from tpu_dist.training import integrity
 
-        t0 = time.perf_counter()
-        deadline = t0 + self.pull_timeout_s
+        deadline = time.perf_counter() + self.pull_timeout_s
         rank_key = str(self.rank)
         while True:
             loaded = self.psdir.load_published()
@@ -212,26 +210,18 @@ class ParameterServerStrategy(Strategy):
                     "silent) — is the server process alive?")
             time.sleep(0.002)
         integrity.verify_pull_checksums(arrays, manifest)
-        metrics.observe_value("ps.staleness", float(pending))
-        metrics.observe_value("ps.pull_s", time.perf_counter() - t0)
-        metrics.inc("ps.pulls")
         self._last_version = int(manifest["version"])
         params = arrays_to_tree(params_template, arrays)
         return params, self._last_version
 
     def push(self, grads: Any, *, loss: float) -> int:
         """Publish one gradient packet; returns this worker's push seq."""
-        from tpu_dist.observe import metrics
-
-        t0 = time.perf_counter()
         seq = self._pushed
         self.psdir.push_grad(
             tree_to_arrays(grads), rank=self.rank, seq=seq,
             meta={"base_version": self._last_version,
                   "loss": float(loss), "time": time.time()})
         self._pushed += 1
-        metrics.observe_value("ps.push_s", time.perf_counter() - t0)
-        metrics.inc("ps.pushes")
         return seq
 
     def heartbeat(self, *, step: int) -> None:
@@ -399,12 +389,8 @@ class PSServer:
         self.psdir.publish_params(
             arrays, version=self.applies, applied=self.applied_by_rank,
             checksums=integrity.host_leaf_checksums(arrays))
-        from tpu_dist.observe import metrics
-
-        metrics.set_gauge("ps.version", float(self.applies))
 
     def _checksum_epoch(self) -> None:
-        from tpu_dist.observe import metrics
         from tpu_dist.resilience import events
         from tpu_dist.training import integrity
 
@@ -418,7 +404,6 @@ class PSServer:
         })
         events.maybe_log("ps_checksum_epoch", applies=self.applies,
                          n_leaves=len(sums))
-        metrics.inc("ps.checksum_epochs")
 
     def _gc_grads(self) -> None:
         """Delete packets only once a PUBLISHED checkpoint covers their
@@ -476,7 +461,6 @@ class PSServer:
         self.applied_by_rank[rank] = self.applied_by_rank.get(rank, 0) + 1
         lag = max(0.0, time.time() - float(meta.get("time", time.time())))
         metrics.observe_value("ps.apply_lag", lag)
-        metrics.inc("ps.applies")
         # The apply log is the bit-exact replay contract: coordinates
         # only, never wall-clock (lag lives in the ps.apply_lag metric).
         self.psdir.append_apply_log({

@@ -35,6 +35,7 @@ from typing import Optional, Sequence
 from tpu_dist.cluster import bootstrap
 from tpu_dist.parallel import mesh as mesh_lib
 from tpu_dist.parallel.collectives import CollectiveCommunication, ReduceOp
+from tpu_dist.utils import profiler
 
 logger = logging.getLogger("tpu_dist.strategy")
 
@@ -187,9 +188,12 @@ class Strategy:
         specs = tensor.prune_indivisible(specs, tree, self._mesh)
         return tensor.shardings_from_specs(specs, self._mesh)
 
+    @profiler.spanned("strategy.place_variables")
     def place_variables(self, params, tree, *, broadcast: bool | None = None):
         """Place a variables tree with per-leaf shardings derived from the
-        params rules; the TP-aware generalization of :meth:`replicate`."""
+        params rules; the TP-aware generalization of :meth:`replicate`.
+        Every leaf makes a round trip through the host (a span of its
+        own: it is most of a large model's set-up)."""
         import jax
 
         if broadcast is None:

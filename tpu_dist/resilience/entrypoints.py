@@ -111,8 +111,8 @@ def install_sigterm_handler() -> None:
     """Arm the graceful-preemption seam (idempotent, main thread only).
 
     On SIGTERM: record the request (the ``PreemptionDrain`` callback stops
-    training at the next step boundary), count it
-    (``elastic.preemptions``), and start the drain watchdog — a daemon
+    training at the next step boundary), log it
+    (``preempt_requested``), and start the drain watchdog — a daemon
     timer that hard-exits the process if the drain outlives its deadline,
     so a wedged drain (a hung collective inside the final commit) cannot
     outstall the supervisor's own SIGKILL escalation."""
@@ -126,9 +126,6 @@ def install_sigterm_handler() -> None:
                 return  # duplicate notice; drain already underway
             _PREEMPT_REQUESTED_AT = time.monotonic()
         deadline = _drain_deadline_s()
-        from tpu_dist.observe import metrics as metrics_lib
-
-        metrics_lib.inc("elastic.preemptions")
         events.maybe_log("preempt_requested", deadline_s=deadline,
                          attempt=events.current_attempt())
         print(f"tpu_dist.resilience: SIGTERM received — draining at the "

@@ -589,14 +589,12 @@ class RejoinGate(Callback):
 
     def on_epoch_begin(self, epoch: int) -> None:
         from tpu_dist.cluster import bootstrap
-        from tpu_dist.observe import metrics as metrics_lib
 
         t0 = time.monotonic()
         ranks = bootstrap.epoch_rendezvous(
             self.directory, epoch=epoch, rank=self.rank, world=self.world,
             timeout_s=self.timeout_s)
         wait_s = time.monotonic() - t0
-        metrics_lib.observe_value("elastic.rejoin_wait_s", wait_s)
         log = events.log_from_env()
         if log is not None:
             log.append("rejoin_rendezvous", attempt=events.current_attempt(),

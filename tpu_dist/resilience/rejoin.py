@@ -100,7 +100,6 @@ class StepRejoinGate(Callback):
     def rendezvous(self, *, step: int, epoch: Optional[int] = None) -> None:
         """Meet the gang at ``step`` under the current generation."""
         from tpu_dist.cluster import bootstrap
-        from tpu_dist.observe import metrics as metrics_lib
 
         coord = (self.generation, step)
         if self._met_at == coord:
@@ -116,7 +115,6 @@ class StepRejoinGate(Callback):
             abort_check=self._check_reform)
         wait_s = time.monotonic() - t0
         self._met_at = coord
-        metrics_lib.observe_value("elastic.rejoin_wait_s", wait_s)
         log = events.log_from_env()
         if log is not None:
             log.append("rejoin_rendezvous", attempt=events.current_attempt(),

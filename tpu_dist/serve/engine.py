@@ -27,9 +27,22 @@ shardcheck SC103 guards this): ``serve.request.latency_s`` /
 ``serve.request.ttft_s`` / ``serve.batch.occupancy`` distributions (the
 registry's reservoir quantiles give p50/p95/p99 directly),
 ``serve.queue.depth`` / ``serve.ready`` gauges, and
-``serve.{requests.*,tokens.generated,decode.steps,prefills}`` counters.
-Arm ``$TPU_DIST_OBSERVE_DIR`` (or call ``metrics.enable()``) to record;
-disabled is free.
+``serve.{requests.*,tokens.generated,decode.steps,prefill.chunks}``
+counters. Arm ``$TPU_DIST_OBSERVE_DIR`` (or call ``metrics.enable()``) to
+record; disabled is free.
+
+A round of :meth:`ServeEngine.step` is one ``serve.step`` span
+(``utils.profiler.span``, ``ident`` = the round's number) whose children
+stand at the phase boundaries: ``serve.step.admit``, one
+``serve.step.prefill_chunk`` a chunk (with ``serve.step.first_token_wait``
+inside a last chunk), ``serve.step.decode_prep``, ``.decode_dispatch``,
+``.decode_wait``, ``.pick`` and ``.journal_flush``. Counters at the same
+boundaries: ``serve.step.rounds``, ``serve.upload.bytes`` and
+``serve.logits.bytes`` (what crosses to and from the device),
+``serve.programs.built``; and once a round ``serve.step.host_s``, the
+round's duration less its two waits for the device. With whole-prompt
+prefill (``prefill_chunk=0``) the prompt is one chunk and its span lies
+inside ``serve.step.admit``, whose self time is then the admission alone.
 
 Resilience (see ``serve/journal.py`` and README "Serving resilience"):
 an optional durable request journal makes a supervised restart replay
@@ -61,6 +74,7 @@ from tpu_dist.parallel.strategy import get_strategy
 from tpu_dist.serve import kv_cache, paging
 from tpu_dist.serve import journal as journal_lib
 from tpu_dist.serve.scheduler import DONE, SHED, Request, Scheduler
+from tpu_dist.utils import profiler
 
 logger = logging.getLogger(__name__)
 
@@ -216,6 +230,7 @@ class ServeEngine:
         mode and the bench's A/B control).
     """
 
+    @profiler.spanned("serve.engine.build")
     def __init__(self, model: Sequential, *, max_batch: int = 8,
                  max_len: Optional[int] = None,
                  buckets: Optional[tuple[int, ...]] = None,
@@ -359,6 +374,14 @@ class ServeEngine:
         self._tokens = np.zeros(self.max_batch, np.int32)
         self._lengths = np.zeros(self.max_batch, np.int32)
         self.finished: list[Request] = []
+        #: Rounds of step() so far: the ``ident`` of a round's spans.
+        self._round = 0
+        #: Seconds this round has waited for the device (decode_wait and
+        #: first_token_wait): what serve.step.host_s leaves out.
+        self._waited_s = 0.0
+        #: int8 prefill errors still on the device, read with the next
+        #: read-back that happens anyway (recording runs only).
+        self._pending_qerr: list = []
 
         # CPU XLA has no buffer donation — donating there only logs
         # warnings; on TPU the cache updates in place (no per-step copy).
@@ -493,11 +516,16 @@ class ServeEngine:
         directly (the exact pre-jobs path); under an active job scope the
         program lives in the pool's MeshRuntime cache, keyed by job,
         model, and engine generation."""
+        def build():
+            metrics.inc("serve.programs.built")
+            with profiler.span("serve.program.build", f"{kind}:{key}"):
+                return builder()
+
         if self._job is None:
-            return builder()
+            return build()
         return self._job.runtime.cached(
             self._job.program_key(self.model.name, self._serial, kind, key),
-            builder)
+            build)
 
     def _decode_fn(self, bucket: int):
         fn = self._decode_fns.get(bucket)
@@ -673,7 +701,6 @@ class ServeEngine:
         if req.generated and req.replays + 1 > self.retry_budget:
             return self._shed(req, "retry_budget", journaled=True)
         self.scheduler.submit(req, now=self.clock(), rid=req.rid)
-        metrics.inc("serve.requests.adopted")
         return req
 
     # -- overload protection --------------------------------------------------
@@ -802,16 +829,39 @@ class ServeEngine:
 
     def _unpack_prefill(self, out):
         """Unpack a paged-prefill result: int8 pools return a third
-        element — the call's max-abs dequantization error — observed
-        host-side into the ``serve.kv.quant_error`` distribution (the
-        readback happens after the traced program, so shardcheck's
-        SC103 host-callback scan stays clean)."""
+        element — the call's max-abs dequantization error. It stays on
+        the device unless the registry records, and is then read with the
+        next read-back that happens anyway (:meth:`_to_host`), never with
+        one of its own."""
         if self._kv_quant:
             self.cache, logits, qerr = out
-            metrics.observe_value("serve.kv.quant_error", float(qerr))
+            if metrics.enabled():
+                self._pending_qerr.append(qerr)
         else:
             self.cache, logits = out
         return logits
+
+    def _to_host(self, logits, span_name: str) -> np.ndarray:
+        """The one place the host waits for the device: ``np.asarray`` of
+        a program's logits, under ``span_name``. Prefill errors parked by
+        :meth:`_unpack_prefill` ride along into ``serve.kv.quant_error``
+        (host-side, after the traced program: SC103-clean)."""
+        with profiler.span(span_name, self._round) as wait:
+            logits = np.asarray(logits)  # blocks until the device is done
+        self._waited_s += wait.seconds
+        if metrics.enabled():
+            metrics.inc("serve.logits.bytes", logits.nbytes)
+            for qerr in jax.device_get(self._pending_qerr):
+                metrics.observe_value("serve.kv.quant_error", float(qerr))
+        self._pending_qerr.clear()
+        return logits
+
+    def _upload(self, *arrays) -> list:
+        """Host arrays to the device, counted in ``serve.upload.bytes``."""
+        if metrics.enabled():
+            metrics.inc("serve.upload.bytes",
+                        sum(a.nbytes for a in arrays))
+        return [jnp.asarray(a) for a in arrays]
 
     def _prefill(self, req: Request) -> None:
         # A journal-recovered request re-prefills with prompt + everything
@@ -832,31 +882,39 @@ class ServeEngine:
             for src, dst in setup.copies:
                 self.cache = self._copy_fn(self.cache, jnp.int32(src),
                                            jnp.int32(dst))
-            suffix = plen - setup.start
-            pad = _pad_to_pow2(suffix, hi=self.max_len)
-            tokens = np.zeros(pad, np.int32)
-            tokens[:suffix] = seq[setup.start:]
-            fn = self._paged_prefill_fn(pad)
-            row = self._paging.allocator.table[req.slot]
-            out = fn(self.params, self.cache,
-                     jnp.asarray(row), jnp.asarray(tokens),
-                     jnp.int32(plen), jnp.int32(setup.start))
-            logits = self._unpack_prefill(out)
-            self._paging.register_prefill(req.slot, req.prompt)
-        else:
-            pad = _pad_to_pow2(plen, hi=self.max_len)
-            tokens = np.zeros(pad, np.int32)
-            tokens[:plen] = seq
-            fn = self._prefill_fn(pad)
-            self.cache, logits = fn(self.params, self.cache,
-                                    jnp.asarray(tokens), jnp.int32(plen),
-                                    jnp.int32(req.slot))
-        req.prefill_pos = plen
-        metrics.inc("serve.prefills")
+        # The whole prompt is one chunk: the same span as a chunk's.
+        with profiler.span("serve.step.prefill_chunk", self._round):
+            if self.paged:
+                suffix = plen - setup.start
+                pad = _pad_to_pow2(suffix, hi=self.max_len)
+                tokens = np.zeros(pad, np.int32)
+                tokens[:suffix] = seq[setup.start:]
+                fn = self._paged_prefill_fn(pad)
+                row, toks = self._upload(
+                    self._paging.allocator.table[req.slot], tokens)
+                out = fn(self.params, self.cache, row, toks,
+                         jnp.int32(plen), jnp.int32(setup.start))
+                logits = self._unpack_prefill(out)
+                self._paging.register_prefill(req.slot, req.prompt)
+            else:
+                pad = _pad_to_pow2(plen, hi=self.max_len)
+                tokens = np.zeros(pad, np.int32)
+                tokens[:plen] = seq
+                fn = self._prefill_fn(pad)
+                self.cache, logits = fn(self.params, self.cache,
+                                        *self._upload(tokens),
+                                        jnp.int32(plen), jnp.int32(req.slot))
+            req.prefill_pos = plen
+            self._first_token(req, logits, plen)
+
+    def _first_token(self, req: Request, logits, plen: int) -> None:
+        """The end of a prefill: read the last position's logits back,
+        stamp, emit the first generated token."""
         # Materialize BEFORE stamping first-token time: jax dispatch is
         # async, so the pre-readback clock() under-reported TTFT against
         # any client-observed wall clock (the PR 12 wart).
-        token = self._pick(np.asarray(logits))
+        logits = self._to_host(logits, "serve.step.first_token_wait")
+        token = self._pick(logits)
         now = self.clock()
         done = self.scheduler.record_token(req, token, now=now)
         metrics.inc("serve.tokens.generated")
@@ -898,46 +956,38 @@ class ServeEngine:
         ``[prefill_pos, min(prefill_pos + prefill_chunk, plen))``. The
         final chunk yields the last valid position's logits — the first
         generated token — and moves the request into the decode set."""
-        seq = list(req.prompt) + list(req.generated)
-        plen = len(seq)
-        startpos = req.prefill_pos
-        end = min(startpos + self.prefill_chunk, plen)
-        valid = end - startpos
-        pad = _pad_to_pow2(valid, hi=self.prefill_chunk)
-        tokens = np.zeros(pad, np.int32)
-        tokens[:valid] = seq[startpos:end]
-        if self.paged:
-            self._paging.extend_prefill(req.slot, end)
-            fn = self._paged_prefill_fn(pad)
-            row = self._paging.allocator.table[req.slot]
-            out = fn(self.params, self.cache,
-                     jnp.asarray(row), jnp.asarray(tokens),
-                     jnp.int32(end), jnp.int32(startpos))
-            logits = self._unpack_prefill(out)
-        else:
-            fn = self._chunk_fn(pad)
-            self.cache, logits = fn(self.params, self.cache,
-                                    jnp.asarray(tokens), jnp.int32(end),
-                                    jnp.int32(req.slot),
-                                    jnp.int32(startpos))
-        req.prefill_pos = end
-        self._lengths[req.slot] = end
-        metrics.inc("serve.prefill.chunks")
-        if end < plen:
-            return  # more chunks owed; logits of a mid-chunk are unused
-        self.scheduler.dequeue_prefill(req)
-        if self.paged:
-            self._paging.register_prefill(req.slot, req.prompt)
-        metrics.inc("serve.prefills")
-        token = self._pick(np.asarray(logits))  # readback, then stamp
-        now = self.clock()
-        done = self.scheduler.record_token(req, token, now=now)
-        metrics.inc("serve.tokens.generated")
-        if self.journal is not None:
-            self.journal.record_token(req.rid, token)
-        self._tokens[req.slot] = token
-        if done or plen >= self.max_len:
-            self._retire(req, now=now, status=DONE)
+        with profiler.span("serve.step.prefill_chunk", self._round):
+            seq = list(req.prompt) + list(req.generated)
+            plen = len(seq)
+            startpos = req.prefill_pos
+            end = min(startpos + self.prefill_chunk, plen)
+            valid = end - startpos
+            pad = _pad_to_pow2(valid, hi=self.prefill_chunk)
+            tokens = np.zeros(pad, np.int32)
+            tokens[:valid] = seq[startpos:end]
+            if self.paged:
+                self._paging.extend_prefill(req.slot, end)
+                fn = self._paged_prefill_fn(pad)
+                row, toks = self._upload(
+                    self._paging.allocator.table[req.slot], tokens)
+                out = fn(self.params, self.cache, row, toks,
+                         jnp.int32(end), jnp.int32(startpos))
+                logits = self._unpack_prefill(out)
+            else:
+                fn = self._chunk_fn(pad)
+                self.cache, logits = fn(self.params, self.cache,
+                                        *self._upload(tokens),
+                                        jnp.int32(end), jnp.int32(req.slot),
+                                        jnp.int32(startpos))
+            req.prefill_pos = end
+            self._lengths[req.slot] = end
+            metrics.inc("serve.prefill.chunks")
+            if end < plen:
+                return  # more chunks owed; a mid-chunk's logits are unused
+            self.scheduler.dequeue_prefill(req)
+            if self.paged:
+                self._paging.register_prefill(req.slot, req.prompt)
+            self._first_token(req, logits, plen)
 
     def step(self) -> int:
         """One scheduling round: deadline evictions → admissions (each
@@ -949,19 +999,41 @@ class ServeEngine:
         the round, after the fault-injector seams, so an injected crash
         loses the unflushed tail and recovery must regenerate it (the
         harsher ordering for the parity gate)."""
-        now = self.clock()
-        for req, swap in self.scheduler.evict_deadline(now=now):
-            self._release_pages(req)
-            self._apply_swap(swap)
-            self.finished.append(req)
-            metrics.inc("serve.requests.evicted")
-            if self.journal is not None:
-                self.journal.record_finish(req)
+        self._round += 1
+        self._waited_s = 0.0
+        with profiler.span("serve.step", self._round) as whole:
+            active = self._round_body()
+        if metrics.enabled():
+            metrics.inc("serve.step.rounds")
+            # What the host did itself: the round less its waits for the
+            # device. The engine is synchronous, so the device idles for
+            # this long, except where a prefill chunk is still in flight.
+            metrics.observe_value("serve.step.host_s",
+                                  whole.seconds - self._waited_s)
+        return active
 
-        gate = self._admission_gate if self.paged else None
-        for req in self.scheduler.admit(gate=gate):
-            self._prefill(req)
-        metrics.set_gauge("serve.queue.depth", self.scheduler.queue_depth())
+    def _flush_journal(self) -> None:
+        if self.journal is not None:
+            with profiler.span("serve.step.journal_flush", self._round):
+                self.journal.flush()
+
+    def _round_body(self) -> int:
+        rnd = self._round
+        with profiler.span("serve.step.admit", rnd):
+            now = self.clock()
+            for req, swap in self.scheduler.evict_deadline(now=now):
+                self._release_pages(req)
+                self._apply_swap(swap)
+                self.finished.append(req)
+                metrics.inc("serve.requests.evicted")
+                if self.journal is not None:
+                    self.journal.record_finish(req)
+
+            gate = self._admission_gate if self.paged else None
+            for req in self.scheduler.admit(gate=gate, now=now):
+                self._prefill(req)
+            metrics.set_gauge("serve.queue.depth",
+                              self.scheduler.queue_depth())
 
         if self.prefill_chunk:
             # Interleave policy: at most ``prefill_interleave`` prefill
@@ -977,16 +1049,14 @@ class ServeEngine:
         if self.paged:
             self._paging.note_usage()
         if n == 0:
-            if self.journal is not None:
-                self.journal.flush()
+            self._flush_journal()
             return 0
         # Decode covers only fully-prefilled slots; a mid-chunk slot's
         # cursor excludes it until its last chunk lands (ready() is all
         # of active() when chunking is off).
         ready = self.scheduler.ready()
         if not ready:
-            if self.journal is not None:
-                self.journal.flush()
+            self._flush_journal()
             return n
         # Ragged mode decodes the whole slot capacity in one program —
         # the scheduler's pow2 bucket is never consulted, so occupancy
@@ -994,16 +1064,35 @@ class ServeEngine:
         bucket = (self.max_batch if self.paged and self.ragged
                   else self.scheduler.bucket())
         metrics.observe_value("serve.batch.occupancy", len(ready) / bucket)
-        if self.paged:
-            # Host-side page bookkeeping for this round's appends: cross
-            # a page boundary -> allocate the next page (covered by the
-            # admission reservation); tail page shared with the prefix
-            # cache -> copy-on-write it private before the scatter.
-            for req in ready:
-                for src, dst in self._paging.prepare_append(
-                        req.slot, int(self._lengths[req.slot])):
-                    self.cache = self._copy_fn(self.cache, jnp.int32(src),
-                                               jnp.int32(dst))
+        with profiler.span("serve.step.decode_prep", rnd):
+            if self.paged:
+                # Host-side page bookkeeping for this round's appends:
+                # cross a page boundary -> allocate the next page (covered
+                # by the admission reservation); tail page shared with the
+                # prefix cache -> copy-on-write it private before the
+                # scatter.
+                for req in ready:
+                    for src, dst in self._paging.prepare_append(
+                            req.slot, int(self._lengths[req.slot])):
+                        self.cache = self._copy_fn(
+                            self.cache, jnp.int32(src), jnp.int32(dst))
+            host = [self._tokens, self._lengths]
+            if self.paged:
+                fn = self._paged_decode_fn(bucket)
+                host.insert(0, self._paging.allocator.table)
+            else:
+                fn = self._decode_fn(bucket)
+            if self.paged and self.ragged:
+                # Per-slot active mask: only fully-prefilled decoding
+                # slots write to their real tail pages — empty slots AND
+                # slots mid-chunked-prefill (whose table rows hold real
+                # pages a stray decode write must not touch) route their
+                # garbage write to the scratch page inside the kernel.
+                active = np.zeros(self.max_batch, bool)
+                for req in ready:
+                    active[req.slot] = True
+                host.append(active)
+            args = self._upload(*host)
         t0 = self.clock()
         timer = None
         if self.stall_timeout_s is not None:
@@ -1014,34 +1103,13 @@ class ServeEngine:
             timer.daemon = True
             timer.start()
         try:
-            if self.paged and self.ragged:
-                # Per-slot active mask: only fully-prefilled decoding
-                # slots write to their real tail pages — empty slots AND
-                # slots mid-chunked-prefill (whose table rows hold real
-                # pages a stray decode write must not touch) route their
-                # garbage write to the scratch page inside the kernel.
-                active = np.zeros(self.max_batch, bool)
-                for req in ready:
-                    active[req.slot] = True
-                self.cache, logits = self._paged_decode_fn(bucket)(
-                    self.params, self.cache,
-                    jnp.asarray(self._paging.allocator.table),
-                    jnp.asarray(self._tokens), jnp.asarray(self._lengths),
-                    jnp.asarray(active))
-            elif self.paged:
-                self.cache, logits = self._paged_decode_fn(bucket)(
-                    self.params, self.cache,
-                    jnp.asarray(self._paging.allocator.table),
-                    jnp.asarray(self._tokens), jnp.asarray(self._lengths))
-            else:
-                self.cache, logits = self._decode_fn(bucket)(
-                    self.params, self.cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._lengths))
+            with profiler.span("serve.step.decode_dispatch", rnd):
+                self.cache, logits = fn(self.params, self.cache, *args)
             if self.fault_injector is not None:
                 # Inside the watchdog window on purpose: a decode_stall
                 # fault must look exactly like a hung runtime call.
                 self.fault_injector.on_decode()
-            logits = np.asarray(logits)  # blocks until the device is done
+            logits = self._to_host(logits, "serve.step.decode_wait")
         finally:
             if timer is not None:
                 timer.cancel()
@@ -1053,25 +1121,25 @@ class ServeEngine:
             self._step_ema_s = (dt if self._step_ema_s is None else
                                 _EMA_ALPHA * dt
                                 + (1.0 - _EMA_ALPHA) * self._step_ema_s)
-        now = self.clock()
-        completed = []
-        for req in ready:
-            token = self._pick(logits[req.slot])
-            self._lengths[req.slot] += 1
-            self._tokens[req.slot] = token
-            done = self.scheduler.record_token(req, token, now=now)
-            metrics.inc("serve.tokens.generated")
-            if self.journal is not None:
-                self.journal.record_token(req.rid, token)
-            if done or self._lengths[req.slot] >= self.max_len:
-                completed.append(req)
-        # Highest slot first: each swap moves the (untouched) last slot.
-        for req in sorted(completed, key=lambda r: r.slot, reverse=True):
-            self._retire(req, now=now, status=DONE)
+        with profiler.span("serve.step.pick", rnd):
+            now = self.clock()
+            completed = []
+            for req in ready:
+                token = self._pick(logits[req.slot])
+                self._lengths[req.slot] += 1
+                self._tokens[req.slot] = token
+                done = self.scheduler.record_token(req, token, now=now)
+                metrics.inc("serve.tokens.generated")
+                if self.journal is not None:
+                    self.journal.record_token(req.rid, token)
+                if done or self._lengths[req.slot] >= self.max_len:
+                    completed.append(req)
+            # Highest slot first: each swap moves the (untouched) last slot.
+            for req in sorted(completed, key=lambda r: r.slot, reverse=True):
+                self._retire(req, now=now, status=DONE)
         if self.fault_injector is not None:
             self.fault_injector.on_step_end(self._done_count)
-        if self.journal is not None:
-            self.journal.flush()
+        self._flush_journal()
         return self.scheduler.num_active
 
     def run_until_idle(self, *, max_steps: int = 100_000) -> list[Request]:

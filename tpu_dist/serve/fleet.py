@@ -592,7 +592,6 @@ class ServeFleet:
                 target = coldest
                 fr.route = "overridden"
                 self.route_counts["affinity_overridden"] += 1
-                metrics.inc("fleet.route.affinity_overridden")
             else:
                 fr.route = "affinity"
                 self.route_counts["affinity"] += 1
@@ -618,7 +617,6 @@ class ServeFleet:
                        "count": f.count, "at_index": self._submit_index}
                 self._storm_fired.append(rec)
                 events.maybe_log("fault_fired", **rec)
-                metrics.inc("fleet.router_storm.injected", f.count)
                 # Seeded chaff: short prompts, tiny budgets — load, not
                 # output. Deterministic per (seed, storm index).
                 import numpy as np

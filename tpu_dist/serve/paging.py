@@ -616,6 +616,8 @@ class PagedKVState:
     # -- telemetry ------------------------------------------------------------
 
     def note_usage(self) -> None:
+        if not metrics.enabled():
+            return  # the sums below cost a pass over the table a round
         metrics.set_gauge("serve.pages.in_use",
                           float(self.allocator.pages_in_use))
         metrics.set_gauge("serve.pages.free",

@@ -25,6 +25,15 @@ which queued requests enter and which active ones leave:
 
 Host-side and jax-free on purpose: scheduling decisions happen between
 compiled steps, never inside them.
+
+The scheduler owns a request's stamps, so it records what they divide
+(while the observe registry is enabled, else nothing):
+``serve.request.queue_wait_s`` (submit to admit) at admission,
+``serve.request.prefill_s`` (admit to first token) and ``serve.token.gap_s``
+(gap to the request's previous token) in :meth:`Scheduler.record_token`,
+and three span records a request — ``serve.request.queued``, ``.prefill``,
+``.decode`` — that share ``ident = rid``, on the clock the engine stamps
+with.
 """
 
 from __future__ import annotations
@@ -32,6 +41,8 @@ from __future__ import annotations
 import dataclasses
 import itertools
 from typing import Optional
+
+from tpu_dist.observe import metrics
 
 #: Request lifecycle states. SHED is terminal like DONE/EVICTED but
 #: mutually exclusive with both: a shed request was REJECTED at admission
@@ -54,7 +65,9 @@ class Request:
     generated: list = dataclasses.field(default_factory=list)
     slot: int = -1
     submit_s: float = 0.0
+    admit_s: Optional[float] = None  #: when the request took its slot
     first_token_s: Optional[float] = None
+    last_token_s: Optional[float] = None  #: read-back of the newest token
     finish_s: Optional[float] = None
     finish_reason: Optional[str] = None  #: eos | length | deadline | shed
     #: Why a SHED request was rejected: queue_full | projected_ttft |
@@ -177,10 +190,12 @@ class Scheduler:
 
     # -- admission ------------------------------------------------------------
 
-    def admit(self, *, gate=None) -> list[Request]:
+    def admit(self, *, gate=None,
+              now: Optional[float] = None) -> list[Request]:
         """Move queued requests into free slots (FIFO); returns the newly
         admitted requests, each with ``slot`` assigned — the engine owes
-        each one a prefill before the next decode step.
+        each one a prefill before the next decode step. ``now`` stamps
+        ``admit_s``, the end of the request's queue wait.
 
         ``gate`` (optional ``fn(req) -> bool``) is consulted before each
         admission and stops the round on the first False — the paged
@@ -197,6 +212,12 @@ class Scheduler:
             req = self.queue.pop(0)
             req.slot = self.num_active
             req.status = ACTIVE
+            req.admit_s = now
+            if now is not None and metrics.enabled():
+                metrics.observe_value("serve.request.queue_wait_s",
+                                      now - req.submit_s)
+                metrics.record_span("serve.request.queued", req.submit_s,
+                                    now, ident=req.rid)
             self.slots[req.slot] = req
             self.num_active += 1
             admitted.append(req)
@@ -258,6 +279,15 @@ class Scheduler:
         calls :meth:`finish`."""
         if req.first_token_s is None:
             req.first_token_s = now
+            if req.admit_s is not None and metrics.enabled():
+                metrics.observe_value("serve.request.prefill_s",
+                                      now - req.admit_s)
+                metrics.record_span("serve.request.prefill", req.admit_s,
+                                    now, ident=req.rid)
+        elif req.last_token_s is not None:
+            metrics.observe_value("serve.token.gap_s",
+                                  now - req.last_token_s)
+        req.last_token_s = now
         req.generated.append(int(token))
         if req.eos_id is not None and int(token) == req.eos_id:
             req.finish_reason = "eos"
@@ -283,6 +313,9 @@ class Scheduler:
             self.dequeue_prefill(req)
         req.status = status
         req.finish_s = now
+        if req.first_token_s is not None:
+            metrics.record_span("serve.request.decode", req.first_token_s,
+                                now, ident=req.rid)
         req.released_slot = slot
         req.slot = -1
         last = self.num_active - 1

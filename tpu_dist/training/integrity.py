@@ -89,7 +89,6 @@ import dataclasses
 import logging
 import math
 import os
-import time
 from typing import Any, Optional
 
 import jax
@@ -522,10 +521,8 @@ class IntegrityGuard:
 
     def _anomaly(self, kind: str, first_gstep: int, k: int,
                  **detail: Any) -> None:
-        from tpu_dist.observe import metrics as metrics_lib
         from tpu_dist.resilience import events
 
-        metrics_lib.inc("integrity.anomalies")
         events.maybe_log("integrity_anomaly", kind=kind, step=first_gstep,
                          window=k, attempt=events.current_attempt(), **detail)
         logger.warning("integrity anomaly %r at global step %d (+%d): %s",
@@ -560,7 +557,6 @@ class IntegrityGuard:
         mesh = getattr(self._strategy, "mesh", None)
         if mesh is None:
             return True
-        t0 = time.perf_counter()
         flat_with_paths = jax.tree_util.tree_flatten_with_path(params)[0]
         leaves = [leaf for _, leaf in flat_with_paths]
         specs = tuple(_leaf_audit_spec(leaf, mesh) for leaf in leaves)
@@ -577,10 +573,6 @@ class IntegrityGuard:
                                    for d in mesh.devices.flat]
         table = self._audit_fn(*leaves)
         rows = self._host_rows(table)
-        dt = time.perf_counter() - t0
-        from tpu_dist.observe import metrics as metrics_lib
-
-        metrics_lib.observe_value("integrity.audit_s", dt)
         # Bisection: name every (device, leaf) cell that deviates from its
         # SHARD GROUP's majority value. A group with no strict majority
         # (e.g. one corrupted member out of two) localizes the mismatch to

@@ -1102,7 +1102,6 @@ class Trainer:
         run re-initializes from the seed and replays from epoch 0 — exact
         for the epoch-keyed RNG + per-epoch-pass datasets of the demo
         paths."""
-        from tpu_dist.observe import metrics as metrics_lib
         from tpu_dist.resilience import events
         from tpu_dist.training import checkpoint as ckpt_lib
 
@@ -1125,7 +1124,6 @@ class Trainer:
         self._iterator = None
         self._close_prefetcher()
         guard.note_rollback(rb, restored)
-        metrics_lib.inc("integrity.rollbacks")
         events.maybe_log("integrity_rollback", kind=rb.kind, step=rb.gstep,
                          restored_step=restored, next_epoch=next_epoch,
                          attempt=events.current_attempt())
@@ -1209,7 +1207,6 @@ class Trainer:
         gate.rendezvous(step=next_epoch * steps_per_epoch, epoch=next_epoch)
         reform_s = _time.monotonic() - t_reform
 
-        metrics_lib.inc("elastic.gang_reforms")
         metrics_lib.observe_value("elastic.drain_s", drain_s)
         metrics_lib.observe_value("elastic.reform_s", reform_s)
         metrics_lib.observe_value("elastic.restore_s", restore_s)
@@ -1239,7 +1236,7 @@ class Trainer:
         monitor = getattr(self.strategy, "liveness_monitor", None)
         # Installed by a Telemetry callback's on_train_begin (which has
         # already run); None on uninstrumented fits — the hot loop then
-        # pays exactly one is-None check per execution.
+        # pays one is-None check and three null spans per execution.
         timer = active_step_timer()
         for epoch in range(initial_epoch, epochs):
             if monitor is not None:
@@ -1301,80 +1298,89 @@ class Trainer:
                     step_i += kk
                     executions += 1
                     continue
-                # Step-phase timing (tpu_dist.observe): data-wait ends at
-                # t_fetch, dispatch at the compiled call's return, device
-                # time is the block_until_ready below. perf_counter calls
-                # only when a Telemetry span is active.
-                t_exec0 = time.perf_counter() if timer is not None else 0.0
-                t_fetch = t_exec0
+                # Two spans an execution (utils.profiler.span; null objects
+                # unless something records): the fetch is the host input
+                # pipeline, the dispatch the compiled call until it returns.
                 with profiler.step_annotation(gstep0):
                     if kk == 1:
-                        if device_ds:
-                            xb, yb = dist.next_batch()
-                        elif k > 1:
-                            # Tail step of a multi-step run: stay on the HOST
-                            # iterator — switching kinds would recreate the
-                            # iterator mid-epoch and replay batches.
-                            hb = self._next_batch(dist, host=True)
-                            xb, yb = self.strategy.distribute_batch(hb)
-                        else:
-                            xb, yb = self._next_batch(dist)
-                        xb, yb = fire_batch_hook(gstep0, 1, xb, yb)
-                        rng = key_chunks[executions]
-                        if timer is not None:
-                            t_fetch = time.perf_counter()
-                        (loss, v["params"], v["state"], v["opt"], v["metrics"],
-                         loss_acc, health) = self._train_step(
-                            v["params"], v["state"], v["opt"], v["metrics"],
-                            loss_acc, xb, yb, rng)
+                        with profiler.span("train.exec.fetch",
+                                           gstep0) as fetch:
+                            if device_ds:
+                                xb, yb = dist.next_batch()
+                            elif k > 1:
+                                # Tail step of a multi-step run: stay on the
+                                # HOST iterator — switching kinds would
+                                # recreate the iterator mid-epoch and replay
+                                # batches.
+                                hb = self._next_batch(dist, host=True)
+                                xb, yb = self.strategy.distribute_batch(hb)
+                            else:
+                                xb, yb = self._next_batch(dist)
+                            xb, yb = fire_batch_hook(gstep0, 1, xb, yb)
+                            rng = key_chunks[executions]
+                        with profiler.span("train.exec.dispatch",
+                                           gstep0) as dispatch:
+                            (loss, v["params"], v["state"], v["opt"],
+                             v["metrics"], loss_acc,
+                             health) = self._train_step(
+                                v["params"], v["state"], v["opt"],
+                                v["metrics"], loss_acc, xb, yb, rng)
                     elif device_ds:
                         # Device-resident path: batches gathered ON device
                         # (index transfer only), one scanned dispatch.
-                        xb, yb = dist.next_stack(kk)
-                        xb, yb = fire_batch_hook(gstep0, kk, xb, yb)
-                        if timer is not None:
-                            t_fetch = time.perf_counter()
-                        (loss, v["params"], v["state"], v["opt"],
-                         v["metrics"], loss_acc, health) = self._multi_step(
-                            v["params"], v["state"], v["opt"],
-                            v["metrics"], loss_acc, xb, yb,
-                            key_chunks[executions])
-                    else:
-                        # steps_per_execution: stack kk host batches, ONE
-                        # dispatch runs the scanned step (SURVEY.md
-                        # hard-part #5). loss comes back as the kk-mean.
-                        batches = [self._next_batch(dist, host=True)
-                                   for _ in range(kk)]
-                        if timer is not None:
-                            # Host-iterator pulls are the data wait; the
-                            # stack/placement below is charged to dispatch.
-                            t_fetch = time.perf_counter()
-                        if len({b[0].shape for b in batches}) == 1:
-                            xs = np.stack([b[0] for b in batches])
-                            ys = np.stack([b[1] for b in batches])
-                            xb, yb = self.strategy.distribute_batch_stack(
-                                (xs, ys))
+                        with profiler.span("train.exec.fetch",
+                                           gstep0) as fetch:
+                            xb, yb = dist.next_stack(kk)
                             xb, yb = fire_batch_hook(gstep0, kk, xb, yb)
+                        with profiler.span("train.exec.dispatch",
+                                           gstep0) as dispatch:
                             (loss, v["params"], v["state"], v["opt"],
                              v["metrics"], loss_acc,
                              health) = self._multi_step(
                                 v["params"], v["state"], v["opt"],
                                 v["metrics"], loss_acc, xb, yb,
                                 key_chunks[executions])
-                        else:
-                            # Ragged batch in the window (drop_remainder=False
-                            # tail): un-stackable — run the collected batches
-                            # per-step instead of crashing.
-                            for j, hb in enumerate(batches):
-                                xb, yb = self.strategy.distribute_batch(hb)
-                                xb, yb = fire_batch_hook(gstep0 + j, 1,
-                                                         xb, yb)
+                    else:
+                        # steps_per_execution: stack kk host batches, ONE
+                        # dispatch runs the scanned step (SURVEY.md
+                        # hard-part #5). loss comes back as the kk-mean.
+                        # Host-iterator pulls are the fetch; the stack and
+                        # the placement are charged to the dispatch.
+                        with profiler.span("train.exec.fetch",
+                                           gstep0) as fetch:
+                            batches = [self._next_batch(dist, host=True)
+                                       for _ in range(kk)]
+                        with profiler.span("train.exec.dispatch",
+                                           gstep0) as dispatch:
+                            if len({b[0].shape for b in batches}) == 1:
+                                xs = np.stack([b[0] for b in batches])
+                                ys = np.stack([b[1] for b in batches])
+                                xb, yb = (
+                                    self.strategy.distribute_batch_stack(
+                                        (xs, ys)))
+                                xb, yb = fire_batch_hook(gstep0, kk, xb, yb)
                                 (loss, v["params"], v["state"], v["opt"],
                                  v["metrics"], loss_acc,
-                                 health) = self._train_step(
+                                 health) = self._multi_step(
                                     v["params"], v["state"], v["opt"],
                                     v["metrics"], loss_acc, xb, yb,
-                                    key_chunks[executions][j])
+                                    key_chunks[executions])
+                            else:
+                                # Ragged batch in the window
+                                # (drop_remainder=False tail): un-stackable
+                                # — run the collected batches per-step
+                                # instead of crashing.
+                                for j, hb in enumerate(batches):
+                                    xb, yb = self.strategy.distribute_batch(
+                                        hb)
+                                    xb, yb = fire_batch_hook(gstep0 + j, 1,
+                                                             xb, yb)
+                                    (loss, v["params"], v["state"],
+                                     v["opt"], v["metrics"], loss_acc,
+                                     health) = self._train_step(
+                                        v["params"], v["state"], v["opt"],
+                                        v["metrics"], loss_acc, xb, yb,
+                                        key_chunks[executions][j])
                 step_i += kk
                 executions += 1
                 if guard is not None:
@@ -1382,17 +1388,19 @@ class Trainer:
                     # new vector's host copy starts now (non-blocking), the
                     # previous execution's — already in flight — is judged.
                     guard.on_execution(gstep0, kk, health, v["params"])
+                waited_s = 0.0
+                if bounded:
+                    t_wait = time.perf_counter()
+                    jax.block_until_ready(loss)
+                    waited_s = time.perf_counter() - t_wait
                 if timer is not None:
-                    # The blocking wait IS the device-time measurement; it
-                    # also satisfies the bounded-dispatch requirement.
-                    t_disp = time.perf_counter()
-                    jax.block_until_ready(loss)
+                    # Telemetry adds no wait of its own: the device time it
+                    # books is what the host waited anyway (here, and at
+                    # the epoch's end for the loss).
                     timer.record_execution(
-                        steps=kk, data_wait_s=t_fetch - t_exec0,
-                        dispatch_s=t_disp - t_fetch,
-                        device_block_s=time.perf_counter() - t_disp)
-                elif bounded:
-                    jax.block_until_ready(loss)
+                        steps=kk, data_wait_s=fetch.seconds,
+                        dispatch_s=dispatch.seconds,
+                        device_block_s=waited_s)
                 if eager_loss:
                     loss_val = float(loss)
                     loss_running += loss_val
@@ -1430,7 +1438,8 @@ class Trainer:
                 val_logs = self._evaluate_on(val_dist, steps=val_steps)
                 logs.absorb(val_logs, prefix="val_")
             bar.finish(logs)
-            cbs.on_epoch_end(epoch, logs)
+            with profiler.span("train.epoch.end", epoch):
+                cbs.on_epoch_end(epoch, logs)
 
     def evaluate(self, x, *, steps: Optional[int], verbose: int) -> dict:
         self.ensure_variables()
