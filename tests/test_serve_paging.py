@@ -216,7 +216,7 @@ class TestPagedKernelEquivalence:
         model = _lm()
         plan = kv_cache.build_plan(model)
         pool = kv_cache.init_page_pool(plan, num_pages=4, page_size=4)
-        pool = {k: v + np.arange(5)[None, :, None, None, None]
+        pool = {k: v + np.arange(5)[None, :, None, None]
                 for k, v in pool.items()}
         out = kv_cache.copy_page(pool, jnp.int32(3), jnp.int32(1))
         np.testing.assert_array_equal(np.asarray(out["k"][:, 1]),
@@ -481,8 +481,12 @@ class TestInt8KV:
                                        dtype=jnp.int8)
         assert pool["k"].dtype == jnp.int8
         assert pool["k_scale"].dtype == jnp.float32
-        assert pool["k_scale"].shape == pool["k"].shape[:-1]
-        assert pool["v_scale"].shape == pool["v"].shape[:-1]
+        # One fp32 scale per (layer, page, head, position); the payload
+        # keeps a position's heads side by side in one row.
+        layers, pages, ps, width = pool["k"].shape
+        assert width == plan.num_heads * plan.key_dim
+        assert pool["k_scale"].shape == (layers, pages, ps, plan.num_heads)
+        assert pool["v_scale"].shape == pool["k_scale"].shape
 
     def test_int8_streams_match_fp32_paged(self):
         model = _lm()
@@ -588,3 +592,240 @@ class TestRaggedDecode:
         _drive(engine, _workload(8, seed=11))
         assert engine.compiled_programs()["paged_decode"] == [4]
         assert fn._cache_size() == 1
+
+
+# -- the page-walking decode kernel (ops/paged_attention.py) -------------------
+
+WALK_PS, WALK_MAX_LEN, WALK_SLOTS = 8, 64, 4
+WALK_MAX_PAGES = WALK_MAX_LEN // WALK_PS
+
+
+def _walk_state(kv_dtype, lengths, *, shared_pages=0, retired=(), seed=0):
+    """A pool whose every page (scratch included) holds random K/V, page
+    tables of distinct pages per slot (the first ``shared_pages`` columns
+    of slots 0 and 1 the SAME pages: a shared prefix; a ``retired``
+    slot's row all scratch, as the allocator leaves it), and one token
+    per slot to decode at ``lengths``."""
+    model = _lm(seq_len=WALK_MAX_LEN)
+    plan = kv_cache.build_plan(model)
+    params = model.init(0)["params"]
+    rng = np.random.default_rng(seed)
+    num_pages = WALK_SLOTS * WALK_MAX_PAGES
+    pool = kv_cache.init_page_pool(plan, num_pages=num_pages,
+                                   page_size=WALK_PS, dtype=kv_dtype)
+    filled = {}
+    for name, a in pool.items():
+        if a.dtype == jnp.int8:
+            filled[name] = rng.integers(-127, 128, a.shape).astype(np.int8)
+        elif name.endswith("_scale"):
+            filled[name] = rng.uniform(0.002, 0.02, a.shape).astype(
+                np.float32)
+        else:
+            filled[name] = rng.normal(size=a.shape).astype(np.float32)
+    tables = rng.permutation(num_pages).reshape(
+        WALK_SLOTS, WALK_MAX_PAGES).astype(np.int32)
+    tables[1, :shared_pages] = tables[0, :shared_pages]
+    tables[list(retired)] = num_pages
+    tokens = rng.integers(1, VOCAB, WALK_SLOTS).astype(np.int32)
+    return (plan, params, {k: jnp.asarray(v) for k, v in filled.items()},
+            tables, tokens, np.asarray(lengths, np.int32))
+
+
+def _pages_equal(before, after, pages):
+    return all(np.array_equal(np.asarray(before[n])[:, pages],
+                              np.asarray(after[n])[:, pages])
+               for n in before)
+
+
+WALK_CASES = {
+    # lengths of the four slots; the first names the case.
+    "first_decode_of_an_empty_slot": dict(lengths=[0, 5, 17, 30]),
+    "at_a_page_boundary": dict(lengths=[WALK_PS, 3 * WALK_PS, 1, 9]),
+    "one_short_of_a_page_boundary": dict(
+        lengths=[WALK_PS - 1, 4 * WALK_PS - 1, 20, 2]),
+    "last_position": dict(lengths=[WALK_MAX_LEN - 1, WALK_MAX_LEN - 1, 0, 33]),
+    "shared_prefix_pages": dict(lengths=[2 * WALK_PS + 3, 2 * WALK_PS + 5,
+                                         7, 12], shared_pages=2),
+    "inactive_and_mid_prefill_slots": dict(
+        lengths=[19, 11, 26, 0], active=[True, False, True, False]),
+    "bucketed": dict(lengths=[13, WALK_PS, 40, 3], bucket=2),
+    # A request that ran to ``max_len`` leaves its length behind when it
+    # retires; a swap can park that slot inside the bucket, its table
+    # row all scratch. One key more than the row addresses.
+    "bucketed_with_a_retired_slot_at_max_len": dict(
+        lengths=[21, WALK_MAX_LEN, WALK_MAX_LEN, 6], bucket=4,
+        retired=(1, 2)),
+}
+
+
+class TestPageWalkingKernel:
+    @pytest.mark.parametrize("kv_dtype", [jnp.int8, jnp.float32],
+                             ids=["int8", "float32"])
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_kernel_under_the_interpreter_matches_the_xla_body(
+            self, case, kv_dtype):
+        spec = dict(WALK_CASES[case])
+        active = spec.pop("active", None)
+        bucket = spec.pop("bucket", None)
+        plan, params, pool, tables, tokens, lengths = _walk_state(
+            kv_dtype, **spec)
+        scratch = pool["k"].shape[1] - 1
+        if bucket is not None:
+            def decode(walk):
+                return kv_cache.paged_decode_step(
+                    plan, params, dict(pool), jnp.asarray(tables),
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    bucket=bucket, walk=walk)
+            live = ((np.arange(WALK_SLOTS) < bucket)
+                    & (tables[:, 0] != scratch))
+        else:
+            live = np.asarray(active if active is not None
+                              else [True] * WALK_SLOTS)
+
+            def decode(walk):
+                return kv_cache.paged_decode_ragged(
+                    plan, params, dict(pool), jnp.asarray(tables),
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(live), walk=walk)
+        # Off the TPU the kernel runs under the Pallas interpreter.
+        want_pool, want = decode(False)
+        got_pool, got = decode(True)
+        # fp32 round-off: the kernel sums in blocks and scales the
+        # scores, the XLA body scales the keys.
+        rows = live[:got.shape[0]]
+        np.testing.assert_allclose(np.asarray(got)[rows],
+                                   np.asarray(want)[rows],
+                                   rtol=2e-5, atol=2e-5)
+        assert np.all(np.isfinite(np.asarray(got)))
+        for name in pool:
+            assert np.all(np.isfinite(np.asarray(got_pool[name])))
+            # The scratch page absorbs the inactive slots' writes, which
+            # the two bodies make from different garbage.
+            a = np.asarray(got_pool[name])[:, :scratch]
+            b = np.asarray(want_pool[name])[:, :scratch]
+            if a.dtype == np.int8:
+                # A value that sat on a rounding boundary may land one
+                # step apart once layer 0's output differs by round-off.
+                assert np.abs(a.astype(np.int32) - b).max() <= 1
+            else:
+                np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        # Only the live slots' tail pages (and scratch) were written:
+        # an inactive or mid-prefill slot's real pages, and every page
+        # that is not a tail, keep their bytes.
+        tails = {int(tables[s, lengths[s] // WALK_PS])
+                 for s in range(WALK_SLOTS) if live[s]}
+        untouched = [p for p in range(scratch) if p not in tails]
+        assert _pages_equal(pool, got_pool, untouched)
+        for s in np.flatnonzero(~live):
+            assert _pages_equal(pool, got_pool,
+                                tables[s][tables[s] != scratch])
+
+    @pytest.mark.parametrize("kv_dtype,ragged", [
+        ("int8", True), (None, True), ("int8", False)],
+        ids=["int8-ragged", "float32-ragged", "int8-bucketed"])
+    def test_greedy_streams_are_token_identical_at_rehearsal_sizes(
+            self, kv_dtype, ragged):
+        """The chat cell's engine at its rehearsal sizes, decoding once
+        through the XLA body and once through the interpreted kernel."""
+        import functools
+
+        import jax
+
+        model = build_transformer_lm(512, 128, d_model=64, depth=2,
+                                     num_heads=4, ff_dim=256)
+        model.init(0)
+        rng = np.random.default_rng(7)
+        workload = [{"prompt": rng.integers(1, 512, int(n)).tolist(),
+                     "max_new_tokens": int(m)}
+                    for n, m in zip(rng.integers(8, 90, 8),
+                                    rng.integers(2, 16, 8))]
+
+        def engine():
+            return ServeEngine(model, max_batch=4, paged=True, ragged=ragged,
+                               kv_dtype=kv_dtype, page_size=16,
+                               prefill_chunk=32)
+
+        want = _drive(engine(), workload)
+        walked = engine()
+        if ragged:
+            walked._paged_decode_fns[4] = jax.jit(functools.partial(
+                kv_cache.paged_decode_ragged, walked.plan, walk=True))
+        else:
+            for bucket in (1, 2, 4):
+                walked._paged_decode_fns[bucket] = jax.jit(functools.partial(
+                    kv_cache.paged_decode_step, walked.plan, bucket=bucket,
+                    walk=True))
+        assert _drive(walked, workload) == want
+
+    def test_the_kernel_stops_at_the_row_and_masks_the_scale_rows(self):
+        """A retired slot's stale length counts one key more than its
+        table row addresses: the kernel stops at the row's capacity (no
+        table entry, page or scale row past it is read). And a masked
+        key's scale is never multiplied in: the scratch page's scale
+        rows may hold anything, and 0 * NaN is not 0."""
+        from tpu_dist.ops import paged_attention as pa
+
+        plan, _, pool, tables, _, _ = _walk_state(jnp.int8, [0] * WALK_SLOTS)
+        rng = np.random.default_rng(3)
+        q = jnp.asarray(rng.normal(size=(
+            WALK_SLOTS, plan.num_heads, plan.key_dim)).astype(np.float32))
+        held = np.array([WALK_MAX_LEN, 11, WALK_MAX_LEN, 1], np.int32)
+
+        def attend(n_keys, poison):
+            scales = []
+            for name in ("k", "v"):
+                sc = np.array(kv_cache._gather_scales(
+                    pool, name, 0, jnp.asarray(tables)))     # [b, H, S]
+                if poison:
+                    masked = (np.arange(WALK_MAX_LEN)[None, None, :]
+                              >= held[:, None, None])
+                    sc[np.broadcast_to(masked, sc.shape)] = np.nan
+                scales.append(jnp.asarray(sc))
+            return np.asarray(pa.paged_attention(
+                q, pool["k"], pool["v"], 0, jnp.asarray(tables),
+                jnp.asarray(n_keys), scales=tuple(scales)))
+
+        want = attend(held, poison=False)
+        past = held + (held == WALK_MAX_LEN)        # max_len + 1 keys
+        np.testing.assert_array_equal(attend(past, poison=False), want)
+        np.testing.assert_array_equal(attend(held, poison=True), want)
+
+    def test_the_compiled_kernel_takes_whole_tiles_only(self):
+        from tpu_dist.ops import paged_attention as pa
+
+        cell = jnp.zeros((1, 2, 16, 20 * 64), jnp.int8)   # the chat cell's
+        assert pa.pages_per_block(64, 16) == 8
+        assert pa.supported(cell, max_pages=64)
+        # A row narrower than a lane tile, a page shorter than a sublane
+        # tile, a table too short for a 128-key block: the XLA body.
+        assert not pa.supported(jnp.zeros((1, 2, 16, 32), jnp.int8), 64)
+        assert not pa.supported(jnp.zeros((1, 2, 4, 1280), jnp.int8), 64)
+        assert not pa.supported(cell, max_pages=4)
+        assert "key blocks of 64" in pa.decline_reason(cell, max_pages=4)
+        # Off the TPU the engine's programs never hold it.
+        assert not kv_cache.walks_pages({"k": cell}, 64)
+
+    def test_a_kernel_declined_on_the_tpu_is_said_once(self, monkeypatch,
+                                                       caplog):
+        """The XLA body reads the cache by capacity, sixty times slower
+        at the chat cell's sizes: a TPU run that lands on it says so,
+        once per pool and reason (the flash kernel's idiom)."""
+        import jax
+
+        from tpu_dist.ops import paged_attention as pa
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        pa.log_declined.cache_clear()
+        cell = {"k": jnp.zeros((1, 2, 16, 20 * 64), jnp.int8)}
+        narrow = {"k": jnp.zeros((1, 2, 16, 32), jnp.int8)}
+        with caplog.at_level("WARNING", logger=pa.logger.name):
+            assert kv_cache.walks_pages(cell, 64)
+            assert not caplog.records
+            for _ in range(3):
+                assert not kv_cache.walks_pages(narrow, 64)
+            assert not kv_cache.walks_pages(cell, 64, devices=4)
+        said = [r.getMessage() for r in caplog.records]
+        assert len(said) == 2
+        assert "(1, 2, 16, 32)" in said[0] and "multiple of 128" in said[0]
+        assert "4 devices" in said[1]
+        pa.log_declined.cache_clear()

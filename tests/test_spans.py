@@ -264,6 +264,41 @@ def test_int8_prefill_error_is_read_only_while_recording(chat_engine):
             == snap["counters"]["serve.prefill.chunks"])
 
 
+@pytest.mark.parametrize("walks", [False, True], ids=["xla-body", "kernel"])
+def test_decode_page_counters_count_what_the_lengths_say(
+        chat_engine, monkeypatch, walks):
+    """``serve.decode.pages_read`` over ``.pages_addressed``: the pages a
+    decode step's attention reads against those its table rows address.
+    Which body runs the engine decided when it made its pool (the
+    platform); here the test answers for it once the programs are built,
+    so the compiled program stays what it was."""
+    reg = metrics.get_registry()
+    reg.reset()
+    prompt, new = list(range(1, 15)), 6     # decodes at lengths 14 .. 18
+    assert chat_engine._walks_pages is False        # off the TPU
+    chat_engine.generate(prompt, max_new_tokens=new)
+    monkeypatch.setattr(chat_engine, "_walks_pages", walks)
+    assert not any(k.startswith("serve.decode.pages")
+                   for k in reg.snapshot()["counters"])
+    metrics.enable()
+    try:
+        chat_engine.generate([p + 1 for p in prompt], max_new_tokens=new)
+        counters = reg.snapshot()["counters"]
+    finally:
+        metrics.disable()
+        reg.reset()
+    steps = new - 1                         # the prefill gives the first
+    page, slots = chat_engine.page_size, chat_engine.max_batch
+    max_pages = chat_engine._paging.allocator.table.shape[1]
+    addressed = steps * slots * max_pages
+    assert counters["serve.decode.steps"] == steps
+    assert counters["serve.decode.pages_addressed"] == addressed
+    read = sum((len(prompt) + i) // page + 1 for i in range(steps))
+    assert read == 1 + 1 + 2 + 2 + 2        # the tail crosses into page 2
+    assert counters["serve.decode.pages_read"] == (
+        read if walks else addressed)
+
+
 # -- the trainer --------------------------------------------------------------
 
 def _fit(callbacks):
@@ -324,7 +359,7 @@ NEW_METRICS = {
     "serve.gpt2-large.chat": (
         "serve_step_host_share", "serve_decode_prep_ms", "serve_pick_ms",
         "serve_token_gap_mean_ms", "serve_queue_wait_mean_ms",
-        "serve_prefill_mean_ms"),
+        "serve_prefill_mean_ms", "decode_pages_read_share"),
     "train.gpt2-medium.dp1": ("trainer_fetch_ms", "trainer_dispatch_ms"),
 }
 
@@ -368,3 +403,5 @@ def test_the_new_metric_reads_from_files_and_entries_alone(
     assert value > 0.0
     if name == "serve_step_host_share":
         assert value <= 100.0
+    if name == "decode_pages_read_share":
+        assert value == 100.0   # off the TPU the XLA body reads every row

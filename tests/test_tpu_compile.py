@@ -6,11 +6,14 @@ driver checks on). Interpret mode cannot see what this sees: unaligned
 tiles, a kernel's fast-memory budget, a Mosaic lowering jax no longer
 accepts. Nothing runs here — a pass is not a chip run.
 
-Shapes are ``chip_smoke.py``'s: GPT-2-small attention ``[8, 12, 1024, 64]``.
+Shapes are ``chip_smoke.py``'s: GPT-2-small attention ``[8, 12, 1024, 64]``,
+and the chat cell's paged decode program (``tpubench``: GPT-2 large, 32
+slots, 1024 int8 pages of 16, 64 pages a table row).
 """
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -99,3 +102,69 @@ def test_fused_adam_compiles(v5e_chip):
     text = _compiled_text(lambda p, g, m, v, s: fn(p, g, m, v, scale=s),
                           tree, tree, tree, tree, scale)
     assert "tpu_custom_call" in text
+
+
+# -- the chat cell's paged ragged decode program -------------------------------
+
+#: Pool-shaped ``copy`` instructions in the parent's (PR 25) compiled
+#: decode program at these shapes, all 36 layers: the int8 ``k`` and ``v``
+#: (755 MB each) re-laid out on the way in and on the way out, and the
+#: same for the two fp32 scale planes (47 MB each). At 2048 pages the
+#: parent's count was 294 and a decode step took 5.5 s (PERF.md).
+PARENT_POOL_COPIES = 8
+
+
+@pytest.mark.parametrize("depth", [
+    pytest.param(2, id="kernel-2-layers"),
+    pytest.param(36, id="pool-copies-36-layers")])
+def test_paged_ragged_decode_walks_pages_and_copies_no_pool(
+        v5e_chip, monkeypatch, depth):
+    from tpu_dist.models.policy import policy, set_policy
+    from tpu_dist.models.transformer import build_transformer_lm
+    from tpu_dist.ops import paged_attention
+    from tpu_dist.serve import kv_cache
+
+    slots, pages, page_size, max_pages = 32, 1024, 16, 64
+    # The kernel asks jax for the platform, and jax says "cpu" here:
+    # ask for it compiled, for the described chip.
+    monkeypatch.setattr(
+        paged_attention, "paged_attention",
+        functools.partial(paged_attention.paged_attention, interpret=False))
+    kv_cache._walked_attention.clear_cache()
+    before = policy()
+    set_policy("mixed_bfloat16")
+    try:
+        model = build_transformer_lm(50257, 1024, d_model=1280, depth=depth,
+                                     num_heads=20, ff_dim=5120)
+        plan = kv_cache.build_plan(model)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=v5e_chip), tree)
+
+        params = on_chip(jax.eval_shape(lambda: model.init(0))["params"])
+        pool = on_chip(jax.eval_shape(lambda: kv_cache.init_page_pool(
+            plan, num_pages=pages, page_size=page_size, dtype=jnp.int8)))
+        assert paged_attention.supported(pool["k"], max_pages)
+        row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=v5e_chip)
+        tables = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                      sharding=v5e_chip)
+        text = jax.jit(
+            functools.partial(kv_cache.paged_decode_ragged, plan, walk=True),
+            donate_argnums=(1,)).lower(
+                params, pool, tables, row(jnp.int32), row(jnp.int32),
+                row(jnp.bool_)).compile().as_text()
+    finally:
+        set_policy(before)
+        kv_cache._walked_attention.clear_cache()
+    # One kernel call a layer, and nothing dequantised by capacity.
+    assert text.count("tpu_custom_call") == depth
+    assert f"f32[{slots},20,{max_pages * page_size},64]" not in text
+    copies = re.findall(
+        r"= (?:s8|f32)\[%d,%d,[0-9,]+\]\S* copy\(" % (depth, pages + 1), text)
+    assert len(copies) <= PARENT_POOL_COPIES
+    # The int8 payload is never re-laid out: what stays are the scale
+    # planes' (S1 b).
+    assert not any(c.startswith("= s8") for c in copies)
+    assert len(copies) <= 4

@@ -596,7 +596,11 @@ def peak_live_bytes(jaxpr) -> int:
     peak = live
     for i, eqn in enumerate(eqns):
         inner = 0
-        for _, sub in _sub_jaxprs(eqn.params):
+        # A Pallas kernel's body lives in VMEM, semaphores and registers:
+        # its HBM is its operands and results, which are counted here.
+        subs = (() if eqn.primitive.name == "pallas_call"
+                else _sub_jaxprs(eqn.params))
+        for _, sub in subs:
             inner = max(inner,
                         peak_live_bytes(sub) - _boundary_bytes(sub))
         born = 0

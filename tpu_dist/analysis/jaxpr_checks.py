@@ -858,7 +858,7 @@ def _trace_serve_paged_decode_int8():
         params, pool, tables, tokens, lengths)
 
 
-def _trace_serve_paged_decode_ragged_int8():
+def _trace_serve_paged_decode_ragged_int8(walk=None):
     """``serve.kv_cache.paged_decode_ragged`` over an int8 pool — the
     two tentpole optimizations composed: one full-capacity masked decode
     program over quantized pages. The production configuration for
@@ -878,8 +878,18 @@ def _trace_serve_paged_decode_ragged_int8():
     active = jnp.ones((4,), bool)
     return jax.make_jaxpr(
         lambda p, c, tb, t, ln, a: kv_cache.paged_decode_ragged(
-            plan, p, c, tb, t, ln, a))(
+            plan, p, c, tb, t, ln, a, walk=walk))(
         params, pool, tables, tokens, lengths, active)
+
+
+def _trace_serve_paged_decode_ragged_walked():
+    """The same program with the attention the TPU runs: the
+    page-walking kernel (``ops/paged_attention.py``), traced with
+    ``walk=True`` so the ``pallas_call`` is in the jaxpr on any backend.
+    Its modeled peak HBM is what separates it from
+    ``serve.paged_decode_ragged_int8``: no gathered, dequantized
+    ``[slots, heads, max_len, key_dim]`` copy of the cache."""
+    return _trace_serve_paged_decode_ragged_int8(walk=True)
 
 
 def _trace_integrity_health_step():
@@ -1105,6 +1115,8 @@ ENTRY_POINTS = {
     "serve.paged_prefill_int8": _trace_serve_paged_prefill_int8,
     "serve.paged_decode_int8": _trace_serve_paged_decode_int8,
     "serve.paged_decode_ragged_int8": _trace_serve_paged_decode_ragged_int8,
+    "serve.paged_decode_ragged_walked":
+        _trace_serve_paged_decode_ragged_walked,
     "training.integrity.health_step": _trace_integrity_health_step,
     "training.integrity.audit_checksum": _trace_integrity_audit_checksum,
     "training.integrity.audit_checksum_sharded":

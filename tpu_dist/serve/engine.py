@@ -39,7 +39,10 @@ inside a last chunk), ``serve.step.decode_prep``, ``.decode_dispatch``,
 ``.decode_wait``, ``.pick`` and ``.journal_flush``. Counters at the same
 boundaries: ``serve.step.rounds``, ``serve.upload.bytes`` and
 ``serve.logits.bytes`` (what crosses to and from the device),
-``serve.programs.built``; and once a round ``serve.step.host_s``, the
+``serve.programs.built``, ``serve.decode.pages_read`` and
+``.pages_addressed`` (the pages a paged decode step's attention reads
+against those its table rows address); and once a round
+``serve.step.host_s``, the
 round's duration less its two waits for the device. With whole-prompt
 prefill (``prefill_chunk=0``) the prompt is one chunk and its span lies
 inside ``serve.step.admit``, whose self time is then the admission alone.
@@ -332,6 +335,11 @@ class ServeEngine:
                 self.plan, num_pages=self.num_pages,
                 page_size=self.page_size, dtype=cache_dtype,
                 budget_bytes=budget_bytes))
+            # Decided once, here: the decode programs are built with the
+            # answer and ``decode_prep`` counts pages by it.
+            self._walks_pages = kv_cache.walks_pages(
+                self.cache, max_pages,
+                devices=self.strategy.mesh.devices.size)
             # Per-position pool bytes, derived from the page layout so
             # int8's fp32 scale rows are priced in (for float dtypes this
             # is exactly 2 * L * H * dk * itemsize).
@@ -561,14 +569,16 @@ class ServeEngine:
                     "paged_decode_ragged", bucket,
                     lambda: jax.jit(
                         functools.partial(kv_cache.paged_decode_ragged,
-                                          self.plan),
+                                          self.plan,
+                                          walk=self._walks_pages),
                         donate_argnums=self._donate))
             else:
                 fn = self._acquire_program(
                     "paged_decode", bucket,
                     lambda: jax.jit(
                         functools.partial(kv_cache.paged_decode_step,
-                                          self.plan, bucket=bucket),
+                                          self.plan, bucket=bucket,
+                                          walk=self._walks_pages),
                         donate_argnums=self._donate))
             self._paged_decode_fns[bucket] = fn
         return fn
@@ -1079,7 +1089,20 @@ class ServeEngine:
             host = [self._tokens, self._lengths]
             if self.paged:
                 fn = self._paged_decode_fn(bucket)
-                host.insert(0, self._paging.allocator.table)
+                table = self._paging.allocator.table
+                host.insert(0, table)
+                if metrics.enabled():
+                    # Pages this step's attention reads against those
+                    # its table rows address: the kernel stops at each
+                    # ready slot's length, the XLA body gathers every
+                    # row of the bucket whole.
+                    addressed = bucket * table.shape[1]
+                    metrics.inc("serve.decode.pages_addressed", addressed)
+                    metrics.inc(
+                        "serve.decode.pages_read",
+                        sum(int(self._lengths[req.slot]) // self.page_size
+                            + 1 for req in ready)
+                        if self._walks_pages else addressed)
             else:
                 fn = self._decode_fn(bucket)
             if self.paged and self.ragged:

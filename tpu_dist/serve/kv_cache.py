@@ -53,6 +53,7 @@ from tpu_dist.models.transformer import (Embedding, LayerNormalization,
                                          MultiHeadAttention,
                                          PositionalEmbedding,
                                          _default_attention)
+from tpu_dist.ops import paged_attention
 
 # -- plan: a flat, servable description of the Sequential ---------------------
 
@@ -449,8 +450,15 @@ def swap_slots(cache: dict, i, j):
 #
 # The paged variant replaces the contiguous [layers, slots, heads, max_len,
 # key_dim] preallocation with a pool of fixed-size pages — [layers,
-# num_pages + 1, heads, page_size, key_dim] — addressed through a per-slot
-# page table of page indices (host-managed by serve/paging.py). Row
+# num_pages + 1, page_size, heads * key_dim] — addressed through a per-slot
+# page table of page indices (host-managed by serve/paging.py). A page is
+# position-major: one position's K (or V) of every head is one row, so a
+# page is one contiguous slab whose two minor dimensions fill whole TPU
+# tiles at serving widths. That is what lets the decode kernel
+# (ops/paged_attention.py) copy a page in one DMA, and what keeps XLA
+# from choosing one layout for the pool at the program's boundary and
+# another inside it (under [.., heads, page_size, key_dim] it re-laid the
+# whole pool out on the way in and out of every program). Row
 # ``num_pages`` is a reserved scratch page: every index a program might
 # compute for an invalid position (prompt padding, inactive decode slots
 # whose stale page-table rows could otherwise alias pages reallocated to
@@ -462,13 +470,15 @@ def swap_slots(cache: dict, i, j):
 #
 # int8 pool (``dtype=jnp.int8``): pages store K/V as int8 with fp32
 # per-page scale ROWS — ``k_scale``/``v_scale`` of ``[num_layers,
-# num_pages + 1, num_heads, page_size]``, one amax-derived symmetric
+# num_pages + 1, page_size, num_heads]``, one amax-derived symmetric
 # scale per written position per head. Quantization happens at write
 # time (prefill scatter, decode tail-append; ``copy_page`` clones the
-# scale rows along with the int8 payload through the same generic loop)
-# and dequantization is fused into the page gather, so the fp32
-# attention math downstream is byte-for-byte the float path on the
-# dequantized values. Scaling per POSITION rather than per whole page is
+# scale rows along with the int8 payload through the same generic loop).
+# The XLA bodies fuse dequantization into the page gather, so their fp32
+# attention math is byte-for-byte the float path on the dequantized
+# values; the decode kernel multiplies scores and probabilities by the
+# scale rows instead and never forms a dequantized value.
+# Scaling per POSITION rather than per whole page is
 # what makes quantization write-order independent: a position's stored
 # bytes depend only on its own K/V projection — never on what else
 # landed in the page before or after — so journal replay (one big
@@ -528,7 +538,7 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
                    dtype=jnp.float32,
                    budget_bytes: Optional[int] = None) -> dict:
     """Zeros page pool pytree: ``k``/``v`` of
-    ``[num_layers, num_pages + 1, num_heads, page_size, key_dim]`` —
+    ``[num_layers, num_pages + 1, page_size, num_heads * key_dim]`` —
     the extra row is the write-off scratch page.
 
     Like :func:`init_cache`, ``budget_bytes`` raises a loud sizing error
@@ -549,46 +559,76 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
                 f"{page_size} positions (plus the scratch page) but "
                 f"budget_bytes={budget_bytes} — the budget fits {fits} "
                 "page(s). Lower num_pages/page_size or raise the budget.")
-    shape = (plan.num_layers, num_pages + 1, plan.num_heads, page_size,
-             plan.key_dim)
+    shape = (plan.num_layers, num_pages + 1, page_size,
+             plan.num_heads * plan.key_dim)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if jnp.dtype(dtype) == jnp.int8:
-        # fp32 scale rows, one per (layer, page, head, position). Zero
+        # fp32 scale rows, one per (layer, page, position, head). Zero
         # pages decode to exact zeros under any scale; real scales are
         # written alongside every K/V write.
-        sshape = shape[:-1]
+        sshape = shape[:-1] + (plan.num_heads,)
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
         pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return pool
 
 
-def _gather_pages(pool_arr, layer_idx: int, page_rows):
-    """Flatten one layer's pages into position order.
+def _gather_pages(pool_arr, layer_idx: int, page_rows, num_heads: int):
+    """One layer's pages in position order, per head.
 
-    ``pool_arr``: ``[L, P, H, ps, dk]``; ``page_rows``: int32
+    ``pool_arr``: ``[L, P, ps, H * dk]``; ``page_rows``: int32
     ``[..., max_pages]`` page-table row(s). Returns
     ``[..., H, max_pages * ps, dk]`` where flattened index j holds
     absolute position j of that slot's sequence (table entries are
     position-ordered; unallocated entries point at scratch, whose
     garbage the caller's validity mask never admits).
     """
-    g = pool_arr[layer_idx][page_rows]     # [..., max_pages, H, ps, dk]
-    g = jnp.moveaxis(g, -3, -4)            # [..., H, max_pages, ps, dk]
-    *lead, h, mp, ps, dk = g.shape
-    return g.reshape(*lead, h, mp * ps, dk)
+    g = pool_arr[layer_idx][page_rows]     # [..., max_pages, ps, H * dk]
+    *lead, mp, ps, width = g.shape
+    g = g.reshape(*lead, mp * ps, num_heads, width // num_heads)
+    return jnp.moveaxis(g, -2, -3)         # [..., H, S, dk]
 
 
-def _gather_kv(pool: dict, name: str, layer_idx: int, page_rows):
+def _gather_scales(pool: dict, name: str, layer_idx: int, page_rows):
+    """``pool[name + "_scale"]``'s rows in position order:
+    ``[..., H, max_pages * ps]`` fp32."""
+    s = pool[name + "_scale"][layer_idx][page_rows]  # [..., mp, ps, H]
+    *lead, mp, ps, h = s.shape
+    return jnp.moveaxis(s.reshape(*lead, mp * ps, h), -1, -2)
+
+
+def _gather_kv(pool: dict, name: str, layer_idx: int, page_rows,
+               num_heads: int):
     """Position-ordered gather of ``pool[name]``, dequantized for int8
     pools (int8 payload × per-position fp32 scale row → fp32); float
     pools pass straight through :func:`_gather_pages`."""
-    g = _gather_pages(pool[name], layer_idx, page_rows)
+    g = _gather_pages(pool[name], layer_idx, page_rows, num_heads)
     if not _quantized(pool):
         return g
-    s = pool[name + "_scale"][layer_idx][page_rows]  # [..., mp, H, ps]
-    s = jnp.moveaxis(s, -2, -3)                      # [..., H, mp, ps]
-    *lead, h, mp, ps = s.shape
-    return g.astype(jnp.float32) * s.reshape(*lead, h, mp * ps)[..., None]
+    return (g.astype(jnp.float32)
+            * _gather_scales(pool, name, layer_idx, page_rows)[..., None])
+
+
+def _write_rows(pool: dict, layer_idx: int, pages, offsets, k, v):
+    """Write one position's K/V per row of ``k``/``v`` (``[n, H, dk]``)
+    at ``(pages[i], offsets[i])`` of layer ``layer_idx``, quantizing for
+    an int8 pool. Returns the per-row max-abs dequantization error
+    (``[n]`` fp32) for an int8 pool, else None."""
+    n = k.shape[0]
+    err = None
+    for name, new in (("k", k), ("v", v)):
+        if _quantized(pool):
+            rows = new.astype(jnp.float32)
+            qv, sc = _quant_rows(rows)                       # [n, H, dk], [n, H]
+            pool[name + "_scale"] = pool[name + "_scale"].at[
+                layer_idx, pages, offsets, :].set(sc)
+            e = jnp.max(jnp.abs(rows - qv.astype(jnp.float32)
+                                * sc[..., None]), axis=(1, 2))
+            err = e if err is None else jnp.maximum(err, e)
+        else:
+            qv = new.astype(pool[name].dtype)
+        pool[name] = pool[name].at[layer_idx, pages, offsets, :].set(
+            qv.reshape(n, -1))
+    return err
 
 
 def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
@@ -621,7 +661,7 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
       positions (fp32 scalar — the ``serve.kv.quant_error`` datum).
     """
     num_pages = pool["k"].shape[1] - 1     # last row is scratch
-    ps = pool["k"].shape[3]
+    ps = pool["k"].shape[2]
     max_pages = page_row.shape[0]
     pad = tokens.shape[0]
     x = tokens[None]                       # [1, pad]
@@ -653,26 +693,15 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
                 page_row[jnp.minimum(pos // ps, max_pages - 1)],
                 num_pages)                 # [pad]
             off = pos % ps
-            if _quantized(pool):
-                for name, new in (("k", k), ("v", v)):
-                    rows = jnp.moveaxis(                     # [pad, H, dk]
-                        new[0].astype(jnp.float32), 1, 0)
-                    qv, sc = _quant_rows(rows)
-                    pool[name] = pool[name].at[idx, pg, :, off, :].set(qv)
-                    pool[name + "_scale"] = \
-                        pool[name + "_scale"].at[idx, pg, :, off].set(sc)
-                    err = jnp.max(jnp.abs(
-                        rows - qv.astype(jnp.float32) * sc[..., None]),
-                        axis=(1, 2))                         # [pad]
-                    qerr = jnp.maximum(
-                        qerr, jnp.max(jnp.where(valid_q, err, 0.0)))
-            else:
-                dt = pool["k"].dtype
-                for name, new in (("k", k), ("v", v)):
-                    pool[name] = pool[name].at[idx, pg, :, off, :].set(
-                        jnp.moveaxis(new.astype(dt)[0], 1, 0))  # [pad, H, dk]
-            keys = _gather_kv(pool, "k", idx, page_row)  # [H, S, dk]
-            vals = _gather_kv(pool, "v", idx, page_row)
+            err = _write_rows(pool, idx, pg, off,
+                              jnp.moveaxis(k[0], 1, 0),      # [pad, H, dk]
+                              jnp.moveaxis(v[0], 1, 0))
+            if err is not None:
+                qerr = jnp.maximum(
+                    qerr, jnp.max(jnp.where(valid_q, err, 0.0)))
+            keys = _gather_kv(pool, "k", idx, page_row,
+                              plan.num_heads)               # [H, S, dk]
+            vals = _gather_kv(pool, "v", idx, page_row, plan.num_heads)
             scale = 1.0 / math.sqrt(layer.key_dim)
             s = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
                            keys.astype(jnp.float32)) * scale
@@ -695,21 +724,88 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
     return pool, last[0, 0]
 
 
+def _gathered_attention(plan: DecodePlan, pool: dict, layer_idx: int,
+                        tables, q, n_keys):
+    """The plain XLA body of paged decode attention: every slot's whole
+    table row gathered (and dequantized) into ``[b, H, S, dk]``, softmax
+    over all ``S`` positions under the validity mask. What runs off the
+    TPU, and the reference the kernel is held to."""
+    keys = _gather_kv(pool, "k", layer_idx, tables, plan.num_heads)
+    vals = _gather_kv(pool, "v", layer_idx, tables, plan.num_heads)
+    scale = 1.0 / math.sqrt(plan.key_dim)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   keys.astype(jnp.float32)) * scale
+    valid = jnp.arange(keys.shape[2])[None, :] < n_keys[:, None]  # [b, S]
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    prob = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", prob,
+                      vals.astype(jnp.float32)).astype(q.dtype)
+
+
+@jax.jit
+def _walked_attention(pool: dict, layer_idx, tables, q, n_keys):
+    """Paged decode attention through the page-walking kernel
+    (ops/paged_attention.py): only the pages a slot holds are read, and
+    an int8 pool is never dequantized into HBM. ``layer_idx`` is traced
+    and the function jitted, so a program's layers share one trace and
+    one lowering of it (the kernel's is the expensive one). Off the TPU
+    the kernel runs through the Pallas interpreter."""
+    scales = None
+    if _quantized(pool):
+        scales = (_gather_scales(pool, "k", layer_idx, tables),
+                  _gather_scales(pool, "v", layer_idx, tables))
+    out = paged_attention.paged_attention(
+        q[:, :, 0, :], pool["k"], pool["v"], layer_idx, tables, n_keys,
+        scales=scales)
+    return out[:, :, None, :]
+
+
+def walks_pages(pool: dict, max_pages: int, *, devices: int = 1) -> bool:
+    """Kernel or XLA body, from what the program's builder can see: the
+    platform, the pool's shapes, and the number of devices the program
+    spans (a Pallas call is opaque to the partitioner, and the kernel
+    has run on one chip only). On a TPU a declined kernel is said once,
+    with the shapes and the reason."""
+    if jax.default_backend() != "tpu":
+        return False
+    reason = paged_attention.decline_reason(pool["k"], max_pages)
+    if reason is None and devices > 1:
+        reason = (f"the program spans {devices} devices and the "
+                  "partitioner cannot see into the kernel")
+    if reason is not None:
+        paged_attention.log_declined(tuple(pool["k"].shape), max_pages,
+                                     reason)
+    return reason is None
+
+
 def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
-                       tokens, pos, route):
+                       tokens, pos, active, walk: Optional[bool]):
     """Shared body of the bucketed and ragged paged decode programs.
 
-    ``route(pg)`` maps each slot's computed tail page to its write
-    destination — identity for the bucketed path (inactive slots there
-    carry all-scratch table rows by host invariant), scratch-for-inactive
-    for the ragged path (where mid-chunked-prefill slots hold REAL pages
-    a stray decode write must not touch).
+    ``walk`` picks the attention: the page-walking kernel or the
+    gathered XLA body; None asks :func:`walks_pages`. A builder that has
+    to know what it built (the engine counts pages by it) asks once and
+    passes the answer.
+
+    ``active`` (bool ``[b]`` or None) marks the slots that really decode.
+    The bucketed path passes None: inactive slots there carry all-scratch
+    table rows by host invariant. On the ragged path a mid-chunked-
+    prefill slot holds REAL pages a stray decode write must not touch, so
+    an inactive slot's tail write goes to the scratch page; the kernel
+    visits none of its pages (the XLA body attends whatever its stale
+    length admits: nobody reads either).
     """
-    ps = pool["k"].shape[3]
+    num_pages = pool["k"].shape[1] - 1     # last row is scratch
+    ps = pool["k"].shape[2]
     max_pages = tables.shape[1]
     b = tokens.shape[0]
     rows = jnp.arange(b)
-    key_pos = jnp.arange(max_pages * ps)
+    if walk is None:
+        walk = walks_pages(pool, max_pages)
+    # The new position is written before the attention: keys 0 .. pos.
+    n_keys = pos + 1
+    if walk and active is not None:
+        n_keys = jnp.where(active, n_keys, 0)
     x = tokens[:, None]                    # [b, 1]
     residuals: list = []
     for op in plan.ops:
@@ -728,32 +824,16 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
             p = _params_at(params, path)
             q, k, v = _qkv(layer, p, x)    # [b, H, 1, dk]
             # Tail-page append: clamping the page-table column keeps the
-            # gather in range; ``route`` decides where garbage writes go.
-            pg = route(
-                tables[rows, jnp.minimum(pos // ps, max_pages - 1)])  # [b]
-            off = pos % ps
-            if _quantized(pool):
-                for name, new in (("k", k), ("v", v)):
-                    qv, sc = _quant_rows(new[:, :, 0, :])  # [b, H, dk]
-                    pool[name] = pool[name].at[idx, pg, :, off, :].set(qv)
-                    pool[name + "_scale"] = \
-                        pool[name + "_scale"].at[idx, pg, :, off].set(sc)
+            # gather in range.
+            pg = tables[rows, jnp.minimum(pos // ps, max_pages - 1)]  # [b]
+            if active is not None:
+                pg = jnp.where(active, pg, num_pages)
+            _write_rows(pool, idx, pg, pos % ps, k[:, :, 0, :], v[:, :, 0, :])
+            if walk:
+                out = _walked_attention(pool, jnp.int32(idx), tables, q,
+                                        n_keys)
             else:
-                dt = pool["k"].dtype
-                pool["k"] = pool["k"].at[idx, pg, :, off, :].set(
-                    k[:, :, 0, :].astype(dt))
-                pool["v"] = pool["v"].at[idx, pg, :, off, :].set(
-                    v[:, :, 0, :].astype(dt))
-            keys = _gather_kv(pool, "k", idx, tables)  # [b, H, S, dk]
-            vals = _gather_kv(pool, "v", idx, tables)
-            scale = 1.0 / math.sqrt(layer.key_dim)
-            s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                           keys.astype(jnp.float32)) * scale
-            valid = key_pos[None, :] <= pos[:, None]      # [b, S]
-            s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
-            prob = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", prob,
-                             vals.astype(jnp.float32)).astype(q.dtype)
+                out = _gathered_attention(plan, pool, idx, tables, q, n_keys)
             x = _attn_out(layer, p, out)
         else:  # "embed" / "point"
             _, layer, path = op
@@ -762,30 +842,37 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
 
 
 def paged_decode_step(plan: DecodePlan, params, pool: dict, page_tables,
-                      tokens, lengths, *, bucket: int):
+                      tokens, lengths, *, bucket: int,
+                      walk: Optional[bool] = None):
     """One generated token for the first ``bucket`` slots through the
     page tables.
 
     The new K/V land at offset ``length % page_size`` of the slot's tail
     page ``page_tables[slot, length // page_size]``; attention then runs
-    over the gathered pages under the same ``arange <= pos`` validity
-    mask as the contiguous path. Inactive slots inside the bucket must
-    have all-scratch table rows so their garbage writes are absorbed.
+    over the slot's pages under the same ``arange <= pos`` validity
+    mask as the contiguous path: on the TPU through the page-walking
+    kernel, elsewhere over the gathered pages. Inactive slots inside the
+    bucket must have all-scratch table rows so their garbage writes are
+    absorbed.
 
     Args:
       page_tables: int32 ``[cap, max_pages]``; only ``[:bucket]`` read.
       tokens / lengths / bucket: as :func:`decode_step`.
+      walk: attend through the page-walking kernel (off the TPU under
+        the Pallas interpreter, how the CPU tests hold it to the XLA
+        body) or the gathered XLA body; None decides from the platform
+        and the pool's shapes (:func:`walks_pages`).
 
     Returns:
       ``(pool, logits)`` with logits ``[bucket, vocab]`` fp32.
     """
     return _paged_decode_core(plan, params, pool, page_tables[:bucket],
-                              tokens[:bucket], lengths[:bucket],
-                              lambda pg: pg)
+                              tokens[:bucket], lengths[:bucket], None, walk)
 
 
 def paged_decode_ragged(plan: DecodePlan, params, pool: dict, page_tables,
-                        tokens, lengths, active):
+                        tokens, lengths, active, *,
+                        walk: Optional[bool] = None):
     """One generated token for every ACTIVE slot, full capacity in one
     program.
 
@@ -803,14 +890,13 @@ def paged_decode_ragged(plan: DecodePlan, params, pool: dict, page_tables,
       page_tables: int32 ``[cap, max_pages]``.
       tokens / lengths: int32 ``[cap]``, all rows read, inactive ignored.
       active: bool ``[cap]`` — which slots are really decoding.
+      walk: as :func:`paged_decode_step`.
 
     Returns:
       ``(pool, logits)`` with logits ``[cap, vocab]`` fp32.
     """
-    num_pages = pool["k"].shape[1] - 1     # last row is scratch
     return _paged_decode_core(plan, params, pool, page_tables, tokens,
-                              lengths,
-                              lambda pg: jnp.where(active, pg, num_pages))
+                              lengths, active, walk)
 
 
 def copy_page(pool: dict, src, dst):
