@@ -25,3 +25,10 @@ def peaks_for(device_kind: str) -> dict:
         raise KeyError(
             f"no peaks known for device kind {device_kind!r}; add it to "
             f"tpubench/harness/peaks.py with its source") from None
+
+
+def roofline_seconds(flops: float, bytes_: float, peaks: dict):
+    """(least seconds the chip could take, which bound sets it)."""
+    t_c = flops / peaks["bf16_flops"]
+    t_m = bytes_ / peaks["hbm_bytes_per_s"]
+    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")

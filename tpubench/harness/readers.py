@@ -9,16 +9,17 @@ returns 0 for a share of a peak.
 * ``counters`` / ``distributions`` — a snapshot of ``observe.metrics``;
 * ``host`` — values the harness measured on the host clock;
 * ``trace`` — the profiler trace in the neutral form of ``trace.py``;
-* ``work`` — operations and bytes from shapes (``work.py``) for the
-  traced window, keyed by name;
+* ``work`` — operations and bytes from shapes (the cell's family
+  module counts them) for the traced window, keyed by name;
 * ``peaks`` — the chip's row of the table of peaks; ``chips``;
-* ``sizes`` — the configuration's and the engine's sizes, for patterns.
+* ``sizes`` — the family's named sizes of the configuration and the
+  engine's, for patterns.
 """
 
 from __future__ import annotations
 
 from tpubench.harness import trace as trace_lib
-from tpubench.harness import work as work_lib
+from tpubench.harness import peaks as peaks_lib
 
 
 def _sum_counter_or_dist(ctx, name):
@@ -66,8 +67,8 @@ def _trace(ctx):
 def _program_seconds(ctx, spec):
     """Device seconds and count of the program executions a spec names:
     ``patterns`` on ``line``, narrowed by ``containing`` (patterns of
-    operations, in which ``{key}`` stands for a size of the cell's
-    configuration or engine, as ``{max_batch}`` or ``{n_vocab}``)."""
+    operations, in which ``{key}`` stands for one of ``ctx["sizes"]``,
+    as ``{max_batch}`` or ``{n_vocab}``)."""
     containing = [p.format_map(ctx.get("sizes", {}))
                   for p in spec.get("containing", [])]
     return trace_lib.matching_seconds(
@@ -117,7 +118,7 @@ def kernel_roofline(ctx, spec):
     seconds, count = trace_lib.matching_seconds(t, spec["patterns"])
     if not count or seconds <= 0:
         return None
-    least, _ = work_lib.roofline_seconds(pair[0], pair[1], ctx["peaks"])
+    least, _ = peaks_lib.roofline_seconds(pair[0], pair[1], ctx["peaks"])
     return 100.0 * least / seconds
 
 

@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from tpubench.harness import stats, traffic, work
+from tpubench.harness import reference, stats, traffic
 
 DRAIN_LIMIT_S = 60.0
 
@@ -34,27 +34,26 @@ def _span(name, on):
 class ServeRun:
     def __init__(self, cell, seed: int):
         self.cell, self.seed = cell, int(seed)
-        self.cfg, self.mix = cell.config, cell.mix
+        self.cfg, self.mix, self.family = cell.config, cell.mix, cell.family
+        self.vocab, self.pad = cell.sizes["n_vocab"], cell.sizes["n_ctx"]
         self.engine_args = dict(self.mix["engine"])
 
     def build(self, seconds: float):
         from tpu_dist.models.policy import set_policy
         from tpu_dist.serve.engine import ServeEngine
-        from tpubench.harness import program
 
         set_policy(self.mix["policy"])
-        self.model = program.build_lm(self.cfg, self.seed)
+        self.model = self.family.build_program(self.cfg, self.seed)
         self.engine = ServeEngine(
             self.model, seed=self.seed % (2 ** 31 - 1),
             clock=time.perf_counter, **self.engine_args)
         self.plan = traffic.plan_requests(
-            self.mix, seconds, self.seed, self.cfg["n_vocab"],
-            self.engine.max_len)
+            self.mix, seconds, self.seed, self.vocab, self.engine.max_len)
 
     def warm_up(self):
         """Every program the mix can reach, through submit()/step()."""
         prompts = traffic.warmup_prompts(
-            self.mix, self.seed, self.cfg["n_vocab"],
+            self.mix, self.seed, self.vocab,
             self.engine_args["prefill_chunk"], self.plan)
         for prompt, new in prompts[:-1]:
             self.engine.submit(prompt, max_new_tokens=new)
@@ -67,7 +66,12 @@ class ServeRun:
 
     # -- the window --------------------------------------------------------
 
-    def window(self, seconds: float, *, trace_dir=None, traced_s=4.0):
+    def window(self, seconds: float, *, trace_dir=None, traced_s=4.0,
+               at_close=None):
+        """``at_close`` is called once when the window closes, before the
+        profiler stops (a stall of seconds that belongs to the drain): a
+        traced run snapshots the program's counters there, so every
+        per-layer metric reads the window and nothing of the drain."""
         import jax
 
         engine, plan = self.engine, self.plan
@@ -77,7 +81,9 @@ class ServeRun:
         last_len, last_stamp = {}, {}
         itl_s: list = []
         steps: list = []          # (t_before, t_after, contexts of ready)
-        live: dict = {}           # index -> request still generating
+        waiting: list = []        # indices sent and not yet admitted
+        live: dict = {}           # index -> request admitted, unfinished
+        admitted_at_close: list = []
         nxt = generated = 0
         longest_step = 0.0
         tracing, window_span = False, None
@@ -86,7 +92,7 @@ class ServeRun:
         # The profiler runs over the LAST ``traced_s`` of the window, so
         # that writing the trace out falls after the close.
         t_trace = t0 + max(0.0, seconds - traced_s)
-        closed_at = None
+        closed_at = drain_from = None
         while True:
             now = time.perf_counter()
             if spans and traced_from is None and now >= t_trace:
@@ -96,10 +102,17 @@ class ServeRun:
                 tracing, traced_from = True, time.perf_counter()
             if closed_at is None and now - t0 >= seconds:
                 closed_at = now
+                admitted_at_close = [i for i in range(nxt)
+                                     if reqs[i].status != "queued"]
+                if at_close is not None:
+                    at_close()
                 if tracing:
                     window_span.__exit__(None, None, None)
                     jax.profiler.stop_trace()
                     tracing, traced_until = False, now
+                # Writing a busy trace out takes tens of seconds: the
+                # drain and its limit start when that is done.
+                drain_from = time.perf_counter()
             # Every request of the plan is due before the close; one that
             # came due during the last step is still sent, late.
             with _span("tpubench.submit", tracing):
@@ -108,10 +121,11 @@ class ServeRun:
                     submit_s[nxt] = time.perf_counter()
                     reqs[nxt] = engine.submit(
                         r.prompt, max_new_tokens=r.max_new_tokens)
-                    live[nxt] = reqs[nxt]
+                    waiting.append(nxt)
                     nxt += 1
             if closed_at is not None and (
-                    not live or now - closed_at > DRAIN_LIMIT_S):
+                    not (live or waiting)
+                    or now - drain_from > DRAIN_LIMIT_S):
                 break
             if engine.scheduler.idle():
                 if closed_at is not None:
@@ -125,6 +139,12 @@ class ServeRun:
             with _span("tpubench.engine_step", tracing):
                 engine.step()
             t_b = time.perf_counter()
+            # A backlog keeps a thousand requests queued: only those the
+            # engine has admitted are looked at token by token.
+            admitted = [i for i in waiting if reqs[i].status != "queued"]
+            if admitted:
+                live.update((i, reqs[i]) for i in admitted)
+                waiting = [i for i in waiting if i not in live]
             contexts = []
             for i in list(live):
                 req = reqs[i]
@@ -136,11 +156,12 @@ class ServeRun:
                         itl_s.append(t_b - last_stamp[i])
                         contexts.append(len(req.prompt) + n)
                     last_len[i], last_stamp[i] = n, t_b
-                if req.status != "active" and req.status != "queued":
+                if req.status != "active":
                     del live[i]
             steps.append((t_a, t_b, contexts))
             longest_step = max(longest_step, t_b - t_a)
-        t_close = closed_at if closed_at is not None else time.perf_counter()
+        t_end = time.perf_counter()
+        t_close = closed_at if closed_at is not None else t_end
         window_s = t_close - t0
         sent = [i for i in range(len(plan)) if reqs[i] is not None]
         due_abs = [t0 + plan[i].due_s for i in sent]
@@ -155,16 +176,22 @@ class ServeRun:
                   if traced_until is not None
                   and a >= traced_from and b <= traced_until]
         return {
-            "window_s": window_s, "sent": len(sent),
+            "window_s": window_s,
+            "drain_s": t_end - (drain_from or t_end),
+            "sent": len(sent),
             "not_sent": len(plan) - len(sent),
             "failed": sum(reqs[i].status != "done" for i in sent),
             "tokens_generated": generated,
             "tokens_completed": sum(len(r.generated)
                                     for r in done_in_window),
+            "completed_in_window": len(done_in_window),
             "ttft_ms": ttft, "itl_ms": [1e3 * v for v in itl_s],
             "lateness_ms": stats.lateness_ms(
                 due_abs, [submit_s[i] for i in sent]),
             "prompt_tokens_sent": sum(len(plan[i].prompt) for i in sent),
+            "prompt_tokens_admitted": sum(
+                len(plan[i].prompt) for i in admitted_at_close),
+            "queued_at_close": len(sent) - len(admitted_at_close),
             "traced_decode_contexts": [c for _, _, c in traced if c],
             "engine_steps": len(steps),
             "engine_step_max_ms": 1e3 * longest_step,
@@ -211,26 +238,25 @@ class ServeRun:
 
         import jax
 
-        from tpubench.reference import gpt2
-
-        cfg, pad = self.cfg, self.cfg["n_ctx"]
+        fam, cfg, pad = self.family, self.cfg, self.pad
         widest, tokens = 0.0, 0
         with jax.default_matmul_precision("highest"):
-            params = jax.jit(lambda k: gpt2.make_params(k, cfg))(
-                gpt2.seed_key(self.seed))
-            ref_fn = jax.jit(functools.partial(gpt2.forward, cfg=cfg))
-            low_fn = (jax.jit(functools.partial(gpt2.forward, cfg=cfg,
+            params = jax.jit(lambda k: fam.make_params(k, cfg))(
+                reference.seed_key(self.seed))
+            ref_fn = jax.jit(functools.partial(fam.forward, cfg=cfg))
+            low_fn = (jax.jit(functools.partial(fam.forward, cfg=cfg,
                                                 quant=quant))
                       if quant else None)
             for prompt, served, _ in self.sample():
-                rows = gpt2.served_rows(ref_fn, params, prompt, served, pad)
+                rows = reference.served_rows(ref_fn, params, prompt, served,
+                                             pad)
                 if not np.all(np.isfinite(rows)):
                     return {"served_logit_gap": math.inf, "_tokens": tokens}
                 judged = served
                 if low_fn is not None:
-                    judged = gpt2.served_rows(
+                    judged = reference.served_rows(
                         low_fn, params, prompt, served, pad).argmax(axis=-1)
-                gaps = gpt2.gap_in_sigmas(rows, judged)
+                gaps = reference.gap_in_sigmas(rows, judged)
                 widest = max(widest, float(gaps.max()))
                 tokens += len(served)
         return {"served_logit_gap": widest, "_tokens": tokens}
@@ -240,11 +266,11 @@ def layer_work(run: ServeRun, host: dict) -> dict:
     contexts = host["traced_decode_contexts"]
     if not contexts:
         return {}
-    cfg, kv = run.cfg, run.engine_args["kv_dtype"]
+    fam, cfg, kv = run.family, run.cfg, run.engine_args["kv_dtype"]
     return {
-        "decode_flops": sum(work.decode_step_flops(cfg, c)
+        "decode_flops": sum(fam.decode_step_flops(cfg, c)
                             for c in contexts),
-        "decode_bytes": sum(work.decode_step_bytes(cfg, sum(c), kv)
+        "decode_bytes": sum(fam.decode_step_bytes(cfg, sum(c), kv)
                             for c in contexts),
     }
 
@@ -264,12 +290,15 @@ def run_cell(cell, args, ctx) -> dict:
         metrics.get_registry().reset()
     before = ctx["meter"].read()
     setup_s = time.perf_counter() - ctx["t_start"]
-    host = run.window(args.seconds, trace_dir=ctx.get("trace_dir"))
+    snap: dict = {}
+    host = run.window(
+        args.seconds, trace_dir=ctx.get("trace_dir"),
+        at_close=((lambda: snap.update(metrics.get_registry().snapshot()))
+                  if traced else None))
     after = ctx["meter"].read()
     host["compiles_in_window"] = after["requests"] - before["requests"]
     host["setup_compile_s"] = before["compile_s"]
     if traced:
-        snap = metrics.get_registry().snapshot()
         metrics.disable()
         host["counters"] = snap["counters"]
         host["distributions"] = snap["distributions"]

@@ -13,14 +13,19 @@ profiler writes (``jax.profiler.ProfileData``, nothing but JAX).
 from __future__ import annotations
 
 import bisect
+import heapq
 import pathlib
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-#: Host spans the harness writes (``jax.profiler.TraceAnnotation``).
+#: Host spans that are kept: the harness's own
+#: (``jax.profiler.TraceAnnotation``) and the program's
+#: (``tpu_dist.utils.profiler.span``), which lie inside them.
 SPAN_PREFIX = "tpubench."
+PROGRAM_PREFIX = "tpu_dist."
+SPAN_PREFIXES = (SPAN_PREFIX, PROGRAM_PREFIX)
 WINDOW_SPAN = "tpubench.window"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute",
@@ -29,7 +34,8 @@ COLLECTIVE = re.compile(
 
 def load_xplane(trace_dir) -> dict:
     """The newest ``.xplane.pb`` under ``trace_dir`` in the neutral form.
-    Only device planes and the harness's own host spans are kept."""
+    Only device planes and the harness's and the program's host spans
+    are kept."""
     from jax.profiler import ProfileData
 
     files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"),
@@ -50,7 +56,7 @@ def load_xplane(trace_dir) -> dict:
             else:
                 events = [[e.name, int(e.start_ns), int(e.duration_ns)]
                           for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
+                          if e.name.startswith(SPAN_PREFIXES)]
             if events:
                 lines.setdefault(line.name, []).extend(events)
         if lines:
@@ -64,13 +70,15 @@ def device_planes(trace: dict) -> list[str]:
 
 
 def host_spans(trace: dict) -> list[list]:
-    """Every harness span on any host line: [name, start_ns, dur_ns]."""
+    """Every harness or program span on any host line:
+    [name, start_ns, dur_ns]."""
     spans = []
     for plane, lines in trace.items():
         if DEVICE_PLANE.match(plane):
             continue
         for events in lines.values():
-            spans.extend(e for e in events if e[0].startswith(SPAN_PREFIX))
+            spans.extend(e for e in events
+                         if e[0].startswith(SPAN_PREFIXES))
     return sorted(spans, key=lambda e: e[1])
 
 
@@ -217,26 +225,41 @@ def top_device_ops(trace: dict, n: int = 10) -> list[list]:
 
 def idle_gaps_by_span(trace: dict, n: int = 10) -> list[list]:
     """[host span name, idle seconds]: the first device's idle time inside
-    the window, each gap charged to the harness span that covers most of
-    it (``unattributed`` where none does); the largest first."""
+    the window, the largest first. A gap runs from the end of one program,
+    across the host's phases, into the next dispatch, so every part of it
+    is charged to the INNERMOST span open at that instant: the shortest
+    of the harness's and the program's spans that cover it
+    (``unattributed`` where none does)."""
     planes = device_planes(trace)
     if not planes:
         return []
     t0, t1 = window_ns(trace)
     busy = union((a, b) for _, a, b in ops_in_window(trace, planes[0]))
     gaps = subtract([(t0, t1)], busy)
-    spans = [(name, s, s + d) for name, s, d in host_spans(trace)
-             if name != WINDOW_SPAN]
+    spans = sorted((max(s, t0), min(s + d, t1), name)
+                   for name, s, d in host_spans(trace)
+                   if name != WINDOW_SPAN and s < t1 and s + d > t0)
     sums: dict = {}
-    for a, b in gaps:
-        best, best_cover = "unattributed", 0
-        for name, s, e in spans:
-            cover = min(b, e) - max(a, s)
-            if cover > best_cover:
-                best, best_cover = name, cover
-        sums[best] = sums.get(best, 0) + (b - a)
+    open_: list = []            # heap of (length, end, name): shortest first
+    k = 0
+    for cur, b in gaps:
+        while cur < b:
+            while k < len(spans) and spans[k][0] <= cur:
+                s, e, name = spans[k]
+                heapq.heappush(open_, (e - s, e, name))
+                k += 1
+            while open_ and open_[0][1] <= cur:
+                heapq.heappop(open_)
+            nxt = b
+            if k < len(spans):
+                nxt = min(nxt, spans[k][0])
+            name = "unattributed"
+            if open_:
+                name, nxt = open_[0][2], min(nxt, open_[0][1])
+            sums[name] = sums.get(name, 0) + (nxt - cur)
+            cur = nxt
     top = sorted(sums.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, v / 1e9] for k, v in top]
+    return [[name, ns / 1e9] for name, ns in top]
 
 
 def describe(trace: dict, n: int = 40) -> dict:

@@ -5,7 +5,8 @@ Two kinds of mix:
 
 * ``"kind": "train"`` — a training job: rows per chip, sequence length,
   steps per epoch, optimizer settings. :func:`token_rows` makes its data.
-* ``"kind": "serve"`` — an open-loop request schedule. Arrival times and
+* ``"kind": "serve"`` — an open-loop request schedule (``rate_rps`` with
+  its bursts, or ``requests_at_once``: a batch job's queue). Arrival times and
   each arrival's prompt length, output length, system prompt and repeat
   are fixed by the file (``schedule_seed``), so every run seed offers the
   same work at the same moments and only the tokens differ: a tail over
@@ -40,11 +41,15 @@ def _lognormal_quantiles(n: int, median: float, sigma: float, lo: int,
 
 
 def arrival_times(mix: dict, seconds: float) -> np.ndarray:
-    """Arrival offsets in [0, seconds): a steady stream carrying
+    """Arrival offsets in [0, seconds). A mix with ``requests_at_once``
+    hands the whole job over at t = 0: that many requests, all due at
+    once, whatever ``seconds`` is. Otherwise a steady stream carrying
     ``steady_share`` of ``rate_rps`` with exponential gaps, and bursts of
     ``burst_size`` requests spread evenly over ``burst_span_s`` carrying
     the rest, so one burst every burst_size / ((1 - share) * rate)
     seconds. All of it from ``schedule_seed``, never from the run seed."""
+    if mix.get("requests_at_once"):
+        return np.zeros(int(mix["requests_at_once"]))
     rng = np.random.default_rng(mix["schedule_seed"])
     rate, share = float(mix["rate_rps"]), float(mix["steady_share"])
     times = []

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from tpubench.harness import checks, traffic, work
+from tpubench.harness import checks, reference, traffic
 
 CHECK_STEPS = 3
 
@@ -80,9 +80,10 @@ class TrainRun:
 
     def __init__(self, cell, seed: int, *, chips: int):
         self.cell, self.seed, self.chips = cell, int(seed), chips
-        self.cfg, self.mix = cell.config, cell.mix
+        self.cfg, self.mix, self.family = cell.config, cell.mix, cell.family
         self.global_batch = self.mix["rows_per_chip"] * chips
-        self.seq = self.cfg["n_ctx"]
+        self.seq = cell.sizes["n_ctx"]
+        self.vocab = cell.sizes["n_vocab"]
         self.steps_per_epoch = int(self.mix["steps_per_epoch"])
         self.fit_seed = self.seed % (2 ** 31 - 1)
         self.prog: dict = {}
@@ -95,27 +96,26 @@ class TrainRun:
 
         import tpu_dist as td
         from tpu_dist.models.policy import set_policy
-        from tpubench.harness import program
 
         set_policy(self.mix["policy"])
         self.strategy = td.MirroredStrategy(
             devices=jax.devices()[:self.chips])
         with self.strategy.scope():
-            self.model = program.build_lm(self.cfg, self.seed)
+            self.model = self.family.build_program(self.cfg, self.seed)
             self.model.compile(
                 loss=td.ops.SparseCategoricalCrossentropy(from_logits=True),
                 optimizer=td.ops.Adam(
                     learning_rate=self.mix["learning_rate"]))
         rows = self.global_batch * int(self.mix["pool_batches"])
         self.x, self.y = traffic.token_rows(
-            self.seed, self.cfg["n_vocab"], rows, self.seq)
+            self.seed, self.vocab, rows, self.seq)
         ds = td.data.Dataset.from_tensor_slices((self.x, self.y)).batch(
             self.global_batch).repeat()
         # One distributed dataset for every fit(): its iterator persists,
         # so the first steps and the window read on through the same rows.
         self.dist = self.strategy.experimental_distribute_dataset(ds)
-        self._grad_norms = program.program_grad_norms(self.cfg)
-        self._delta_norms = program.program_delta_norms(self.cfg)
+        self._grad_norms = self.family.program_grad_norms(self.cfg)
+        self._delta_norms = self.family.program_delta_norms(self.cfg)
 
     def _fit(self, first_epoch, epochs, steps, callbacks=()):
         history = self.model.fit(
@@ -131,8 +131,6 @@ class TrainRun:
         what the comparison reads of them."""
         import jax
 
-        from tpubench.reference import gpt2
-
         losses = []
         for i in range(CHECK_STEPS):
             history = self._fit(i, 1, 1)
@@ -141,7 +139,7 @@ class TrainRun:
                 grad = jax.device_get(
                     self._grad_norms(self.model.variables["opt"].mu))
         delta = jax.device_get(self._delta_norms(
-            self.model.variables["params"], gpt2.seed_key(self.seed)))
+            self.model.variables["params"], reference.seed_key(self.seed)))
         self.prog = {"losses": losses,
                      "grad_norms": {k: float(v) for k, v in grad.items()},
                      "delta_norms": {k: float(v) for k, v in delta.items()}}
@@ -214,16 +212,16 @@ class TrainRun:
         ``highest`` matmul precision, rows in blocks."""
         import jax
 
-        from tpubench.reference import gpt2
-
         batches = [(self.x[i * self.global_batch:(i + 1) * self.global_batch],
                     self.y[i * self.global_batch:(i + 1) * self.global_batch])
                    for i in range(CHECK_STEPS)]
         with jax.default_matmul_precision("highest"):
-            params = jax.jit(lambda k: gpt2.make_params(k, self.cfg))(
-                gpt2.seed_key(self.seed))
-            ref = gpt2.TrainReference(
-                self.cfg, lr=self.mix["learning_rate"], quant=quant,
+            params = jax.jit(
+                lambda k: self.family.make_params(k, self.cfg))(
+                reference.seed_key(self.seed))
+            ref = reference.TrainReference(
+                self.family.loss_sum, self.cfg,
+                lr=self.mix["learning_rate"], quant=quant,
                 rows_per_block=int(self.mix.get("reference_rows_per_block",
                                                 2)),
                 keep_rows=keep_rows, freeze=freeze,
@@ -232,17 +230,15 @@ class TrainRun:
 
 
 def layer_work(run: TrainRun, traced_steps: int) -> dict:
-    """Operations and bytes of the traced steps, per device."""
-    cfg, rows, seq = run.cfg, run.mix["rows_per_chip"], run.seq
-    calls = traced_steps * cfg["n_layer"]
-    fwd = work.flash_fwd_work(cfg, rows, seq)
-    bwd = work.flash_bwd_work(cfg, rows, seq)
-    return {
-        "train_step_flops": (traced_steps * rows * seq
-                             * work.train_flops_per_token(cfg, seq)),
-        "flash_fwd": (fwd[0] * calls, fwd[1] * calls),
-        "flash_bwd": (bwd[0] * calls, bwd[1] * calls),
-    }
+    """Operations and bytes of the traced steps, per device: the
+    family counts a step's, the harness sums the steps."""
+    fam, cfg = run.family, run.cfg
+    rows, seq = run.mix["rows_per_chip"], run.seq
+    out = {"train_step_flops": (traced_steps * rows * seq
+                                * fam.train_flops_per_token(cfg, seq))}
+    for name, (flops, bytes_) in fam.train_kernels(cfg, rows, seq).items():
+        out[name] = (flops * traced_steps, bytes_ * traced_steps)
+    return out
 
 
 def run_cell(cell, args, ctx) -> dict:

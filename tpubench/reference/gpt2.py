@@ -1,12 +1,27 @@
-"""Plain GPT-2 for the benchmark: weights from a seed, forward, loss,
-gradients and Adam, in straightforward ``jax.numpy`` and float32.
+"""The GPT-2 family: everything the benchmark knows of it, in one module
+that a configuration names under ``reference`` (``harness/cells.py`` states
+the interface; no other file of the benchmark names this family or reads
+its keys).
 
-This file imports nothing of ``tpu_dist`` and takes nothing that the
-program has made. It follows OpenAI's GPT-2 (pre-LN blocks, learned
-positions, tanh-GELU, LayerNorm eps 1e-5) with the departures that
-``PERF.md`` section 4 states for the repo's block: separate biased
-``wq/wk/wv`` projections instead of one ``c_attn``, and an untied, biased
-vocabulary head.
+Three parts:
+
+* **The plain reference** (``make_params``, ``forward``, ``loss_sum``):
+  weights from a key, forward and loss in straightforward ``jax.numpy``
+  and float32. It imports nothing of ``tpu_dist`` and takes nothing that
+  the program has made. It follows OpenAI's GPT-2 (pre-LN blocks, learned
+  positions, tanh-GELU, LayerNorm eps 1e-5) with the departures that
+  ``PERF.md`` section 4 states for the repo's block: separate biased
+  ``wq/wk/wv`` projections instead of one ``c_attn``, and an untied,
+  biased vocabulary head.
+* **The program at these sizes** (``build_program`` and the leaf names):
+  the repo's LM through its normal constructor, whose ``init`` hands out
+  the reference's weights. Only ``build_program`` imports ``tpu_dist``.
+* **Sizes and work from shapes** (``sizes``, ``train_flops_per_token``,
+  ``train_kernels``, ``decode_step_flops``, ``decode_step_bytes``,
+  ``kv_bytes_per_token``): what the algorithm needs, whatever implements
+  it: causal attention is counted over the lower triangle, recomputation
+  is never counted, and a weight is read once. A share of a peak built on
+  them cannot pass 100 % unless the time leaves out part of the work.
 
 Sizes (``cfg``) use the published key names: ``n_vocab, n_ctx, n_embd,
 n_head, n_layer`` and ``n_inner`` (4 * n_embd).
@@ -28,7 +43,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from tpubench.harness.reference import norm, seed_key
 
 LN_EPS = 1e-5
 #: Stacked (per-layer) leaves: name -> (shape builder, kind).
@@ -43,12 +59,6 @@ _BLOCK_LEAVES = (
     ("w2", lambda d, f: (f, d), "matrix"), ("b2", lambda d, f: (d,), "bias"),
 )
 BLOCK_LEAF_NAMES = tuple(n for n, _, _ in _BLOCK_LEAVES)
-
-
-def seed_key(seed: int):
-    """A PRNG key from any whole number (the driver's seeds pass 2**31)."""
-    words = np.random.SeedSequence(int(seed)).generate_state(2)
-    return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
 def _leaf(key, shape, kind):
@@ -169,130 +179,207 @@ def loss_sum(params, x, y, cfg, quant=None):
     return -jnp.take_along_axis(logp, y[..., None], axis=-1).sum()
 
 
-def adam_update(params, grads, mu, nu, step, *, lr, b1=0.9, b2=0.999,
-                eps=1e-7):
-    """Adam as Keras states it (epsilon outside the root, bias correction
-    folded into the step size); ``step`` counts from 1."""
-    mu = jax.tree_util.tree_map(lambda m, g: b1 * m + (1 - b1) * g, mu, grads)
-    nu = jax.tree_util.tree_map(lambda n, g: b2 * n + (1 - b2) * g * g,
-                                nu, grads)
-    scale = lr * math.sqrt(1 - b2 ** step) / (1 - b1 ** step)
-    params = jax.tree_util.tree_map(
-        lambda p, m, n: p - scale * m / (jnp.sqrt(n) + eps), params, mu, nu)
-    return params, mu, nu
+# -- the program at these sizes -------------------------------------------
+# The seam between the benchmark and the system under test. From
+# ``tpu_dist`` the benchmark takes the entry points (``Model.fit``,
+# ``ServeEngine.submit``/``step``), the counters of ``observe.metrics`` and
+# nothing else. This part builds the repo's LM at a configuration's widths
+# and lays the reference's weights into the tree the program names its
+# parameters by. It is the only place that knows those names.
 
 
-def leaf_norms(tree: dict) -> dict:
-    """L2 norm of every leaf; a stacked leaf gives one norm per layer,
-    named ``h<i>.<leaf>``."""
-    out = {}
-    for name, a in tree.items():
-        if name.startswith("h."):
-            per = jnp.sqrt(jnp.sum(
-                jnp.square(a.astype(jnp.float32)),
-                axis=tuple(range(1, a.ndim))))
-            for i in range(a.shape[0]):
-                out[f"h{i}.{name[2:]}"] = per[i]
-        else:
-            out[name] = jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32))))
+def block_name(i: int) -> str:
+    return "block" if i == 0 else f"block_{i}"
+
+
+def to_program_tree(p: dict, cfg: dict) -> dict:
+    """Reference weights (stacked layers) in ``build_transformer_lm``'s
+    parameter tree."""
+    tree = {"embedding": {"table": p["wte"]},
+            "positionalembedding": {"table": p["wpe"]},
+            "layernormalization": {"gamma": p["lnf_g"], "beta": p["lnf_b"]},
+            "dense": {"kernel": p["head_w"], "bias": p["head_b"]}}
+    for i in range(cfg["n_layer"]):
+        h = {n: p["h." + n][i] for n in BLOCK_LEAF_NAMES}
+        tree[block_name(i)] = {
+            "residual": {"main": {
+                "layernormalization": {"gamma": h["ln1_g"],
+                                       "beta": h["ln1_b"]},
+                "multiheadattention": {k: h[k] for k in (
+                    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo")}}},
+            "residual_1": {"main": {
+                "layernormalization": {"gamma": h["ln2_g"],
+                                       "beta": h["ln2_b"]},
+                "dense": {"kernel": h["w1"], "bias": h["b1"]},
+                "dense_1": {"kernel": h["w2"], "bias": h["b2"]}}}}
+    return tree
+
+
+def canonical_leaves(tree: dict, cfg: dict) -> dict:
+    """The program's tree flattened to the reference's leaf names
+    (``wte``, ``h3.wq``, ...)."""
+    out = {"wte": tree["embedding"]["table"],
+           "wpe": tree["positionalembedding"]["table"],
+           "lnf_g": tree["layernormalization"]["gamma"],
+           "lnf_b": tree["layernormalization"]["beta"],
+           "head_w": tree["dense"]["kernel"],
+           "head_b": tree["dense"]["bias"]}
+    for i in range(cfg["n_layer"]):
+        b = tree[block_name(i)]
+        attn, mlp = b["residual"]["main"], b["residual_1"]["main"]
+        leaves = {"ln1_g": attn["layernormalization"]["gamma"],
+                  "ln1_b": attn["layernormalization"]["beta"],
+                  "ln2_g": mlp["layernormalization"]["gamma"],
+                  "ln2_b": mlp["layernormalization"]["beta"],
+                  "w1": mlp["dense"]["kernel"], "b1": mlp["dense"]["bias"],
+                  "w2": mlp["dense_1"]["kernel"],
+                  "b2": mlp["dense_1"]["bias"]}
+        leaves.update({k: attn["multiheadattention"][k] for k in (
+            "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo")})
+        for k, v in leaves.items():
+            out[f"h{i}.{k}"] = v
     return out
 
 
-class TrainReference:
-    """The first steps of training, block of rows by block of rows so that
-    the float32 activations fit beside the parameters and Adam's state.
+def build_program(cfg: dict, seed: int):
+    """The repo's LM at ``cfg``'s widths whose ``init`` hands out the
+    benchmark's weights: made on the device in one jitted call from the
+    seed, float32 as the program holds them."""
+    from tpu_dist.models.transformer import build_transformer_lm
 
-    ``keep_rows`` plants the faults the controls read: a fraction of every
-    batch is left out and the mean taken over the rest.
-    """
+    model = build_transformer_lm(
+        cfg["n_vocab"], cfg["n_ctx"], d_model=cfg["n_embd"],
+        depth=cfg["n_layer"], num_heads=cfg["n_head"], ff_dim=cfg["n_inner"])
+    theirs = jax.eval_shape(lambda: model.init(0))["params"]
+    make = jax.jit(lambda key: to_program_tree(make_params(key, cfg), cfg))
+    ours = jax.eval_shape(make, seed_key(seed))
+    a = jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), theirs)
+    b = jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), ours)
+    if a != b:
+        raise RuntimeError(
+            "the program's parameter tree is not the one "
+            "this family lays its weights into")
 
-    def __init__(self, cfg: dict, *, lr: float, quant=None, rows_per_block=2,
-                 keep_rows: float = 1.0, freeze: bool = False, devices=None):
-        self.cfg, self.lr, self.quant = cfg, float(lr), quant
-        self.rows_per_block = int(rows_per_block)
-        self.keep_rows = float(keep_rows)
-        self.freeze = bool(freeze)
-        #: Blocks of rows go round the cell's chips, each summing its own;
-        #: the state and the update stay on the first.
-        self.devices = list(devices or jax.devices()[:1])
-        self._grad = jax.jit(jax.value_and_grad(
-            functools.partial(loss_sum, cfg=cfg, quant=quant)))
-        self._add = jax.jit(
-            lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
-            donate_argnums=(0,))
-        self._norms = jax.jit(leaf_norms)
-        self._delta_norms = jax.jit(lambda a, b: leaf_norms(
-            jax.tree_util.tree_map(jnp.subtract, a, b)))
+    def init(_seed=0, input_shape=None):
+        return {"params": make(seed_key(seed)), "state": {}}
 
-    def gradient(self, params, x, y):
-        """(mean loss, gradient of the mean loss) over the rows kept."""
-        rows = max(1, int(round(x.shape[0] * self.keep_rows)))
-        x, y = x[:rows], y[:rows]
-        devs = self.devices
-        copies = [params] + [jax.device_put(params, d) for d in devs[1:]]
-        parts, sums = [], [None] * len(devs)
-        for j, i in enumerate(range(0, rows, self.rows_per_block)):
-            k = j % len(devs)
-            xb = jax.device_put(x[i:i + self.rows_per_block], devs[k])
-            yb = jax.device_put(y[i:i + self.rows_per_block], devs[k])
-            part, g = self._grad(copies[k], xb, yb)
-            parts.append(part)
-            sums[k] = g if sums[k] is None else self._add(sums[k], g)
-        acc = sums[0]
-        for other in sums[1:]:
-            if other is not None:
-                acc = self._add(acc, jax.device_put(other, devs[0]))
-        n = rows * x.shape[1]
-        total = sum(float(p) for p in parts)
-        return total / n, jax.tree_util.tree_map(lambda g: g / n, acc)
-
-    def run(self, params, batches) -> dict:
-        """Follow ``batches`` (a list of (x, y) host arrays). Returns the
-        numbers the comparison reads, as host floats: ``losses``, the
-        first gradient's leaf norms ``grad_norms`` and the leaf norms of
-        the parameters' change after the last step ``delta_norms``."""
-        start = params
-        mu = jax.tree_util.tree_map(jnp.zeros_like, params)
-        nu = jax.tree_util.tree_map(jnp.zeros_like, params)
-        # Gradients and both moments are donated: beside the start and the
-        # current parameters there is one copy of each, not two.
-        update = jax.jit(functools.partial(adam_update, lr=self.lr),
-                         static_argnames=("step",), donate_argnums=(1, 2, 3))
-        losses, grad_norms = [], None
-        for step, (x, y) in enumerate(batches, start=1):
-            loss, grads = self.gradient(params, x, y)
-            losses.append(loss)
-            if grad_norms is None:
-                grad_norms = jax.device_get(self._norms(grads))
-            if not self.freeze:
-                params, mu, nu = update(params, grads, mu, nu, step=step)
-            del grads
-        delta = jax.device_get(self._delta_norms(params, start))
-        return {"losses": [float(v) for v in losses],
-                "grad_norms": {k: float(v) for k, v in grad_norms.items()},
-                "delta_norms": {k: float(v) for k, v in delta.items()}}
+    model.init = init
+    return model
 
 
-# -- serving --------------------------------------------------------------
+def program_grad_norms(cfg: dict, beta_1: float = 0.9):
+    """jit: Adam's first moment after ONE step -> leaf norms of the
+    gradient as the optimizer got it (mu = (1 - beta_1) * g)."""
+    def fn(mu):
+        return {k: norm(v) / (1.0 - beta_1)
+                for k, v in canonical_leaves(mu, cfg).items()}
+
+    return jax.jit(fn)
 
 
-def served_rows(forward_fn, params, prompt, served, pad_to: int):
-    """One full-sequence forward over ``prompt + served`` (teacher forced,
-    padded to ``pad_to``); returns the logits rows from which each served
-    token was picked: served token j comes from position
-    ``len(prompt) - 1 + j``."""
-    seq = list(prompt) + list(served)
-    x = np.zeros((1, pad_to), np.int32)
-    x[0, :len(seq)] = seq
-    logits = forward_fn(params, jnp.asarray(x))[0]
-    return np.asarray(
-        logits[len(prompt) - 1:len(prompt) - 1 + len(served)], np.float32)
+def program_delta_norms(cfg: dict):
+    """jit: (params now, seed key) -> leaf norms of the change since the
+    weights the seed gives."""
+    def fn(params, key):
+        start = to_program_tree(make_params(key, cfg), cfg)
+        now = canonical_leaves(params, cfg)
+        then = canonical_leaves(start, cfg)
+        return {k: norm(now[k] - then[k]) for k in now}
+
+    return jax.jit(fn)
 
 
-def gap_in_sigmas(ref_rows, tokens):
-    """(reference max - reference logit of ``tokens``) / sigma, per row."""
-    ref_rows = np.asarray(ref_rows, np.float32)
-    tokens = np.asarray(tokens)
-    best = ref_rows.max(axis=-1)
-    chosen = ref_rows[np.arange(len(tokens)), tokens]
-    return (best - chosen) / ref_rows.std(axis=-1)
+# -- sizes and work from shapes -------------------------------------------
+
+
+def sizes(cfg: dict) -> dict:
+    """The sizes the harness and the metric patterns ask for by name:
+    ``n_vocab`` the traffic draws its ids from and the decode program's
+    logits are told by, ``n_ctx`` the longest sequence."""
+    return {"n_vocab": cfg["n_vocab"], "n_ctx": cfg["n_ctx"]}
+
+
+def matmul_params(cfg: dict) -> int:
+    """Parameters that take part in a matrix multiplication for every
+    token: the blocks' projections and MLP, and the vocabulary head."""
+    d, f = cfg["n_embd"], cfg["n_inner"]
+    return cfg["n_layer"] * (4 * d * d + 2 * d * f) + d * cfg["n_vocab"]
+
+
+def param_count(cfg: dict) -> int:
+    """Every parameter of the repo's GPT-2 block (untied, biased head)."""
+    d, f, v = cfg["n_embd"], cfg["n_inner"], cfg["n_vocab"]
+    per_layer = 4 * d * d + 4 * d + 2 * d * f + f + d + 4 * d
+    return (v * d + cfg["n_ctx"] * d + cfg["n_layer"] * per_layer
+            + 2 * d + d * v + v)
+
+
+def attention_flops(cfg: dict, seq: int, *, causal: bool = True) -> float:
+    """Forward FLOPs of one layer's QK^T and PV for ONE sequence."""
+    pairs = seq * (seq + 1) / 2 if causal else seq * seq
+    return 2 * 2 * pairs * cfg["n_embd"]
+
+
+def forward_flops_per_token(cfg: dict, seq: int) -> float:
+    """Forward FLOPs per token of a full causal sequence of ``seq``."""
+    attn = cfg["n_layer"] * attention_flops(cfg, seq) / seq
+    return 2 * matmul_params(cfg) + attn
+
+
+def train_flops_per_token(cfg: dict, seq: int) -> float:
+    """Forward plus backward (twice the forward); nothing recomputed."""
+    return 3 * forward_flops_per_token(cfg, seq)
+
+
+def flash_fwd_work(cfg: dict, batch: int, seq: int, itemsize: int = 2):
+    """(FLOPs, bytes) of one layer's causal attention forward over
+    ``batch`` sequences: reads q, k, v, writes o."""
+    flops = batch * attention_flops(cfg, seq)
+    bytes_ = 4 * batch * seq * cfg["n_embd"] * itemsize
+    return flops, bytes_
+
+
+def flash_bwd_work(cfg: dict, batch: int, seq: int, itemsize: int = 2):
+    """(FLOPs, bytes) of one layer's attention backward. It needs four
+    products (dV, dP, dQ, dK), twice the forward's two; forming QK^T
+    again is recomputation and is not counted. Reads q, k, v, o, do;
+    writes dq, dk, dv."""
+    flops = 2 * batch * attention_flops(cfg, seq)
+    bytes_ = 8 * batch * seq * cfg["n_embd"] * itemsize
+    return flops, bytes_
+
+
+def train_kernels(cfg: dict, rows: int, seq: int) -> dict:
+    """Kernel name -> (FLOPs, bytes) that ONE training step of ``rows``
+    sequences asks of it on a device, summed over the layers that call
+    it: what ``kernel_roofline`` divides by the kernel's device time."""
+    calls = cfg["n_layer"]
+    fwd, bwd = flash_fwd_work(cfg, rows, seq), flash_bwd_work(cfg, rows, seq)
+    return {"flash_fwd": (fwd[0] * calls, fwd[1] * calls),
+            "flash_bwd": (bwd[0] * calls, bwd[1] * calls)}
+
+
+def decode_step_flops(cfg: dict, contexts) -> float:
+    """FLOPs one decode step needs for the slots active in it;
+    ``contexts`` holds each active slot's context length (tokens its new
+    query attends over)."""
+    n = len(contexts)
+    attn = 2 * 2 * cfg["n_embd"] * cfg["n_layer"] * float(sum(contexts))
+    return n * 2 * matmul_params(cfg) + attn
+
+
+def kv_bytes_per_token(cfg: dict, kv_dtype: str) -> int:
+    """Pool bytes one cached position pins over all layers, K and V; an
+    int8 position also carries one float32 scale per head, K and V."""
+    per = 2 * cfg["n_layer"] * cfg["n_embd"]
+    if kv_dtype == "int8":
+        return per + 2 * cfg["n_layer"] * cfg["n_head"] * 4
+    return per * {"bf16": 2, "fp32": 4}[kv_dtype]
+
+
+def decode_step_bytes(cfg: dict, live_tokens: int, kv_dtype: str,
+                      weight_itemsize: int = 2) -> float:
+    """Bytes one decode step has to read: the multiplied weights once in
+    the compute dtype, and the live K/V positions at the pool's dtype."""
+    return (matmul_params(cfg) * weight_itemsize
+            + live_tokens * kv_bytes_per_token(cfg, kv_dtype))

@@ -107,8 +107,7 @@ def read_layers(cell, result, trace_dir, info):
            "distributions": host.get("distributions", {}),
            "host": host, "trace": trace, "work": result["work"],
            "chips": cell.chips,
-           "sizes": {**{k: v for k, v in cell.config.items()
-                        if isinstance(v, int)},
+           "sizes": {**cell.sizes,
                      **{k: v for k, v in cell.mix.get("engine", {}).items()
                         if isinstance(v, int)}},
            "peaks": (peaks.peaks_for(info["kind"])

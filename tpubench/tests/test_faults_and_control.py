@@ -71,13 +71,31 @@ def test_the_sound_four_chip_path_is_correct():
     assert result["device"]["count"] == 4
 
 
-def test_the_sound_serving_path_is_correct():
-    result = _run("serve.gpt2-large.chat", seconds="3")
+SERVE_CELLS = ["serve.gpt2-large.chat", "serve.gpt2-large.backlog"]
+
+
+@pytest.mark.parametrize("workload", SERVE_CELLS)
+def test_the_sound_serving_path_is_correct(workload):
+    result = _run(workload, seconds="3")
     assert result["checks_ok"], result["rows"]
     assert result["host"]["checked_tokens"] > 20
+    assert result["failed"] == 0
 
 
-def test_an_altered_token_is_not_correct():
+def test_a_backlog_outlasts_its_window_and_is_drained():
+    """Every request is due at t = 0 and some are still queued when the
+    window closes; the drain answers them all, and only the window's
+    tokens count."""
+    result = _run("serve.gpt2-large.backlog", seconds="0.05")
+    host = result["host"]
+    assert host["sent"] == 40 and host["not_sent"] == 0
+    assert host["queued_at_close"] > 0 and result["failed"] == 0
+    assert host["prompt_tokens_admitted"] < host["prompt_tokens_sent"]
+    assert result["checks_ok"], result["rows"]
+
+
+@pytest.mark.parametrize("workload", SERVE_CELLS)
+def test_an_altered_token_is_not_correct(workload):
     def alter(run):
         pick, calls = run.engine._pick, [0]
 
@@ -88,7 +106,7 @@ def test_an_altered_token_is_not_correct():
 
         run.engine._pick = wrong
 
-    result = _run("serve.gpt2-large.chat", alter, seconds="3")
+    result = _run(workload, alter, seconds="3")
     assert _failed(result) == ["served_logit_gap"]
 
 
@@ -99,7 +117,7 @@ def test_the_training_control_is_not_correct():
     run = train_cell.TrainRun(cell, SEED, chips=1)
     from tpubench.harness import traffic
 
-    run.x, run.y = traffic.token_rows(SEED, run.cfg["n_vocab"],
+    run.x, run.y = traffic.token_rows(SEED, run.vocab,
                                       run.global_batch * 3, run.seq)
     ref = run.reference_numbers()
     control = checks.train_numbers(run.reference_numbers(quant="fp8"), ref)
@@ -107,9 +125,9 @@ def test_the_training_control_is_not_correct():
     assert not all(r["ok"] for r in rows), rows
 
 
-def test_the_serving_control_is_not_correct():
-    args = bench_run.parse(["--workload", "serve.gpt2-large.chat",
-                            "--rehearse", "1"])
+@pytest.mark.parametrize("workload", SERVE_CELLS)
+def test_the_serving_control_is_not_correct(workload):
+    args = bench_run.parse(["--workload", workload, "--rehearse", "1"])
     cell = bench_run.load_cell(args)
     run = serve_cell.ServeRun(cell, SEED)
     run.build(3.0)

@@ -32,6 +32,18 @@ def hand_made():
     }
 
 
+def with_program_spans():
+    """The same, with the program's own spans inside the harness's: a
+    round 2-64 us that holds a decode_prep 58-61 us and a decode_wait
+    61-64 us, and a span that is no one's and is dropped on loading."""
+    t = hand_made()
+    t["/host:CPU"]["python"] += [
+        ["tpu_dist.serve.step", 2 * US, 62 * US],
+        ["tpu_dist.serve.step.decode_prep", 58 * US, 3 * US],
+        ["tpu_dist.serve.step.decode_wait", 61 * US, 3 * US]]
+    return t
+
+
 def test_intervals():
     assert T.union([(5, 7), (0, 3), (2, 4)]) == [(0, 4), (5, 7)]
     assert T.subtract([(0, 10)], [(2, 3), (5, 20)]) == [(0, 2), (3, 5)]
@@ -74,10 +86,36 @@ def test_kernel_sums_and_roofline_by_hand():
 
 def test_idle_gaps_are_charged_to_what_the_host_was_doing():
     gaps = dict(T.idle_gaps_by_span(hand_made()))
-    # Device 0 idle 60-70 (5 us under engine_step, 5 under wait: a tie
-    # goes to the first) and 90-100 (wait_for_request).
-    assert sum(gaps.values()) == pytest.approx(20e-6)
-    assert gaps["tpubench.wait_for_request"] >= 10e-6
+    # Device 0 idle 60-70 (5 us under engine_step, then 5 under wait:
+    # each part of a gap to the span open at that instant) and 90-100
+    # (wait_for_request).
+    assert gaps == {"tpubench.wait_for_request": pytest.approx(15e-6),
+                    "tpubench.engine_step": pytest.approx(5e-6)}
+
+
+def test_each_part_of_a_gap_goes_to_the_innermost_open_span():
+    gaps = T.idle_gaps_by_span(with_program_spans())
+    # Idle 60-70: 60-61 decode_prep, 61-64 decode_wait (both inside the
+    # round, inside engine_step: the shortest wins), 64-65 engine_step
+    # alone, 65-70 and 90-100 wait_for_request.
+    assert dict(gaps) == {
+        "tpubench.wait_for_request": pytest.approx(15e-6),
+        "tpu_dist.serve.step.decode_wait": pytest.approx(3e-6),
+        "tpu_dist.serve.step.decode_prep": pytest.approx(1e-6),
+        "tpubench.engine_step": pytest.approx(1e-6)}
+    assert [name for name, _ in gaps][0] == "tpubench.wait_for_request"
+    assert sum(s for _, s in gaps) == pytest.approx(20e-6)
+    # Where no span is open the time is named as such, not dropped.
+    bare = with_program_spans()
+    bare["/host:CPU"]["python"] = [
+        e for e in bare["/host:CPU"]["python"]
+        if not e[0].startswith("tpubench.") or e[0] == T.WINDOW_SPAN]
+    assert dict(T.idle_gaps_by_span(bare)) == {
+        "unattributed": pytest.approx(16e-6),
+        "tpu_dist.serve.step.decode_wait": pytest.approx(3e-6),
+        "tpu_dist.serve.step.decode_prep": pytest.approx(1e-6)}
+    assert dict(T.idle_gaps_by_span(bare, n=1)) == {
+        "unattributed": pytest.approx(16e-6)}
     ops = dict(T.top_device_ops(hand_made()))
     assert ops["fusion"] == pytest.approx(40e-6)
     assert ops["all-reduce"] == pytest.approx(30e-6)
@@ -127,3 +165,24 @@ def test_the_recorded_trace_reduces_to_what_was_read_by_hand():
     for pattern, (seconds, count) in expect["matching"].items():
         got = T.matching_seconds(t, [pattern])
         assert got == (pytest.approx(seconds), count)
+
+
+@pytest.mark.skipif(not (DATA / "recorded_serve_trace.json").is_file(),
+                    reason="no recorded serving trace in this checkout")
+def test_the_recorded_serving_trace_charges_its_gaps_to_the_programs_spans():
+    """Some rounds of the backlog cell on the chip (``tools/cut_trace.py``):
+    the sweep over span edges gives what painting nanoseconds gave, and
+    the device's idle time lies in the program's own phases."""
+    recorded = json.loads((DATA / "recorded_serve_trace.json").read_text())
+    t, expect = recorded["trace"], recorded["by_hand"]
+    assert T.window_seconds(t) == pytest.approx(expect["window_s"])
+    assert T.busy_seconds(t) == pytest.approx(expect["busy_s"])
+    got = T.idle_gaps_by_span(t, n=100)
+    assert dict(got) == {k: pytest.approx(v, abs=1e-9)
+                         for k, v in expect["idle_by_span_s"].items()}
+    assert sum(s for _, s in got) == pytest.approx(
+        expect["window_s"] - expect["busy_s"])
+    assert [s for _, s in got] == sorted((s for _, s in got), reverse=True)
+    in_program = sum(s for name, s in got if name.startswith("tpu_dist."))
+    assert in_program > 0.5 * sum(s for _, s in got)
+    assert T.idle_gaps_by_span(t) == got[:10]

@@ -5,6 +5,7 @@ import numpy as np
 from tpubench.harness import cells, traffic
 
 CHAT = cells.load_json(cells.BENCH_DIR / "traffic" / "chat.json")
+BACKLOG = cells.load_json(cells.BENCH_DIR / "traffic" / "backlog.json")
 
 
 def _plan(seed, seconds=30.0):
@@ -58,3 +59,29 @@ def test_token_rows_all_differ_and_shift_by_one():
     x, y = traffic.token_rows(2 ** 31 + 5, 50257, 64, 128)
     assert len({row.tobytes() for row in x}) == 64
     assert (x[:, 1:] == y[:, :-1]).all()
+
+
+def test_a_backlog_is_due_all_at_once_whatever_the_window():
+    """``requests_at_once`` is a parameter of a mix: that many requests,
+    all due at t = 0, the same lengths for every seed and every window."""
+    n = BACKLOG["requests_at_once"]
+    assert n % 128 == 0 and "rate_rps" not in BACKLOG
+    a = traffic.plan_requests(BACKLOG, 40.0, 5, 50257, 1024)
+    b = traffic.plan_requests(BACKLOG, 7.0, 6, 50257, 1024)
+    assert len(a) == len(b) == n
+    assert {r.due_s for r in a} == {0.0}
+    shape = lambda plan: [(len(r.prompt), r.max_new_tokens, r.prefix_id,
+                           r.repeat_of) for r in plan]
+    assert shape(a) == shape(b)
+    # Chat's lengths, prefixes and repeats, letter for letter.
+    for key in ("prompt_len", "output_len", "prefix_share", "prefix_count",
+                "prefix_len", "prefix_min_tail", "repeat_share",
+                "schedule_seed", "engine", "policy", "check"):
+        assert BACKLOG[key] == CHAT[key], key
+    shared = [r for r in a if r.prefix_id >= 0]
+    assert abs(len(shared) - n / 2) <= 0.08 * n
+    repeats = [r for r in a if r.repeat_of >= 0]
+    assert abs(len(repeats) - 0.05 * n) <= 0.02 * n
+    assert all(r.prompt == a[r.repeat_of].prompt for r in repeats)
+    lens = np.array([len(r.prompt) for r in a])
+    assert 150 <= np.median(lens) <= 260 and lens.max() <= 768 + 128

@@ -50,8 +50,7 @@ def train_readings(cell, seed, with_program):
         from tpubench.harness import traffic
 
         rows = run.global_batch * 3
-        run.x, run.y = traffic.token_rows(seed, run.cfg["n_vocab"], rows,
-                                          run.seq)
+        run.x, run.y = traffic.token_rows(seed, run.vocab, rows, run.seq)
     ref = run.reference_numbers()
     if with_program:
         out["program"] = checks.train_numbers(run.prog, ref)

@@ -4,18 +4,17 @@
         --seconds 40 --out chiprun_out/spans.<cell>.json
 
 Runs the cell exactly as ``run.py --trace 1`` does and prints the same
-line; beside it, it writes what the harness does not read yet
-(``PERF.md`` section 7): the ``tpu_dist.*`` host spans of the ``.xplane.pb``
-(on the device events' clock), how many lie inside a harness span, the
-device's idle time, each part of a gap charged to the INNERMOST host span
-open at that instant, the registry's span distributions as a per-phase
-split, and the longest serving round of the span ring with its children
-and any compile record inside it. A builder's tool: the benchmark's numbers do not pass
-through it.
+line; beside it, it writes what the result line has no room for: every
+``tpu_dist.*`` and ``tpubench.*`` host span of the trace with its count
+and seconds, how many program spans lie inside a harness span, the
+device's idle time by innermost span in full (``harness/trace.py``; the
+line's ``breakdown`` keeps the first ten), the registry's span
+distributions as a per-phase split, and the longest serving round of the
+span ring with its children and any compile record inside it. A builder's
+tool: the benchmark's numbers do not pass through it.
 """
 
 import argparse
-import bisect
 import json
 import pathlib
 import sys
@@ -27,83 +26,28 @@ if str(ROOT) not in sys.path:
 from tpubench import run as bench_run  # noqa: E402
 from tpubench.harness import trace as trace_lib  # noqa: E402
 
-PROGRAM_PREFIX = "tpu_dist."
+PROGRAM_PREFIX = trace_lib.PROGRAM_PREFIX
 
 
-def host_spans(trace_dir) -> list:
-    """[name, start_ns, dur_ns, line] of every harness and program span
-    on a host line of the newest ``.xplane.pb``."""
-    from jax.profiler import ProfileData
-
-    files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"),
-                   key=lambda p: p.stat().st_mtime)
-    data = ProfileData.from_file(str(files[-1]))
-    out = []
-    for plane in data.planes:
-        if trace_lib.DEVICE_PLANE.match(plane.name):
-            continue
-        for line in plane.lines:
-            out.extend(
-                [e.name, int(e.start_ns), int(e.duration_ns),
-                 f"{plane.name}/{line.name}"]
-                for e in line.events
-                if e.name.startswith((PROGRAM_PREFIX,
-                                      trace_lib.SPAN_PREFIX)))
-    return sorted(out, key=lambda e: e[1])
-
-
-def idle_by_innermost_span(trace: dict, spans: list) -> list:
-    """[span name, idle seconds] on the first device. A gap runs from the
-    end of one program, across the host's phases, into the next dispatch,
-    so every part of it goes to the innermost (shortest) span open at
-    that instant."""
-    planes = trace_lib.device_planes(trace)
-    if not planes:
-        return []
-    t0, t1 = trace_lib.window_ns(trace)
-    busy = trace_lib.union(
-        (a, b) for _, a, b in trace_lib.ops_in_window(trace, planes[0]))
-    gaps = trace_lib.subtract([(t0, t1)], busy)
-    by_length = sorted(((n, s, s + d) for n, s, d, _ in spans
-                        if n != trace_lib.WINDOW_SPAN),
-                       key=lambda x: x[2] - x[1])
-    starts = [a for a, _ in busy]
-    sums, taken = {}, []
-    for name, s, e in by_length:
-        s, e = max(s, t0), min(e, t1)
-        if e <= s:
-            continue
-        near = busy[max(bisect.bisect_left(starts, s) - 1, 0):
-                    bisect.bisect_right(starts, e)]
-        # The span's idle time that no shorter span has claimed.
-        part = trace_lib._length(trace_lib.subtract(
-            trace_lib.subtract([(s, e)], near), trace_lib.union(taken)))
-        if part:
-            sums[name] = sums.get(name, 0) + part
-        taken.append((s, e))
-    rest = trace_lib._length(trace_lib.subtract(gaps,
-                                                trace_lib.union(taken)))
-    if rest:
-        sums["unattributed"] = rest
-    return sorted(([k, v / 1e9] for k, v in sums.items()),
-                  key=lambda kv: -kv[1])
-
-
-def read_trace(trace_dir, trace: dict) -> dict:
-    spans = host_spans(trace_dir)
+def read_trace(trace: dict) -> dict:
+    spans = trace_lib.host_spans(trace)
     program = [s for s in spans if s[0].startswith(PROGRAM_PREFIX)]
     harness = [s for s in spans if s[0].startswith(trace_lib.SPAN_PREFIX)
                and s[0] != trace_lib.WINDOW_SPAN]
     inside = sum(any(h[1] <= s[1] and s[1] + s[2] <= h[1] + h[2]
                      for h in harness) for s in program)
     totals: dict = {}
-    for name, _, dur, _ in spans:
+    for name, _, dur in spans:
         n, t = totals.get(name, (0, 0))
         totals[name] = (n + 1, t + dur)
     device = [(a, b) for p in trace_lib.device_planes(trace)
               for _, a, b in trace_lib.ops_in_window(trace, p)]
     return {
-        "host_lines": sorted({s[3] for s in program}),
+        "host_lines": sorted(
+            f"{plane}/{line}" for plane, lines in trace.items()
+            if not trace_lib.DEVICE_PLANE.match(plane)
+            for line, events in lines.items()
+            if any(e[0].startswith(PROGRAM_PREFIX) for e in events)),
         "program_spans": len(program),
         "program_spans_inside_a_harness_span": inside,
         "spans": {k: {"count": n, "seconds": t / 1e9}
@@ -114,7 +58,7 @@ def read_trace(trace_dir, trace: dict) -> dict:
         "device_op_extent_ns": ([min(a for a, _ in device),
                                  max(b for _, b in device)]
                                 if device else None),
-        "idle_by_innermost_span": idle_by_innermost_span(trace, spans)}
+        "idle_by_innermost_span": trace_lib.idle_gaps_by_span(trace, n=100)}
 
 
 def longest_round(ring: list) -> dict:
@@ -147,7 +91,7 @@ def main(argv=None) -> int:
 
     def load_and_read(trace_dir):
         trace = load(trace_dir)
-        report["trace"] = read_trace(trace_dir, trace)
+        report["trace"] = read_trace(trace)
         return trace
 
     trace_lib.load_xplane = load_and_read

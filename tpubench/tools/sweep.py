@@ -43,7 +43,7 @@ def main(argv=None):
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         run.mix = {**cell.mix, "rate_rps": rate}
         run.plan = traffic.plan_requests(
-            run.mix, args.seconds, args.seed + i, run.cfg["n_vocab"],
+            run.mix, args.seconds, args.seed + i, run.vocab,
             run.engine.max_len)
         host = run.window(args.seconds)
         offered = sum(r.max_new_tokens for r in run.plan) / args.seconds
