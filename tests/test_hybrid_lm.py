@@ -1,0 +1,514 @@
+"""The hybrid LM family on the CPU at rehearsal sizes: each mechanism
+against a plain sequential form or the benchmark's plain reference
+(``tpubench/reference/ling_hybrid.py``), seeded random weights, float32.
+
+Every tolerance stands beside its reason, and for each place where the
+configuration states float32 (the recurrent state, the softmax, the
+router) a CONTROL computes that place in bfloat16 and must FAIL the same
+tolerance: a comparison that a lower precision passes pins nothing.
+"""
+
+import functools
+import hashlib
+import importlib.util
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_dist.models import hybrid
+from tpu_dist.models.policy import policy, set_policy
+from tpu_dist.observe import metrics
+from tpu_dist.parallel.routed_experts import RoutedExperts, route
+from tpu_dist.serve import kv_cache
+from tpu_dist.serve.engine import ServeEngine
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+bf16 = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def family():
+    spec = importlib.util.spec_from_file_location(
+        "ling_hybrid_for_tests", ROOT / "tpubench/reference/ling_hybrid.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    full = json.loads(
+        (ROOT / "tpubench/configs/ling-3.0-flash.json").read_text())
+    return {**full, **full["rehearsal"]}
+
+
+@pytest.fixture(autouse=True)
+def float32_highest():
+    before = policy()
+    set_policy("float32")
+    with jax.default_matmul_precision("highest"):
+        yield
+    set_policy(before)
+
+
+# -- the delta rule ----------------------------------------------------------
+
+
+def _delta_inputs(seed, ln, heads=2, dk=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (ln, heads, dk))) / dk ** 0.5
+    k = unit(jax.random.normal(ks[1], (ln, heads, dk)))
+    v = jax.random.normal(ks[2], (ln, heads, dk))
+    # Log decays down to the configuration's bound of -5 a step: 64 such
+    # steps underflow e^{G}, which the pairwise form must survive.
+    g = -5.0 * jax.random.uniform(ks[3], (ln, heads, dk)) ** 2
+    beta = jax.random.uniform(ks[4], (ln, heads))
+    return q, k, v, g, beta
+
+
+def _sequential(q, k, v, g, beta, s):
+    """The recurrence as the layer's docstring writes it, token by token,
+    in numpy float64."""
+    q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
+    s = np.asarray(s, np.float64).copy()
+    out = np.zeros_like(v)
+    for t in range(q.shape[0]):
+        for h in range(q.shape[1]):
+            sh = np.exp(g[t, h])[:, None] * s[h]
+            kt = k[t, h]
+            sh = sh - beta[t, h] * np.outer(kt, kt @ sh) \
+                + beta[t, h] * np.outer(kt, v[t, h])
+            s[h] = sh
+            out[t, h] = sh.T @ q[t, h]
+    return out, s
+
+
+#: float32 sums over blocks of 64 and a triangular inverse by products
+#: against a float64 loop: errors of 1e-6 on outputs of order 1.
+DELTA_TOL = 2e-5
+
+
+@pytest.mark.parametrize("ln", [8, 64, 200])
+def test_chunked_delta_rule_matches_the_sequential_rule(ln):
+    layer = hybrid.DeltaAttention(num_heads=2, head_dim=16)
+    q, k, v, g, beta = _delta_inputs(ln, ln)
+    s0 = jnp.zeros((2, 16, 16))
+    want_o, want_s = _sequential(q, k, v, g, beta, s0)
+    o, s = layer.scan(q, k, v, g, beta, s0)
+    assert np.abs(np.asarray(o) - want_o).max() < DELTA_TOL
+    assert np.abs(np.asarray(s) - want_s).max() < DELTA_TOL
+
+
+@pytest.mark.parametrize("state_dtype,passes", [
+    pytest.param(jnp.float32, True, id="float32-state"),
+    pytest.param(jnp.bfloat16, False, id="control-bfloat16-state-fails")])
+def test_state_carried_across_chunks_and_into_decode(state_dtype, passes):
+    """Two prefill chunks (70 and 130 tokens), then five one-token steps,
+    the state handed from each to the next as a cache would hold it."""
+    layer = hybrid.DeltaAttention(num_heads=2, head_dim=16)
+    q, k, v, g, beta = _delta_inputs(3, 205)
+    want_o, want_s = _sequential(q, k, v, g, beta, jnp.zeros((2, 16, 16)))
+    held = lambda s: s.astype(state_dtype).astype(jnp.float32)
+    s = jnp.zeros((2, 16, 16))
+    outs = []
+    for a, b in ((0, 70), (70, 200)):
+        o, s = layer.scan(q[a:b], k[a:b], v[a:b], g[a:b], beta[a:b], s)
+        outs.append(o)
+        s = held(s)
+    for t in range(200, 205):
+        o, s = hybrid.delta_rule_step(q[t], k[t], v[t], g[t], beta[t], s)
+        outs.append(o[None])
+        s = held(s)
+    err = max(np.abs(np.asarray(jnp.concatenate(outs)) - want_o).max(),
+              np.abs(np.asarray(s) - want_s).max())
+    assert (err < DELTA_TOL) == passes, err
+
+
+def test_padded_positions_leave_the_state_alone():
+    layer = hybrid.DeltaAttention(num_heads=2, head_dim=16)
+    q, k, v, g, beta = _delta_inputs(5, 64)
+    s0 = jax.random.normal(jax.random.PRNGKey(9), (2, 16, 16))
+    valid = jnp.arange(64) < 40
+    _, s_pad = layer.scan(q, k, v, jnp.where(valid[:, None, None], g, 0.0),
+                          jnp.where(valid[:, None], beta, 0.0), s0)
+    _, s_cut = layer.scan(q[:40], k[:40], v[:40], g[:40], beta[:40], s0)
+    # The same 40 tokens in one block of 64 or one of 40: float32 sums in
+    # another order.
+    assert np.abs(np.asarray(s_pad - s_cut)).max() < DELTA_TOL
+
+
+# -- latent attention ---------------------------------------------------------
+
+
+def _latent_layer():
+    return hybrid.LatentAttention(num_heads=4, kv_rank=32, nope_dim=16,
+                                  rope_dim=8, v_dim=16, rope_theta=6e6)
+
+
+#: The same sums in another order (W_kvb folded into the query and the
+#: output), float32: 1e-6 on outputs of order 0.1.
+LATENT_TOL = 5e-6
+
+
+@pytest.mark.parametrize("probabilities,passes", [
+    pytest.param(None, True, id="float32-softmax"),
+    pytest.param(bf16, False, id="control-bfloat16-softmax-fails")])
+def test_absorbed_decode_over_latent_rows_matches_expanded_attention(
+        monkeypatch, probabilities, passes):
+    layer = _latent_layer()
+    p, _, _ = layer.init(jax.random.PRNGKey(0), (40, 64))
+    p = {**p, "q_norm": 1.0 + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(1), p["q_norm"].shape)}
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 40, 64))
+    want, _ = layer.apply(p, {}, x)                 # expanded, full causal
+    q_nope, q_rope, latent = layer.project(p, x, jnp.arange(40))
+    if probabilities is not None:
+        softmax = jax.nn.softmax
+        monkeypatch.setattr(
+            jax.nn, "softmax",
+            lambda s, axis=-1: probabilities(softmax(s, axis=axis)))
+    # The last token as a decode step sees it: 40 latent rows in a row of
+    # 48, the rest masked.
+    rows = jnp.pad(latent, ((0, 0), (0, 8), (0, 0)))
+    valid = (jnp.arange(48) < 40)[None]
+    o = layer.attend_absorbed(p, q_nope[:, :, -1], q_rope[:, :, -1], rows,
+                              valid)
+    got = layer.output(p, x[:, -1:], o[:, None])
+    err = float(jnp.abs(got[0, 0] - want[0, -1]).max())
+    assert (err < LATENT_TOL) == passes, err
+
+
+def test_rope_turns_interleaved_pairs_and_keeps_relative_position():
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 8))
+    a = hybrid.rope(jnp.tile(x, (6, 1)), jnp.arange(6), 6e6)
+    b = hybrid.rope(jnp.tile(x, (6, 1)), jnp.arange(6) + 11, 6e6)
+    # Pair i keeps its norm, and q_m . k_n depends on m - n alone.
+    pairs = lambda t: t.reshape(6, 4, 2)
+    assert np.allclose(np.linalg.norm(pairs(a), axis=-1),
+                       np.linalg.norm(pairs(jnp.tile(x, (6, 1))), axis=-1),
+                       atol=1e-6)
+    assert np.allclose(a[0] @ a[5], b[0] @ b[5], atol=1e-5)
+    assert np.allclose(a[0], x[0], atol=1e-7)       # position 0: no turn
+
+
+# -- the router and the shares -------------------------------------------------
+
+
+def _route_by_loop(scores, bias, top_k, n_group, topk_group, scaling):
+    scores, bias = np.asarray(scores, np.float64), np.asarray(bias)
+    out = []
+    per = scores.shape[1] // n_group
+    for s in scores:
+        biased = s + bias
+        groups = sorted(range(n_group), key=lambda gi: -np.sort(
+            biased[gi * per:(gi + 1) * per])[-2:].sum())[:topk_group]
+        allowed = [e for gi in groups for e in range(gi * per, (gi + 1) * per)]
+        chosen = sorted(allowed, key=lambda e: -biased[e])[:top_k]
+        total = sum(s[e] for e in chosen)
+        out.append({e: scaling * s[e] / total for e in chosen})
+    return out
+
+
+def _scores(seed, t=64, e=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jax.nn.sigmoid(jax.random.normal(ks[0], (t, e))),
+            0.05 * jax.random.normal(ks[1], (e,)))
+
+
+def test_router_matches_a_loop():
+    scores, bias = _scores(0)
+    chosen, weights = route(scores, bias, top_k=2, n_group=4, topk_group=2,
+                            scaling=2.5)
+    want = _route_by_loop(scores, bias, 2, 4, 2, 2.5)
+    for row, w, ref in zip(np.asarray(chosen), np.asarray(weights), want):
+        assert set(row.tolist()) == set(ref)
+        # Normalised float32 weights: a rounding of 1e-7 on values near 1.
+        assert all(abs(w[j] - ref[int(e)]) < 1e-6 for j, e in enumerate(row))
+    # The bias is there to move choices: without it some tokens differ.
+    unbiased, _ = route(scores, jnp.zeros_like(bias), top_k=2, n_group=4,
+                        topk_group=2, scaling=2.5)
+    assert (np.sort(np.asarray(unbiased)) != np.sort(np.asarray(chosen))
+            ).any()
+
+
+def test_control_bfloat16_scores_change_the_choice():
+    scores, bias = _scores(1, t=512)
+    chosen, _ = route(scores, bias, top_k=2, n_group=4, topk_group=2,
+                      scaling=2.5)
+    low, _ = route(bf16(scores), bf16(bias), top_k=2, n_group=4,
+                   topk_group=2, scaling=2.5)
+    assert (np.sort(np.asarray(low)) != np.sort(np.asarray(chosen))).any()
+
+
+def _expert_layer(held, shared):
+    return RoutedExperts(num_experts=16, experts_held=held, top_k=2,
+                         n_group=4, topk_group=2, ff_dim=32,
+                         shared_ff_dim=shared, routed_scaling=2.5)
+
+
+#: Sixteen experts' float32 products summed in another order.
+SHARE_TOL = 2e-5
+
+
+def test_the_shares_add_up_to_the_uncut_layer(family, cfg):
+    """Four chips hold four experts each: their routed parts, plus the
+    shared expert counted once, are the whole layer, in the program and
+    against the reference's uncut layer."""
+    whole = _expert_layer((0, 16), 32)
+    p, _, _ = whole.init(jax.random.PRNGKey(0), (24, 64))
+    p["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(1), (16,))
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 24, 64))
+    want, stats = whole.forward(p, x)
+    assert int(stats[0]) == int(stats[1]) == 3 * 24 * 2  # all are held here
+    routed_only = {k: v for k, v in p.items() if not k.startswith("shared")}
+    total = hybrid.swiglu(x, p["shared_wg"], p["shared_wu"], p["shared_wd"])
+    held_sum = 0
+    for first in (0, 4, 8, 12):
+        part = {**routed_only, **{k: p[k][first:first + 4]
+                                  for k in ("wg", "wu", "wd")}}
+        y, stats = _expert_layer((first, 4), 0).forward(part, x)
+        total, held_sum = total + y, held_sum + int(stats[1])
+    assert held_sum == 3 * 24 * 2          # every assignment on one chip
+    assert float(jnp.abs(total - want).max()) < SHARE_TOL
+    ref_cfg = {**cfg, "num_experts": 16, "experts_held": [0, 16]}
+    w = {"router": p["router"], "bias": p["bias"], "ewg": p["wg"],
+         "ewu": p["wu"], "ewd": p["wd"], "swg": p["shared_wg"],
+         "swu": p["shared_wu"], "swd": p["shared_wd"]}
+    ref = family._experts(x, w, ref_cfg, None)
+    assert float(jnp.abs(ref - want).max()) < SHARE_TOL
+
+
+def test_a_share_leaves_out_what_absent_experts_would_add(family, cfg):
+    """experts_held = [8, 8) of 16: the program's share is the
+    reference's share, and nothing stands in for the other half. Tokens
+    that are nobody's (an empty slot, a chunk's padding) reach no routed
+    expert, and the counts are of what the grouped product computes."""
+    layer = _expert_layer((8, 8), 32)
+    p, _, _ = layer.init(jax.random.PRNGKey(3), (24, 64))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 64))
+    got, stats = layer.forward(p, x, jnp.ones((2, 24), bool).at[1].set(False))
+    w = {"router": p["router"], "bias": p["bias"], "ewg": p["wg"],
+         "ewu": p["wu"], "ewd": p["wd"], "swg": p["shared_wg"],
+         "swu": p["shared_wu"], "swd": p["shared_wd"]}
+    ref = family._experts(x, w, {**cfg, "experts_held": [8, 8]}, None)
+    assert float(jnp.abs(ref[0] - got[0]).max()) < SHARE_TOL
+    shared = hybrid.swiglu(x[1], p["shared_wg"], p["shared_wu"],
+                           p["shared_wd"])
+    assert float(jnp.abs(shared - got[1]).max()) < SHARE_TOL
+    assert float(jnp.abs(ref[1] - got[1]).max()) > 100 * SHARE_TOL
+    made, held, touched, fullest = (int(v) for v in stats)
+    assert made == 24 * 2 and 0 < held < made    # the valid row only
+    assert 1 <= touched <= 8 and fullest >= held / touched
+    # Every row valid: twice the tokens, and the other row's experts too.
+    _, both = layer.forward(p, x)
+    assert int(both[0]) == 2 * made and int(both[1]) > held
+
+
+# -- the engine ----------------------------------------------------------------
+
+
+def _engine(family, cfg, **kw):
+    model = family.build_program(cfg, 11)
+    args = dict(max_batch=4, max_len=128, paged=True, ragged=True,
+                kv_dtype="fp32", page_size=16, num_pages=40,
+                prefill_chunk=32)
+    args.update(kw)
+    return ServeEngine(model, **args)
+
+
+def _record_logits(engine):
+    """Every logits row a token was picked from, by request id."""
+    rows: dict = {}
+    pick = engine._pick
+
+    def spy(logits):
+        spy.last = np.array(logits)
+        return pick(logits)
+
+    engine._pick = spy
+    record = engine.scheduler.record_token
+
+    def recording(req, token, *, now):
+        rows.setdefault(req.rid, []).append(spy.last)
+        return record(req, token, now=now)
+
+    engine.scheduler.record_token = recording
+    return rows
+
+
+#: Prefill by chunks of 32 and decode through pages and state against one
+#: full-sequence forward: float32 sums in another order through 8 layers,
+#: logits of order 1 (6e-7 measured; a planted state leak reads 0.2-0.3).
+ENGINE_TOL = 2e-5
+
+
+def test_engine_prefill_by_chunks_then_decode_matches_the_full_forward(
+        family, cfg):
+    """Mixed lengths over 4 slots, more requests than slots, so slots are
+    swapped on retirement and reused: a state that leaked from one request
+    to the next, or stayed behind in a swap, would show in the logits."""
+    engine = _engine(family, cfg)
+    rows = _record_logits(engine)
+    swaps = []
+    swap_fn = engine._swap_state_fn
+    engine._swap_state_fn = lambda c, i, j: (swaps.append((int(i), int(j))),
+                                             swap_fn(c, i, j))[1]
+    rng = np.random.default_rng(0)
+    reqs = [engine.submit(rng.integers(0, 512, size=n).tolist(),
+                          max_new_tokens=new)
+            for n, new in [(40, 12), (9, 30), (70, 5), (33, 8), (12, 20),
+                           (64, 9), (5, 6), (90, 3)]]
+    engine.run_until_idle()
+    assert swaps and all(r.status == "done" for r in reqs)
+    assert not engine._paging.state_live.any()
+    params = family.make_params(family.seed_key(11), cfg)
+    forward = jax.jit(functools.partial(family.forward, cfg=cfg))
+    worst = 0.0
+    for r in reqs:
+        seq = r.prompt + r.generated
+        x = np.zeros((1, 128), np.int32)
+        x[0, :len(seq)] = seq
+        ref = np.asarray(forward(params, jnp.asarray(x))[0])
+        ref = ref[len(r.prompt) - 1:len(r.prompt) - 1 + len(r.generated)]
+        worst = max(worst, np.abs(np.stack(rows[r.rid]) - ref).max())
+    assert worst < ENGINE_TOL, worst
+    assert engine.compiled_programs()["paged_decode"] == [4]
+
+
+def test_prefix_caching_asked_for_is_served_without_reuse(family, cfg):
+    """A repeated prompt under ``prefix_caching=True``: the engine says
+    reuse is off and the repeat gets a fresh request's logits, never pages
+    over which its state was not built."""
+    metrics.enable()
+    try:
+        metrics.get_registry().reset()
+        engine = _engine(family, cfg, prefix_caching=True)
+        assert engine._paging.prefix is None
+        rows = _record_logits(engine)
+        prompt = np.random.default_rng(1).integers(0, 512, size=50).tolist()
+        first = engine.submit(prompt, max_new_tokens=6)
+        engine.run_until_idle()
+        again = engine.submit(prompt, max_new_tokens=6)
+        engine.run_until_idle()
+        snap = metrics.get_registry().snapshot()
+    finally:
+        metrics.disable()
+    assert snap["gauges"]["serve.prefix.disabled_recurrent"] == 1.0
+    assert "serve.prefix.hits" not in snap["counters"]
+    assert snap["counters"]["serve.moe.assignments"] > 0
+    assert snap["counters"]["serve.prefill.scan_chunks"] > 0
+    assert first.generated == again.generated
+    assert np.array_equal(np.stack(rows[first.rid]),
+                          np.stack(rows[again.rid]))
+
+
+def test_the_engine_serves_the_weights_it_is_handed(family, cfg):
+    """bfloat16 matrices stay bfloat16 and are not copied: the tree the
+    model's init made IS the engine's."""
+    set_policy("mixed_bfloat16")
+    model = family.build_program(cfg, 11)
+    made = []
+    init = model.init
+    model.init = lambda *a, **k: (made.append(init(*a, **k)), made[-1])[1]
+    engine = ServeEngine(model, max_batch=2, max_len=64, paged=True,
+                         kv_dtype="bf16", num_pages=8)
+    theirs = jax.tree_util.tree_leaves(made[0]["params"])
+    ours = jax.tree_util.tree_leaves(engine.params)
+    assert all(a is b for a, b in zip(theirs, ours))
+    kernel = engine.params["block_2"]["residual_1"]["main"]["routedexperts"]
+    assert kernel["wg"].dtype == jnp.bfloat16
+    assert kernel["router"].dtype == jnp.float32
+    assert engine.cache["state"].dtype == jnp.float32
+    assert engine.cache["latent"].dtype == jnp.bfloat16
+
+
+def test_the_contiguous_engine_refuses_latent_and_state_layers(family, cfg):
+    with pytest.raises(ValueError, match="paged=True"):
+        ServeEngine(family.build_program(cfg, 1), max_batch=2, max_len=64)
+    with pytest.raises(ValueError, match="max_len"):
+        ServeEngine(family.build_program(cfg, 1), max_batch=2, paged=True)
+
+
+def test_plan_gives_each_attention_layer_a_cache_kind(family, cfg):
+    plan = kv_cache.build_plan(family.build_program(cfg, 1))
+    kinds = [op[0] for op in plan.ops if op[0] in ("attn", "latent", "state")]
+    # layer_group_size 3 at rehearsal: latent attention closes each period.
+    assert kinds == ["state", "state", "latent"] * 2 + ["state", "state"]
+    assert (plan.latent_layers, plan.state_layers, plan.moe_layers) == (2, 6, 6)
+    assert plan.latent_width == 40 and plan.recurrent and plan.num_layers == 0
+    pool = jax.eval_shape(lambda: kv_cache.init_page_pool(
+        plan, num_pages=8, page_size=16, dtype=jnp.bfloat16, slots=3))
+    assert pool["latent"].shape == (2, 9, 16, 40)
+    assert pool["state"].shape == (6, 3, 4, 16, 16)
+    assert pool["conv"].shape == (6, 3, 3, 3 * 4 * 16)
+    assert kv_cache.page_nbytes(plan, page_size=16,
+                                dtype=jnp.bfloat16) == 2 * 16 * 40 * 2
+    assert kv_cache.state_nbytes_per_slot(plan) == 6 * 4 * (
+        4 * 16 * 16 + 3 * 192)
+
+
+def test_a_hybrid_model_is_saved_and_loaded_layer_for_layer(family, cfg):
+    """``save_model`` writes layers by class name: the new layers (and the
+    expert layer beside them) resolve on the way back."""
+    from tpu_dist.models import serialize
+
+    def flat(layers):
+        for layer in layers:
+            yield layer
+            for name in ("layers", "main"):
+                yield from flat(getattr(layer, name, ()))
+
+    model = family.build_program(cfg, 1)
+    seen = set()
+    for layer in flat(model.layers):
+        again = serialize.layer_from_config(serialize.layer_config(layer))
+        assert again == layer
+        seen.add(type(layer).__name__)
+    assert {"StreamEmbedding", "RMSNorm", "GatedMLP", "LatentAttention",
+            "DeltaAttention", "RoutedExperts"} <= seen
+
+
+# -- the GPT-2 family's programs ---------------------------------------------------
+
+#: sha256 (first 16 hex digits) of the lowered text of the two programs the
+#: GPT-2 serve cells run, at a small size, as the PARENT commit (PR 27)
+#: lowers them: the hybrid family's cache kinds and the one positions
+#: helper leave them byte for byte. To renew after a deliberate change:
+#: run this test's ``_digests`` on the commit before it.
+PARENT_DIGESTS = {
+    "int8": ("e8f1b89dcbc4d35b", "2b1131b1fd2308b7"),
+    "bfloat16": ("b7e9c46cc4e32c1a", "23f7f9cff5af7765"),
+}
+
+
+def _digests(dtype):
+    from tpu_dist.models.transformer import build_transformer_lm
+
+    set_policy("mixed_bfloat16")
+    model = build_transformer_lm(512, 128, d_model=64, depth=2, num_heads=4,
+                                 ff_dim=256)
+    plan = kv_cache.build_plan(model)
+    params = jax.eval_shape(lambda: model.init(0))["params"]
+    pool = jax.eval_shape(lambda: kv_cache.init_page_pool(
+        plan, num_pages=24, page_size=16, dtype=dtype))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    decode = jax.jit(functools.partial(
+        kv_cache.paged_decode_ragged, plan, walk=False)).lower(
+            params, pool, i32(4, 8), i32(4), i32(4),
+            jax.ShapeDtypeStruct((4,), jnp.bool_)).as_text()
+    prefill = jax.jit(functools.partial(kv_cache.paged_prefill, plan)).lower(
+        params, pool, i32(8), i32(32), i32(), i32()).as_text()
+    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
+                 for t in (decode, prefill))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16],
+                         ids=["int8-pool", "bfloat16-pool"])
+def test_gpt2_paged_programs_lower_as_the_parent_lowered_them(dtype):
+    with jax.default_matmul_precision("default"):
+        assert _digests(dtype) == PARENT_DIGESTS[jnp.dtype(dtype).name]

@@ -359,6 +359,42 @@ class TestServeEngine:
             assert req.generated == ref, f"request {req.rid}"
             assert req.status == "done"
 
+    @pytest.mark.parametrize("trained_first", [False, True])
+    def test_fit_beside_an_engine_leaves_it_servable(self, trained_first):
+        """The trainer donates its variables to every step. An engine made
+        before the first ``fit()`` owns weights of its own making; one
+        made after it serves a snapshot: either way a later ``fit()``
+        deletes nothing the engine holds, and the engine goes on serving
+        the weights it was made with."""
+        import tpu_dist as td
+
+        model = build_transformer_lm(VOCAB, 16, d_model=16, depth=1,
+                                     num_heads=2)
+        model.compile(
+            loss=td.ops.SparseCategoricalCrossentropy(from_logits=True),
+            optimizer="sgd")
+        x = (np.arange(8 * 16).reshape(8, 16) % VOCAB).astype(np.int32)
+        ds = td.data.Dataset.from_tensor_slices((x, x)).batch(8)
+        if trained_first:
+            model.fit(ds, epochs=1, steps_per_epoch=1, verbose=0)
+        engine = ServeEngine(model, max_batch=2, max_len=16)
+        if trained_first:
+            # This backend donates nothing, so say it outright: no leaf
+            # of the engine's is one the trainer will hand to its step.
+            theirs = {id(leaf) for leaf in jax.tree_util.tree_leaves(
+                model.variables["params"])}
+            assert not any(id(leaf) in theirs for leaf in
+                           jax.tree_util.tree_leaves(engine.params))
+        first = engine.submit([1, 2, 3], max_new_tokens=4)
+        engine.run_until_idle()
+        model.fit(ds, epochs=1, steps_per_epoch=2, verbose=0)
+        again = engine.submit([1, 2, 3], max_new_tokens=4)
+        engine.run_until_idle()
+        assert again.status == "done"
+        assert again.generated == first.generated
+        assert not any(leaf.is_deleted() for leaf in
+                       jax.tree_util.tree_leaves(engine.params))
+
     def test_steady_state_never_retraces(self):
         model, _ = _lm()
         engine = ServeEngine(model, max_batch=4, max_len=32)

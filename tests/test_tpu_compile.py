@@ -168,3 +168,63 @@ def test_paged_ragged_decode_walks_pages_and_copies_no_pool(
     # planes' (S1 b).
     assert not any(c.startswith("= s8") for c in copies)
     assert len(copies) <= 4
+
+
+# -- the hybrid family's decode program at its published widths -----------------
+
+
+def test_hybrid_decode_program_compiles_at_published_widths(v5e_chip):
+    """One whole period of the ``ling-3.0-flash`` cut (two dense layers,
+    then KDA x 3, MLA, so six layers: every kind of layer), 64 slots, 8192
+    latent pages: the grouped product over the experts held lowers to the
+    chip's ragged-dot kernel, the logits stay ``f32[slots, vocabulary]``
+    (how the trace readers find the decode program), and the program fits
+    beside its weights."""
+    import json
+    import pathlib
+
+    from tpu_dist.models.hybrid import build_hybrid_lm
+    from tpu_dist.models.policy import policy, set_policy
+    from tpu_dist.serve import kv_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads(
+        (root / "tpubench/configs/ling-3.0-flash.json").read_text())
+    cfg = {**cfg, "num_hidden_layers": 6}
+    slots, pages, page_size, max_pages = 64, 8192, 16, 128
+    before = policy()
+    set_policy("mixed_bfloat16")
+    try:
+        model = build_hybrid_lm(cfg)
+        plan = kv_cache.build_plan(model)
+
+        def on_chip(tree, matrices=None):
+            return jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(
+                    s.shape, matrices if matrices and s.ndim >= 2
+                    and s.shape[-1] > 512 else s.dtype, sharding=v5e_chip),
+                tree)
+
+        # Matrices as the benchmark's family hands them: bfloat16.
+        params = on_chip(jax.eval_shape(lambda: model.init(0))["params"],
+                         jnp.bfloat16)
+        pool = on_chip(jax.eval_shape(lambda: kv_cache.init_page_pool(
+            plan, num_pages=pages, page_size=page_size, dtype=jnp.bfloat16,
+            slots=slots)))
+        row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=v5e_chip)
+        tables = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                      sharding=v5e_chip)
+        compiled = jax.jit(
+            functools.partial(kv_cache.paged_decode_ragged, plan, walk=False),
+            donate_argnums=(1,)).lower(
+                params, pool, tables, row(jnp.int32), row(jnp.int32),
+                row(jnp.bool_)).compile()
+    finally:
+        set_policy(before)
+    text = compiled.as_text()
+    assert pool["latent"].shape == (1, pages + 1, page_size, 576)
+    assert pool["state"].shape == (5, slots, 32, 128, 128)
+    # Four expert layers: three grouped products and their metadata each.
+    assert text.count('op_name="ragged-dot') >= 4 * 4
+    assert f"f32[{slots},{cfg['vocab_size']}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

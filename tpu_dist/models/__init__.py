@@ -25,6 +25,13 @@ from tpu_dist.models.transformer import (
     TransformerBlock,
     build_transformer_lm,
 )
+from tpu_dist.models.hybrid import (
+    DeltaAttention,
+    GatedMLP,
+    LatentAttention,
+    RMSNorm,
+    build_hybrid_lm,
+)
 from tpu_dist.models.cnn import build_and_compile_cnn_model, build_cnn_model
 from tpu_dist.models.policy import compute_dtype, policy, set_policy
 from tpu_dist.models.resnet import ResNet18, ResNet50
@@ -52,6 +59,11 @@ __all__ = [
     "PositionalEmbedding",
     "TransformerBlock",
     "build_transformer_lm",
+    "DeltaAttention",
+    "GatedMLP",
+    "LatentAttention",
+    "RMSNorm",
+    "build_hybrid_lm",
     "save_model",
     "ResNet18",
     "ResNet50",

@@ -88,11 +88,14 @@ def layer_config(layer) -> dict:
 
 
 def layer_from_config(spec: dict):
+    from tpu_dist.models import hybrid as hybrid_mod
     from tpu_dist.models import layers as layers_mod
     from tpu_dist.models import transformer as transformer_mod
+    from tpu_dist.parallel import routed_experts as experts_mod
 
-    cls = getattr(layers_mod, spec["class"],
-                  getattr(transformer_mod, spec["class"], None))
+    cls = next((getattr(m, spec["class"]) for m in (
+        layers_mod, transformer_mod, hybrid_mod, experts_mod)
+        if hasattr(m, spec["class"])), None)
     # Layer subclasses only — the modules also import unrelated classes
     # (PartitionSpec, ...) that a crafted model.json must not reach.
     if (cls is None or not isinstance(cls, type)

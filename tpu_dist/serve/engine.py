@@ -43,7 +43,14 @@ boundaries: ``serve.step.rounds``, ``serve.upload.bytes`` and
 ``.pages_addressed`` (the pages a paged decode step's attention reads
 against those its table rows address); and once a round
 ``serve.step.host_s``, the
-round's duration less its two waits for the device. With whole-prompt
+round's duration less its two waits for the device. A model with routed
+experts adds ``serve.moe.assignments``, ``.assignments_held``,
+``.experts_touched`` and the distribution ``serve.moe.load_max`` (device
+scalars of the decode steps that ride the logits' read-back); one with
+recurrent layers adds ``serve.prefill.scan_chunks``, the gauges
+``serve.state.slots_live``, ``serve.state.bytes`` and
+``serve.prefix.disabled_recurrent``, and a ``serve.step.state_swap`` span
+where compaction moves a slot's state on the device. With whole-prompt
 prefill (``prefill_chunk=0``) the prompt is one chunk and its span lies
 inside ``serve.step.admit``, whose self time is then the admission alone.
 
@@ -71,8 +78,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_dist.models import hybrid
 from tpu_dist.models.model import Sequential
 from tpu_dist.observe import metrics
+from tpu_dist.parallel import mesh as mesh_lib
 from tpu_dist.parallel.strategy import get_strategy
 from tpu_dist.serve import kv_cache, paging
 from tpu_dist.serve import journal as journal_lib
@@ -192,7 +201,11 @@ class ServeEngine:
         mode: sizes ``num_pages`` to the budget when ``num_pages`` is
         not given, else guards the explicit pool the same way.
       prefix_caching: paged mode only — disable to keep paging without
-        cross-request prefix sharing (parity baselines use this).
+        cross-request prefix sharing (parity baselines use this). A model
+        with recurrent layers is served with it OFF whatever is passed:
+        a slot's state is not in its pages, so shared pages would be
+        attached to a state that was not built over them (logged once;
+        gauge ``serve.prefix.disabled_recurrent``).
       prefill_chunk: when > 0, split each admitted prompt's prefill into
         chunks of this many positions (power of two >= 8) and interleave
         them with decode steps, so one long prompt no longer stalls
@@ -251,6 +264,14 @@ class ServeEngine:
                  ragged: bool = False):
         self.model = model
         self.plan = kv_cache.build_plan(model)
+        if (self.plan.latent_layers or self.plan.state_layers) and not paged:
+            raise ValueError(
+                "serve: latent pages and per-slot recurrent state are kinds "
+                "of the paged cache — pass paged=True")
+        if max_len is None and self.plan.max_position >= 2 ** 30:
+            raise ValueError(
+                "serve: the model has no positional table to bound a slot "
+                "— pass max_len")
         self.max_len = int(max_len or self.plan.max_position)
         if self.max_len > self.plan.max_position:
             raise ValueError(
@@ -288,11 +309,13 @@ class ServeEngine:
             self.strategy = model.strategy or get_strategy()
 
         variables = model.variables
-        params = (variables["params"] if variables is not None
-                  else model.init(seed)["params"])
-        # Same mesh placement training uses; on the default single-device
-        # strategy this is a no-op device_put.
-        self.params = self.strategy.replicate(params)
+        if variables is not None:
+            # A trainer holds these and donates them to its next step:
+            # the engine serves a snapshot of its own, through the
+            # placement training uses.
+            self.params = self.strategy.replicate(variables["params"])
+        else:
+            self.params = self._own(model.init(seed)["params"])
         self.paged = bool(paged)
         self.page_size = int(page_size)
         self.ragged = bool(ragged)
@@ -317,6 +340,17 @@ class ServeEngine:
             cache_dtype = resolved
         self._kv_quant = (self.paged
                           and jnp.dtype(cache_dtype) == jnp.int8)
+        if self.paged and self.plan.recurrent:
+            # Pages hold what attention reads; a recurrent layer's state
+            # is built over every token before it. Shared pages handed to
+            # a slot whose state was not built over them would serve wrong
+            # tokens, and this engine snapshots no state: no reuse.
+            if prefix_caching:
+                logger.info(
+                    "serve: the model has recurrent layers — prefix reuse "
+                    "is off (a slot's state is not in its pages)")
+            prefix_caching = False
+            metrics.set_gauge("serve.prefix.disabled_recurrent", 1.0)
         if self.paged:
             max_pages = -(-self.max_len // self.page_size)
             if num_pages is None and budget_bytes is not None:
@@ -334,7 +368,7 @@ class ServeEngine:
             self.cache = self.strategy.replicate(kv_cache.init_page_pool(
                 self.plan, num_pages=self.num_pages,
                 page_size=self.page_size, dtype=cache_dtype,
-                budget_bytes=budget_bytes))
+                budget_bytes=budget_bytes, slots=self.max_batch))
             # Decided once, here: the decode programs are built with the
             # answer and ``decode_prep`` counts pages by it.
             self._walks_pages = kv_cache.walks_pages(
@@ -349,7 +383,9 @@ class ServeEngine:
             self._paging = paging.PagedKVState(
                 num_pages=self.num_pages, page_size=self.page_size,
                 slots=self.max_batch, max_pages=max_pages,
-                bytes_per_token=per_token, prefix_caching=prefix_caching)
+                bytes_per_token=per_token, prefix_caching=prefix_caching,
+                state_bytes_per_slot=kv_cache.state_nbytes_per_slot(
+                    self.plan))
             logger.info(
                 "serve: paged — %d slots, %d pages x %d positions "
                 "(+scratch), pool %.1f MiB (%s), prefix caching %s, "
@@ -390,6 +426,8 @@ class ServeEngine:
         #: int8 prefill errors still on the device, read with the next
         #: read-back that happens anyway (recording runs only).
         self._pending_qerr: list = []
+        #: Expert-routing counts of decode steps, likewise.
+        self._pending_moe: list = []
 
         # CPU XLA has no buffer donation — donating there only logs
         # warnings; on TPU the cache updates in place (no per-step copy).
@@ -407,6 +445,10 @@ class ServeEngine:
         self._chunk_fns: dict[int, callable] = {}
         self._copy_fn = jax.jit(kv_cache.copy_page,
                                 donate_argnums=(0,) if donate else ())
+        #: Moves the per-slot recurrent state when compaction swaps slots
+        #: (their pages move by a host pointer swap).
+        self._swap_state_fn = jax.jit(kv_cache.swap_state,
+                                      donate_argnums=(0,) if donate else ())
 
         # -- resilience state --------------------------------------------
         self.max_ttft_s = None if max_ttft_s is None else float(max_ttft_s)
@@ -433,6 +475,21 @@ class ServeEngine:
         if self.journal is not None:
             self._recover_from_journal()
         metrics.set_gauge("serve.ready", 1.0)
+
+    def _own(self, params):
+        """Weights this engine has just initialised, which nobody else
+        holds. Leaves that ``model.init`` left on the device, replicated
+        over this mesh, are kept as they are, in the dtype they have: no
+        trip through the host and no second copy beside weights that fill
+        most of the chip. Anything else goes through
+        ``strategy.replicate``."""
+        want = mesh_lib.replicated(self.strategy.mesh)
+        if jax.process_count() == 1 and all(
+                isinstance(x, jax.Array) and x.is_fully_addressable
+                and x.sharding.is_equivalent_to(want, x.ndim)
+                for x in jax.tree_util.tree_leaves(params)):
+            return params
+        return self.strategy.replicate(params)
 
     # -- crash recovery -------------------------------------------------------
 
@@ -782,9 +839,13 @@ class ServeEngine:
             return
         i, j = swap
         if self.paged:
-            # Compaction under paging is a host page-table pointer swap —
-            # no device program runs.
+            # Compaction under paging is a host page-table pointer swap;
+            # a recurrent state is held by slot and moves on the device.
             self._paging.swap_slots(i, j)
+            if self.plan.recurrent:
+                with profiler.span("serve.step.state_swap", self._round):
+                    self.cache = self._swap_state_fn(
+                        self.cache, jnp.int32(i), jnp.int32(j))
         else:
             self.cache = self._swap_fn(self.cache, jnp.int32(i),
                                        jnp.int32(j))
@@ -851,6 +912,11 @@ class ServeEngine:
             self.cache, logits = out
         return logits
 
+    def _state_slot(self, req: Request) -> tuple:
+        """The extra argument of a paged prefill program whose plan has
+        state layers: the slot whose state the chunk carries."""
+        return (jnp.int32(req.slot),) if self.plan.recurrent else ()
+
     def _to_host(self, logits, span_name: str) -> np.ndarray:
         """The one place the host waits for the device: ``np.asarray`` of
         a program's logits, under ``span_name``. Prefill errors parked by
@@ -863,7 +929,14 @@ class ServeEngine:
             metrics.inc("serve.logits.bytes", logits.nbytes)
             for qerr in jax.device_get(self._pending_qerr):
                 metrics.observe_value("serve.kv.quant_error", float(qerr))
+            for made, held, touched, fullest in jax.device_get(
+                    self._pending_moe):
+                metrics.inc("serve.moe.assignments", int(made))
+                metrics.inc("serve.moe.assignments_held", int(held))
+                metrics.inc("serve.moe.experts_touched", int(touched))
+                metrics.observe_value("serve.moe.load_max", float(fullest))
         self._pending_qerr.clear()
+        self._pending_moe.clear()
         return logits
 
     def _upload(self, *arrays) -> list:
@@ -903,7 +976,8 @@ class ServeEngine:
                 row, toks = self._upload(
                     self._paging.allocator.table[req.slot], tokens)
                 out = fn(self.params, self.cache, row, toks,
-                         jnp.int32(plen), jnp.int32(setup.start))
+                         jnp.int32(plen), jnp.int32(setup.start),
+                         *self._state_slot(req))
                 logits = self._unpack_prefill(out)
                 self._paging.register_prefill(req.slot, req.prompt)
             else:
@@ -981,8 +1055,13 @@ class ServeEngine:
                 row, toks = self._upload(
                     self._paging.allocator.table[req.slot], tokens)
                 out = fn(self.params, self.cache, row, toks,
-                         jnp.int32(end), jnp.int32(startpos))
+                         jnp.int32(end), jnp.int32(startpos),
+                         *self._state_slot(req))
                 logits = self._unpack_prefill(out)
+                if self.plan.recurrent:
+                    metrics.inc("serve.prefill.scan_chunks",
+                                self.plan.state_layers
+                                * -(-pad // hybrid.SCAN_BLOCK))
             else:
                 fn = self._chunk_fn(pad)
                 self.cache, logits = fn(self.params, self.cache,
@@ -1127,7 +1206,9 @@ class ServeEngine:
             timer.start()
         try:
             with profiler.span("serve.step.decode_dispatch", rnd):
-                self.cache, logits = fn(self.params, self.cache, *args)
+                self.cache, logits, *moe = fn(self.params, self.cache, *args)
+            if moe and metrics.enabled():
+                self._pending_moe.append(moe[0])
             if self.fault_injector is not None:
                 # Inside the watchdog window on purpose: a decode_stall
                 # fault must look exactly like a hung runtime call.

@@ -33,8 +33,16 @@ reuses each layer's own ``apply`` (LayerNorm/Dense/Embedding are
 position-wise) and ``MultiHeadAttention._heads`` projection — the decode
 path shares weights *and code* with training, which is what makes the
 equivalence test meaningful. Models outside the servable family
-(pipelined stages, MoE blocks, custom ``attention_fn`` hooks, non-causal
-attention) are rejected at plan-build time with a pointed error.
+(pipelined stages, the GShard ``MixtureOfExperts``, custom
+``attention_fn`` hooks, non-causal attention) are rejected at plan-build
+time with a pointed error.
+
+The plan gives every attention layer a **cache kind**: K/V pages
+(``MultiHeadAttention``), latent pages (``LatentAttention``: one row a
+token for all heads, the same tables) or a per-slot recurrent state
+(``DeltaAttention``). The last two exist on the paged path only (see
+"latent pages and per-slot state" below); ``RoutedExperts`` is a
+token-wise op that also returns its routing counts.
 """
 
 from __future__ import annotations
@@ -46,6 +54,9 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from tpu_dist.models.hybrid import (DeltaAttention, GatedMLP,
+                                    LatentAttention, RMSNorm, causal_conv,
+                                    delta_rule_step)
 from tpu_dist.models.layers import (Block, Dense, Layer, Residual,
                                     _activation)
 from tpu_dist.models.model import Sequential
@@ -54,14 +65,17 @@ from tpu_dist.models.transformer import (Embedding, LayerNormalization,
                                          PositionalEmbedding,
                                          _default_attention)
 from tpu_dist.ops import paged_attention
+from tpu_dist.parallel.routed_experts import RoutedExperts
 
 # -- plan: a flat, servable description of the Sequential ---------------------
 
 #: Plan op tags. Ops are plain tuples so the plan stays hashable/static
-#: under jit closures: ("embed"|"pos"|"point", layer, path),
-#: ("attn", layer, path, cache_layer_index),
-#: ("res_start",), ("res_end", activation_name).
-_POINTWISE = (LayerNormalization, Dense)
+#: under jit closures: ("embed"|"pos"|"point"|"moe", layer, path),
+#: ("attn"|"latent"|"state", layer, path, index among its cache kind),
+#: ("res_start",), ("res_end", activation_name). The last three attention
+#: tags are the CACHE KINDS: K/V pages, latent pages (one row a token
+#: shared by all heads), and a per-slot recurrent state.
+_POINTWISE = (LayerNormalization, Dense, RMSNorm, GatedMLP)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,19 +83,38 @@ class DecodePlan:
     """Static decode description of one servable Sequential."""
 
     ops: tuple
-    num_layers: int  #: attention layers == KV-cache depth
+    num_layers: int  #: K/V attention layers == KV-cache depth
     num_heads: int
     key_dim: int
     max_position: int  #: PositionalEmbedding.max_len — hard cap on length
     vocab_size: int
+    latent_layers: int = 0  #: layers caching one latent row a token
+    latent_width: int = 0
+    state_layers: int = 0  #: layers holding a per-slot recurrent state
+    state_heads: int = 0
+    state_dim: int = 0  #: the state is [heads, state_dim, state_dim]
+    conv_taps: int = 0  #: the state layers' short convolution
+    moe_layers: int = 0
+
+    @property
+    def conv_width(self) -> int:
+        return 3 * self.state_heads * self.state_dim
+
+    @property
+    def recurrent(self) -> bool:
+        """A slot holds state that its pages do not: prefix reuse would
+        attach pages whose matching state the slot does not have."""
+        return self.state_layers > 0
 
 
 def _unsupported(layer: Layer, why: str) -> TypeError:
     return TypeError(
         f"serve: {type(layer).__name__} is not servable ({why}); the KV-"
-        "cache decode path covers the build_transformer_lm family — "
-        "token/positional embeddings, pre-LN blocks with default causal "
-        "attention, LayerNorm and Dense layers")
+        "cache decode path covers token/positional embeddings, pre-norm "
+        "residual blocks, LayerNorm/RMSNorm, Dense and gated MLPs, default "
+        "causal attention over K/V pages, and on the paged path latent "
+        "attention over latent pages, delta-rule attention over a per-slot "
+        "state, and routed experts held by share")
 
 
 def build_plan(model: Sequential) -> DecodePlan:
@@ -91,7 +124,10 @@ def build_plan(model: Sequential) -> DecodePlan:
             f"serve supports Sequential models, got {type(model).__name__}")
     ops: list = []
     attn_layers: list[MultiHeadAttention] = []
+    latent_layers: list[LatentAttention] = []
+    state_layers: list[DeltaAttention] = []
     pos_layers: list[PositionalEmbedding] = []
+    moe_layers: list[RoutedExperts] = []
 
     def walk(layers, names, path):
         for layer, name in zip(layers, names):
@@ -113,6 +149,15 @@ def build_plan(model: Sequential) -> DecodePlan:
                         "etc.) have no cache-aware decode path")
                 ops.append(("attn", layer, p, len(attn_layers)))
                 attn_layers.append(layer)
+            elif isinstance(layer, LatentAttention):
+                ops.append(("latent", layer, p, len(latent_layers)))
+                latent_layers.append(layer)
+            elif isinstance(layer, DeltaAttention):
+                ops.append(("state", layer, p, len(state_layers)))
+                state_layers.append(layer)
+            elif isinstance(layer, RoutedExperts):
+                ops.append(("moe", layer, p))
+                moe_layers.append(layer)
             elif isinstance(layer, Residual):
                 if layer.shortcut:
                     raise _unsupported(
@@ -129,24 +174,38 @@ def build_plan(model: Sequential) -> DecodePlan:
                 raise _unsupported(layer, "no decode rule for this layer")
 
     walk(model.layers, model.layer_names, ())
-    if not attn_layers:
+    if not (attn_layers or latent_layers or state_layers):
         raise TypeError("serve: model has no attention layers to cache")
-    heads = {(l.num_heads, l.key_dim) for l in attn_layers}
+    heads = {(l.num_heads, l.key_dim) for l in attn_layers} or {(0, 0)}
     if len(heads) > 1:
         raise TypeError(
             f"serve: attention layers disagree on (num_heads, key_dim) "
             f"({sorted(heads)}); a stacked KV cache needs uniform shapes")
+    widths = {l.latent_width for l in latent_layers} or {0}
+    states = ({(l.num_heads, l.head_dim, l.conv_size) for l in state_layers}
+              or {(0, 0, 0)})
+    if len(widths) > 1 or len(states) > 1:
+        raise TypeError(
+            "serve: latent or state layers disagree on their shapes "
+            f"({sorted(widths)}, {sorted(states)}); a cache kind is one "
+            "stacked array")
     last = model.layers[-1]
     if not isinstance(last, Dense):
         raise TypeError(
             "serve: expected a Dense vocabulary head as the final layer, "
             f"got {type(last).__name__}")
     (num_heads, key_dim), = heads
+    (state_heads, state_dim, conv_taps), = states
     max_position = min((l.max_len for l in pos_layers),
                       default=2 ** 30)
     return DecodePlan(ops=tuple(ops), num_layers=len(attn_layers),
                       num_heads=num_heads, key_dim=key_dim,
-                      max_position=max_position, vocab_size=last.units)
+                      max_position=max_position, vocab_size=last.units,
+                      latent_layers=len(latent_layers),
+                      latent_width=widths.pop(),
+                      state_layers=len(state_layers),
+                      state_heads=state_heads, state_dim=state_dim,
+                      conv_taps=conv_taps, moe_layers=len(moe_layers))
 
 
 def init_cache(plan: DecodePlan, *, max_batch: int, max_len: int,
@@ -224,6 +283,26 @@ def _attn_out(layer: MultiHeadAttention, p, out):
     return y
 
 
+def _learned_positions(params, path, x, pos):
+    """The learned-table rule of the ``"pos"`` op, once: ``x`` gains the
+    table's rows at ``pos``, clamped to the table. ``pos`` holds absolute
+    positions: ``[L]`` for one sequence ``x`` [1, L, D], ``[b]`` for one
+    token a row ``x`` [b, 1, D]. Every body computes ``pos`` once and hands
+    the same vector here and to the layers that rotate by it (RoPE in
+    ``LatentAttention.project``): positions have one source."""
+    table = _params_at(params, path)["table"]
+    at = jnp.minimum(pos, table.shape[0] - 1)
+    rows = table[at].astype(x.dtype)
+    return x + (rows[None] if x.shape[0] == 1 and pos.shape[0] == x.shape[1]
+                else rows[:, None, :])
+
+
+def _paged_only(op):
+    return TypeError(
+        f"serve: {type(op[1]).__name__} keeps its cache in latent pages or "
+        "per-slot state, which the paged engine manages — pass paged=True")
+
+
 # -- prefill ------------------------------------------------------------------
 
 
@@ -271,9 +350,9 @@ def prefill(plan: DecodePlan, params, cache: dict, tokens, length, slot,
                     (idx, slot, 0, 0, 0))
             x = _attn_out(layer, p, out)
         elif tag == "pos":
-            _, layer, path = op
-            table = _params_at(params, path)["table"]
-            x = x + table[:pad_len].astype(x.dtype)
+            x = _learned_positions(params, op[2], x, jnp.arange(pad_len))
+        elif tag in ("latent", "state", "moe"):
+            raise _paged_only(op)
         else:  # "embed" / "point": the layer's own stateless apply
             _, layer, path = op
             x, _ = layer.apply(_params_at(params, path), {}, x)
@@ -332,10 +411,9 @@ def prefill_chunk_step(plan: DecodePlan, params, cache: dict, tokens,
         elif tag == "res_end":
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
-            _, layer, path = op
-            table = _params_at(params, path)["table"]
-            at = jnp.minimum(pos, table.shape[0] - 1)
-            x = x + table[at].astype(x.dtype)[None]
+            x = _learned_positions(params, op[2], x, pos)
+        elif tag in ("latent", "state", "moe"):
+            raise _paged_only(op)
         elif tag == "attn":
             _, layer, path, idx = op
             p = _params_at(params, path)
@@ -400,9 +478,9 @@ def decode_step(plan: DecodePlan, params, cache: dict, tokens, lengths,
         elif tag == "res_end":
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
-            _, layer, path = op
-            table = _params_at(params, path)["table"]
-            x = x + table[pos].astype(x.dtype)[:, None, :]
+            x = _learned_positions(params, op[2], x, pos)
+        elif tag in ("latent", "state", "moe"):
+            raise _paged_only(op)
         elif tag == "attn":
             _, layer, path, idx = op
             p = _params_at(params, path)
@@ -512,12 +590,21 @@ def page_nbytes(plan: DecodePlan, *, page_size: int,
                 dtype=jnp.float32) -> int:
     """HBM one page pins across every layer, k and v. An int8 page also
     carries its fp32 scale rows (k and v, per head per position)."""
-    n = 2 * plan.num_layers * plan.num_heads * page_size * plan.key_dim
+    n = (2 * plan.num_layers * plan.num_heads * page_size * plan.key_dim
+         + plan.latent_layers * page_size * plan.latent_width)
     dt = jnp.dtype(dtype)
     if dt == jnp.int8:
         scales = 2 * plan.num_layers * plan.num_heads * page_size * 4
         return n * dt.itemsize + scales
     return n * dt.itemsize
+
+
+def state_nbytes_per_slot(plan: DecodePlan) -> int:
+    """HBM one slot's recurrent layers pin beside its pages: the float32
+    state and the convolution's tail, a state layer."""
+    s = plan.state_heads * plan.state_dim * plan.state_dim
+    tail = max(plan.conv_taps - 1, 0) * plan.conv_width
+    return plan.state_layers * (s + tail) * 4
 
 
 def page_pool_nbytes(plan: DecodePlan, *, num_pages: int, page_size: int,
@@ -535,11 +622,17 @@ def pages_for_budget(plan: DecodePlan, *, page_size: int, budget_bytes: int,
 
 
 def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
-                   dtype=jnp.float32,
-                   budget_bytes: Optional[int] = None) -> dict:
+                   dtype=jnp.float32, budget_bytes: Optional[int] = None,
+                   slots: int = 0) -> dict:
     """Zeros page pool pytree: ``k``/``v`` of
     ``[num_layers, num_pages + 1, page_size, num_heads * key_dim]`` —
-    the extra row is the write-off scratch page.
+    the extra row is the write-off scratch page. A plan with latent
+    layers gets ``latent`` ``[latent_layers, num_pages + 1, page_size,
+    latent_width]`` (the same pages, addressed by the same tables); one
+    with state layers gets, for ``slots`` slots, ``state``
+    ``[state_layers, slots, heads, dim, dim]`` and ``conv``
+    ``[state_layers, slots, taps - 1, conv_width]``, float32 and indexed
+    by SLOT, not by page.
 
     Like :func:`init_cache`, ``budget_bytes`` raises a loud sizing error
     (how many pages DO fit) instead of deferring to an XLA OOM.
@@ -559,6 +652,8 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
                 f"{page_size} positions (plus the scratch page) but "
                 f"budget_bytes={budget_bytes} — the budget fits {fits} "
                 "page(s). Lower num_pages/page_size or raise the budget.")
+    if plan.latent_layers or plan.state_layers:
+        return _init_hybrid_pool(plan, num_pages, page_size, dtype, slots)
     shape = (plan.num_layers, num_pages + 1, page_size,
              plan.num_heads * plan.key_dim)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -570,6 +665,46 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
         pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return pool
+
+
+def _init_hybrid_pool(plan: DecodePlan, num_pages: int, page_size: int,
+                      dtype, slots: int) -> dict:
+    if plan.num_layers:
+        raise TypeError(
+            "serve: K/V attention beside latent or state layers in one "
+            "model has no pool layout yet")
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError(
+            "serve: int8 pages carry per-head scale rows; a latent page "
+            "has no heads — use kv_dtype 'bf16' or 'fp32'")
+    if not plan.latent_layers:
+        raise TypeError(
+            "serve: a model whose every attention layer is recurrent has "
+            "nothing to page; the paged engine needs a latent layer")
+    pool = {"latent": jnp.zeros(
+        (plan.latent_layers, num_pages + 1, page_size, plan.latent_width),
+        dtype)}
+    if plan.state_layers:
+        if slots < 1:
+            raise ValueError("serve: a recurrent state is held by slot — "
+                             "pass slots")
+        dim = plan.state_dim
+        pool["state"] = jnp.zeros(
+            (plan.state_layers, slots, plan.state_heads, dim, dim),
+            jnp.float32)
+        pool["conv"] = jnp.zeros(
+            (plan.state_layers, slots, plan.conv_taps - 1, plan.conv_width),
+            jnp.float32)
+    return pool
+
+
+#: Pool entries addressed by page (the rest are indexed by slot).
+_PAGED_ENTRIES = ("k", "v", "k_scale", "v_scale", "latent")
+
+
+def _pages(pool: dict):
+    """The array whose shape gives the pool's pages and page size."""
+    return pool["k"] if "k" in pool else pool["latent"]
 
 
 def _gather_pages(pool_arr, layer_idx: int, page_rows, num_heads: int):
@@ -631,8 +766,100 @@ def _write_rows(pool: dict, layer_idx: int, pages, offsets, k, v):
     return err
 
 
+# -- latent pages and per-slot state (the hybrid family) -----------------------
+#
+# A latent layer caches ONE row a token, shared by all heads, in pages the
+# same tables address; a state layer holds, for each slot, a float32 state
+# and the last inputs of its short convolution, read and written whole at
+# every step. Prefill runs the layers' sequence forms (keys and values
+# rebuilt from the latent rows; the chunked delta rule), decode their
+# one-token forms (W_kvb absorbed into the query and the output; one step
+# of the rule). The mathematics is the layers' own (models/hybrid.py).
+
+
+def _latent_prefill(op, params, pool, page_row, x, pos, valid_q):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    num_pages, ps = pool["latent"].shape[1] - 1, pool["latent"].shape[2]
+    max_pages = page_row.shape[0]
+    with jax.named_scope("tpu_dist.mla"):
+        q_nope, q_rope, latent = layer.project(p, x, pos)
+        pg = jnp.where(valid_q,
+                       page_row[jnp.minimum(pos // ps, max_pages - 1)],
+                       num_pages)
+        pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
+            latent[0].astype(pool["latent"].dtype))
+        rows = pool["latent"][idx][page_row].reshape(max_pages * ps, -1)
+        # Key j is position j: <= the query's own absolute position.
+        mask = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
+        o = layer.attend_expanded(p, q_nope[0], q_rope[0], rows, mask)
+        return layer.output(p, x, jnp.moveaxis(o, 0, 1)[None])
+
+
+def _latent_decode(op, params, pool, tables, x, pos, active):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    num_pages, ps = pool["latent"].shape[1] - 1, pool["latent"].shape[2]
+    b, max_pages = tables.shape
+    with jax.named_scope("tpu_dist.mla"):
+        q_nope, q_rope, latent = layer.project(p, x, pos[:, None])
+        pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
+        if active is not None:
+            pg = jnp.where(active, pg, num_pages)
+        pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
+            latent[:, 0].astype(pool["latent"].dtype))
+        rows = pool["latent"][idx][tables].reshape(b, max_pages * ps, -1)
+        valid = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
+        o = layer.attend_absorbed(p, q_nope[:, :, 0], q_rope[:, :, 0], rows,
+                                  valid)
+        return layer.output(p, x, o[:, None])
+
+
+def _state_prefill(op, params, pool, slot, x, start, valid_q):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    taps = layer.conv_size
+    with jax.named_scope("tpu_dist.kda"):
+        qkv, beta, g = layer.project(p, x)
+        fresh = start == 0
+        tail = jnp.where(fresh, 0.0, pool["conv"][idx, slot])
+        s0 = jnp.where(fresh, 0.0, pool["state"][idx, slot])
+        qkv, window = causal_conv(qkv[0], tail, p["conv"])
+        q, k, v = layer.heads(qkv)                         # [pad, H, dk]
+        # A padded position leaves the state as it was.
+        beta = jnp.where(valid_q[:, None], beta[0], 0.0)
+        g = jnp.where(valid_q[:, None, None], g[0], 0.0)
+        o, s = layer.scan(q, k, v, g, beta, s0)
+        pool["state"] = pool["state"].at[idx, slot].set(s)
+        pool["conv"] = pool["conv"].at[idx, slot].set(
+            jax.lax.dynamic_slice_in_dim(window, jnp.sum(valid_q), taps - 1))
+        return layer.output(p, x, o[None])
+
+
+def _state_decode(op, params, pool, x, active):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    b = x.shape[0]
+    with jax.named_scope("tpu_dist.kda"):
+        qkv, beta, g = layer.project(p, x)                 # [b, 1, *]
+        tail, old = pool["conv"][idx, :b], pool["state"][idx, :b]
+        qkv, window = causal_conv(qkv, tail, p["conv"])
+        q, k, v = layer.heads(qkv)                         # [b, 1, H, dk]
+        o, s = delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                               beta[:, 0], old)
+        tail_new = window[:, 1:]
+        if active is not None:
+            # A slot that is not decoding (empty, or mid-prefill with a
+            # real state) keeps what it holds.
+            s = jnp.where(active[:, None, None, None], s, old)
+            tail_new = jnp.where(active[:, None, None], tail_new, tail)
+        pool["state"] = pool["state"].at[idx, :b].set(s)
+        pool["conv"] = pool["conv"].at[idx, :b].set(tail_new)
+        return layer.output(p, x, o[:, None])
+
+
 def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
-                  length, start):
+                  length, start, slot=None):
     """Causal forward over the UNCACHED suffix of one prompt, writing
     K/V through the page table.
 
@@ -653,6 +880,10 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
         ``start .. length - 1``, padded past ``length - start``.
       length: scalar int32 total valid positions (prefix + suffix).
       start: scalar int32 cached-prefix length (``< length``).
+      slot: scalar int32, the slot whose recurrent state this chunk
+        carries (plans with state layers only). ``start == 0`` is a
+        request's first chunk: its state starts from zero whatever the
+        slot's last holder left there, and nothing of it is read.
 
     Returns:
       ``(pool, last_logits)`` for float pools; int8 pools return
@@ -660,8 +891,8 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
       max-abs dequantization error over this call's valid suffix
       positions (fp32 scalar — the ``serve.kv.quant_error`` datum).
     """
-    num_pages = pool["k"].shape[1] - 1     # last row is scratch
-    ps = pool["k"].shape[2]
+    num_pages = _pages(pool).shape[1] - 1  # last row is scratch
+    ps = _pages(pool).shape[2]
     max_pages = page_row.shape[0]
     pad = tokens.shape[0]
     x = tokens[None]                       # [1, pad]
@@ -678,10 +909,15 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
         elif tag == "res_end":
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
+            x = _learned_positions(params, op[2], x, pos)
+        elif tag == "latent":
+            x = _latent_prefill(op, params, pool, page_row, x, pos, valid_q)
+        elif tag == "state":
+            x = _state_prefill(op, params, pool, slot, x, start, valid_q)
+        elif tag == "moe":
             _, layer, path = op
-            table = _params_at(params, path)["table"]
-            at = jnp.minimum(pos, table.shape[0] - 1)
-            x = x + table[at].astype(x.dtype)[None]
+            with jax.named_scope("tpu_dist.moe"):
+                x, _ = layer.forward(_params_at(params, path), x, valid_q)
         elif tag == "attn":
             _, layer, path, idx = op
             p = _params_at(params, path)
@@ -766,8 +1002,8 @@ def walks_pages(pool: dict, max_pages: int, *, devices: int = 1) -> bool:
     spans (a Pallas call is opaque to the partitioner, and the kernel
     has run on one chip only). On a TPU a declined kernel is said once,
     with the shapes and the reason."""
-    if jax.default_backend() != "tpu":
-        return False
+    if jax.default_backend() != "tpu" or "k" not in pool:
+        return False  # latent pages are gathered in XLA
     reason = paged_attention.decline_reason(pool["k"], max_pages)
     if reason is None and devices > 1:
         reason = (f"the program spans {devices} devices and the "
@@ -795,8 +1031,8 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
     visits none of its pages (the XLA body attends whatever its stale
     length admits: nobody reads either).
     """
-    num_pages = pool["k"].shape[1] - 1     # last row is scratch
-    ps = pool["k"].shape[2]
+    num_pages = _pages(pool).shape[1] - 1  # last row is scratch
+    ps = _pages(pool).shape[2]
     max_pages = tables.shape[1]
     b = tokens.shape[0]
     rows = jnp.arange(b)
@@ -808,6 +1044,7 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
         n_keys = jnp.where(active, n_keys, 0)
     x = tokens[:, None]                    # [b, 1]
     residuals: list = []
+    moe_stats: list = []
     for op in plan.ops:
         tag = op[0]
         if tag == "res_start":
@@ -815,10 +1052,16 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
         elif tag == "res_end":
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
+            x = _learned_positions(params, op[2], x, pos)
+        elif tag == "latent":
+            x = _latent_decode(op, params, pool, tables, x, pos, active)
+        elif tag == "state":
+            x = _state_decode(op, params, pool, x, active)
+        elif tag == "moe":
             _, layer, path = op
-            table = _params_at(params, path)["table"]
-            at = jnp.minimum(pos, table.shape[0] - 1)
-            x = x + table[at].astype(x.dtype)[:, None, :]
+            with jax.named_scope("tpu_dist.moe"):
+                x, stats = layer.forward(_params_at(params, path), x, active)
+            moe_stats.append(stats)
         elif tag == "attn":
             _, layer, path, idx = op
             p = _params_at(params, path)
@@ -838,7 +1081,13 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
         else:  # "embed" / "point"
             _, layer, path = op
             x, _ = layer.apply(_params_at(params, path), {}, x)
-    return pool, x[:, 0, :].astype(jnp.float32)  # [b, vocab]
+    logits = x[:, 0, :].astype(jnp.float32)      # [b, vocab]
+    if moe_stats:
+        # Summed over the expert layers; the fullest expert is a maximum.
+        stacked = jnp.stack(moe_stats)
+        return pool, logits, jnp.concatenate(
+            [jnp.sum(stacked[:, :3], axis=0), jnp.max(stacked[:, 3:], axis=0)])
+    return pool, logits
 
 
 def paged_decode_step(plan: DecodePlan, params, pool: dict, page_tables,
@@ -908,5 +1157,19 @@ def copy_page(pool: dict, src, dst):
     program serves every copy."""
     out = {}
     for name, a in pool.items():
-        out[name] = a.at[:, dst].set(jnp.take(a, src, axis=1))
+        out[name] = (a.at[:, dst].set(jnp.take(a, src, axis=1))
+                     if name in _PAGED_ENTRIES else a)
+    return out
+
+
+def swap_state(pool: dict, i, j):
+    """Exchange slots ``i`` and ``j`` of the entries held by slot (the
+    recurrent state and its convolution tail): the device half of a
+    compaction move under paging, whose pages move by a host pointer swap.
+    Traced scalars: one compiled program serves every swap."""
+    out = dict(pool)
+    for name in ("state", "conv"):
+        a = pool[name]
+        ri, rj = jnp.take(a, i, axis=1), jnp.take(a, j, axis=1)
+        out[name] = a.at[:, i].set(rj).at[:, j].set(ri)
     return out

@@ -464,13 +464,27 @@ class PagedKVState:
 
     def __init__(self, *, num_pages: int, page_size: int, slots: int,
                  max_pages: int, bytes_per_token: int,
-                 prefix_caching: bool = True) -> None:
+                 prefix_caching: bool = True,
+                 state_bytes_per_slot: int = 0) -> None:
+        if state_bytes_per_slot and prefix_caching:
+            raise ValueError(
+                "serve: a slot with recurrent state cannot be handed shared "
+                "pages — its state was not built over them; pass "
+                "prefix_caching=False")
         self.allocator = PageAllocator(
             num_pages=num_pages, page_size=page_size, slots=slots,
             max_pages=max_pages)
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.allocator) if prefix_caching else None)
         self._bytes_per_token = bytes_per_token
+        #: The second kind of cache: a recurrent state held BY SLOT on the
+        #: device (0 bytes: the model has none). A slot holds one from
+        #: :meth:`begin` to :meth:`finish`; it moves with the slot's pages
+        #: in :meth:`swap_slots` (the engine moves the device rows), and a
+        #: request's first prefill chunk starts it from zero, so what a
+        #: finished request left is never read.
+        self._state_bytes_per_slot = int(state_bytes_per_slot)
+        self.state_live = np.zeros(slots, bool)
 
     # -- admission ------------------------------------------------------------
 
@@ -522,6 +536,11 @@ class PagedKVState:
         ps = alloc.page_size
         need = self.pages_needed(total_tokens)
         alloc.bind_reservation(slot, need)
+        if self._state_bytes_per_slot:
+            if self.state_live[slot]:
+                raise AssertionError(
+                    f"slot {slot} still holds a live recurrent state")
+            self.state_live[slot] = True
         copies: List[Tuple[int, int]] = []
         start = 0
         if self.prefix is not None:
@@ -609,9 +628,11 @@ class PagedKVState:
                 self.prefix.register_partial(prompt,
                                              self.allocator.table[slot])
         self.allocator.release_slot(slot)
+        self.state_live[slot] = False
 
     def swap_slots(self, i: int, j: int) -> None:
         self.allocator.swap_slots(i, j)
+        self.state_live[[i, j]] = self.state_live[[j, i]]
 
     # -- telemetry ------------------------------------------------------------
 
@@ -633,3 +654,9 @@ class PagedKVState:
             per_page = self.allocator.page_size * self._bytes_per_token
             metrics.set_gauge("serve.pages.bytes_per_slot",
                               held * per_page / occupied)
+        if self._state_bytes_per_slot:
+            live = int(self.state_live.sum())
+            metrics.set_gauge("serve.prefix.disabled_recurrent", 1.0)
+            metrics.set_gauge("serve.state.slots_live", float(live))
+            metrics.set_gauge("serve.state.bytes",
+                              float(live * self._state_bytes_per_slot))
