@@ -727,10 +727,6 @@ class TestPageWalkingKernel:
             self, kv_dtype, ragged):
         """The chat cell's engine at its rehearsal sizes, decoding once
         through the XLA body and once through the interpreted kernel."""
-        import functools
-
-        import jax
-
         model = build_transformer_lm(512, 128, d_model=64, depth=2,
                                      num_heads=4, ff_dim=256)
         model.init(0)
@@ -748,13 +744,12 @@ class TestPageWalkingKernel:
         want = _drive(engine(), workload)
         walked = engine()
         if ragged:
-            walked._paged_decode_fns[4] = jax.jit(functools.partial(
-                kv_cache.paged_decode_ragged, walked.plan, walk=True))
+            walked._paged_decode_fns[4] = walked._jit(
+                kv_cache.paged_decode_ragged, walk=True)
         else:
             for bucket in (1, 2, 4):
-                walked._paged_decode_fns[bucket] = jax.jit(functools.partial(
-                    kv_cache.paged_decode_step, walked.plan, bucket=bucket,
-                    walk=True))
+                walked._paged_decode_fns[bucket] = walked._jit(
+                    kv_cache.paged_decode_step, bucket=bucket, walk=True)
         assert _drive(walked, workload) == want
 
     def test_the_kernel_stops_at_the_row_and_masks_the_scale_rows(self):

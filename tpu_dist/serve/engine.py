@@ -36,9 +36,14 @@ A round of :meth:`ServeEngine.step` is one ``serve.step`` span
 stand at the phase boundaries: ``serve.step.admit``, one
 ``serve.step.prefill_chunk`` a chunk (with ``serve.step.first_token_wait``
 inside a last chunk), ``serve.step.decode_prep``, ``.decode_dispatch``,
-``.decode_wait``, ``.pick`` and ``.journal_flush``. Counters at the same
-boundaries: ``serve.step.rounds``, ``serve.upload.bytes`` and
-``serve.logits.bytes`` (what crosses to and from the device),
+``.decode_wait``, ``.pick`` and ``.journal_flush`` (and, inside ``.pick``,
+one ``serve.step.logits_read`` in a step whose logits somebody read).
+Counters at the same boundaries: ``serve.step.rounds``,
+``serve.upload.bytes`` and ``serve.logits.bytes`` (what crosses to and
+from the device: the token ids a program picked, and a step's logits only
+when somebody read a row of them), ``serve.pick.device`` and
+``serve.pick.host_rows`` (tokens taken from a program's pick against rows
+that crossed for a host pick),
 ``serve.programs.built``, ``serve.decode.pages_read`` and
 ``.pages_addressed`` (the pages a paged decode step's attention reads
 against those its table rows address); and once a round
@@ -131,6 +136,63 @@ def _current_job():
     return mod.current_job() if mod is not None else None
 
 
+def _picking(body):
+    """Wrap one of ``kv_cache``'s program bodies, which return ``(cache,
+    logits, *rest)``, so that the program also returns the greedy pick:
+    ``(cache, logits, tokens, *rest)`` with ``tokens = argmax(logits, -1)``
+    as int32 (``[bucket]`` for a decode program, a scalar for a prefill or
+    chunk program). ``jnp.argmax`` takes the first of equal maxima, as
+    ``np.argmax`` does on the same float32 values. The logits stay an
+    output: they are left on the device until somebody reads them."""
+    def program(*args):
+        cache, logits, *rest = body(*args)
+        return (cache, logits,
+                jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
+    return program
+
+
+class _Picked:
+    """One program execution's two results: the token ids it picked, on
+    the host (the read the round waited for, 4 bytes a slot), and the
+    logits it picked them from, left on the device. :meth:`logits`
+    crosses on first call, once, however many rows ask."""
+
+    __slots__ = ("tokens", "_engine", "_logits", "_host")
+
+    def __init__(self, engine: "ServeEngine", logits, tokens: np.ndarray):
+        self.tokens, self._engine, self._logits = tokens, engine, logits
+        self._host = None
+
+    def logits(self) -> np.ndarray:
+        if self._host is None:
+            self._host = self._engine._to_host(self._logits,
+                                               "serve.step.logits_read")
+        return self._host
+
+
+class _LogitsRow:
+    """What :meth:`ServeEngine._pick` is handed: the token the program
+    picked, ``len()`` the vocabulary, and the float32 row itself to
+    whoever asks (``np.asarray(row)``), which is when the step's logits
+    cross. ``index`` is the slot's row of a decode step; a prefill's one
+    row is ``[()]`` of its ``[vocab]`` logits and of its scalar token."""
+
+    __slots__ = ("token", "_picked", "_index")
+
+    def __init__(self, picked: _Picked, index=()):
+        self.token = int(picked.tokens[index])
+        self._picked, self._index = picked, index
+
+    def __len__(self) -> int:
+        return self._picked._logits.shape[-1]
+
+    def __array__(self, dtype=None, copy=None):
+        row = self._picked.logits()[self._index]
+        if dtype is not None and row.dtype != dtype:
+            return row.astype(dtype)
+        return row.copy() if copy else row
+
+
 #: Monotonic engine generation counter — keys pool-cached decode/prefill
 #: programs to one engine instance (its plan, donation mode, and KV-cache
 #: shapes are baked into the traced closures).
@@ -149,8 +211,13 @@ class ServeEngine:
       max_len: per-slot cache capacity (prompt + generated tokens);
         defaults to the model's positional-table length.
       buckets / policy: forwarded to :class:`Scheduler`.
-      temperature: 0 = greedy argmax; > 0 samples from the tempered
-        softmax with a host-side seeded generator (deterministic runs).
+      temperature: 0 = greedy argmax, taken INSIDE the decode, prefill and
+        chunk programs: a step hands the host its slots' token ids (4
+        bytes each) and leaves the logits on the device. They cross only
+        when somebody reads a row (``np.asarray`` of what :meth:`_pick` is
+        handed), once a step however many rows are read. > 0 reads every
+        row and samples from the tempered softmax with a host-side seeded
+        generator (deterministic runs), at the cost of that crossing.
       clock: injectable monotonic clock (tests pin deadlines with it).
       journal: a :class:`~tpu_dist.serve.journal.RequestJournal`, or a
         directory path to open one in. When the directory already holds a
@@ -592,15 +659,19 @@ class ServeEngine:
             self._job.program_key(self.model.name, self._serial, kind, key),
             build)
 
+    def _jit(self, body, **fixed):
+        """The one place the engine compiles a program of ``kv_cache``
+        that returns logits: its plan and ``fixed`` keywords bound, the
+        greedy pick added (:func:`_picking`), the cache donated."""
+        return jax.jit(_picking(functools.partial(body, self.plan, **fixed)),
+                       donate_argnums=self._donate)
+
     def _decode_fn(self, bucket: int):
         fn = self._decode_fns.get(bucket)
         if fn is None:
             fn = self._acquire_program(
                 "decode", bucket,
-                lambda: jax.jit(
-                    functools.partial(kv_cache.decode_step, self.plan,
-                                      bucket=bucket),
-                    donate_argnums=self._donate))
+                lambda: self._jit(kv_cache.decode_step, bucket=bucket))
             self._decode_fns[bucket] = fn
         return fn
 
@@ -608,10 +679,7 @@ class ServeEngine:
         fn = self._prefill_fns.get(pad_len)
         if fn is None:
             fn = self._acquire_program(
-                "prefill", pad_len,
-                lambda: jax.jit(
-                    functools.partial(kv_cache.prefill, self.plan),
-                    donate_argnums=self._donate))
+                "prefill", pad_len, lambda: self._jit(kv_cache.prefill))
             self._prefill_fns[pad_len] = fn
         return fn
 
@@ -624,19 +692,14 @@ class ServeEngine:
                 # compiled_programs() reports the surface uniformly.
                 fn = self._acquire_program(
                     "paged_decode_ragged", bucket,
-                    lambda: jax.jit(
-                        functools.partial(kv_cache.paged_decode_ragged,
-                                          self.plan,
-                                          walk=self._walks_pages),
-                        donate_argnums=self._donate))
+                    lambda: self._jit(kv_cache.paged_decode_ragged,
+                                      walk=self._walks_pages))
             else:
                 fn = self._acquire_program(
                     "paged_decode", bucket,
-                    lambda: jax.jit(
-                        functools.partial(kv_cache.paged_decode_step,
-                                          self.plan, bucket=bucket,
-                                          walk=self._walks_pages),
-                        donate_argnums=self._donate))
+                    lambda: self._jit(kv_cache.paged_decode_step,
+                                      bucket=bucket,
+                                      walk=self._walks_pages))
             self._paged_decode_fns[bucket] = fn
         return fn
 
@@ -645,9 +708,7 @@ class ServeEngine:
         if fn is None:
             fn = self._acquire_program(
                 "paged_prefill", pad_len,
-                lambda: jax.jit(
-                    functools.partial(kv_cache.paged_prefill, self.plan),
-                    donate_argnums=self._donate))
+                lambda: self._jit(kv_cache.paged_prefill))
             self._paged_prefill_fns[pad_len] = fn
         return fn
 
@@ -656,10 +717,7 @@ class ServeEngine:
         if fn is None:
             fn = self._acquire_program(
                 "prefill_chunk", pad_len,
-                lambda: jax.jit(
-                    functools.partial(kv_cache.prefill_chunk_step,
-                                      self.plan),
-                    donate_argnums=self._donate))
+                lambda: self._jit(kv_cache.prefill_chunk_step))
             self._chunk_fns[pad_len] = fn
         return fn
 
@@ -822,11 +880,18 @@ class ServeEngine:
         logger.info("serve: shed request %d (%s)", req.rid, cause)
         return req
 
-    # -- sampling (host-side) -------------------------------------------------
+    # -- the pick ---------------------------------------------------------------
 
-    def _pick(self, logits: np.ndarray) -> int:
+    def _pick(self, logits: _LogitsRow) -> int:
+        """One token from one row. Greedy: the token the program picked;
+        nothing crosses. ``temperature > 0``: the row is read (which
+        brings the step's logits to the host, once a step) and sampled
+        here, from the engine's seeded generator."""
         if self.temperature <= 0.0:
-            return int(np.argmax(logits))
+            metrics.inc("serve.pick.device")
+            return logits.token
+        metrics.inc("serve.pick.host_rows")
+        logits = np.asarray(logits)
         z = logits.astype(np.float64) / self.temperature
         z -= z.max()
         p = np.exp(z)
@@ -898,35 +963,36 @@ class ServeEngine:
         clones) deadlock-free."""
         return self._paging.try_admit(self._total_tokens(req))
 
-    def _unpack_prefill(self, out):
-        """Unpack a paged-prefill result: int8 pools return a third
-        element — the call's max-abs dequantization error. It stays on
-        the device unless the registry records, and is then read with the
-        next read-back that happens anyway (:meth:`_to_host`), never with
-        one of its own."""
-        if self._kv_quant:
-            self.cache, logits, qerr = out
-            if metrics.enabled():
-                self._pending_qerr.append(qerr)
-        else:
-            self.cache, logits = out
-        return logits
+    def _unpack_prefill(self, out) -> tuple:
+        """Unpack a prefill or chunk program's result: keep the cache,
+        return the logits and the token picked from them (both still on
+        the device). An int8 pool's program returns a fourth element, the
+        call's max-abs dequantization error. It stays on the device unless
+        the registry records, and is then read with the next read-back
+        that happens anyway (:meth:`_to_host`), never with one of its
+        own."""
+        self.cache, logits, token, *qerr = out
+        if qerr and metrics.enabled():
+            self._pending_qerr.append(qerr[0])
+        return logits, token
 
     def _state_slot(self, req: Request) -> tuple:
         """The extra argument of a paged prefill program whose plan has
         state layers: the slot whose state the chunk carries."""
         return (jnp.int32(req.slot),) if self.plan.recurrent else ()
 
-    def _to_host(self, logits, span_name: str) -> np.ndarray:
+    def _to_host(self, array, span_name: str) -> np.ndarray:
         """The one place the host waits for the device: ``np.asarray`` of
-        a program's logits, under ``span_name``. Prefill errors parked by
-        :meth:`_unpack_prefill` ride along into ``serve.kv.quant_error``
-        (host-side, after the traced program: SC103-clean)."""
+        a program's result, under ``span_name``: the token ids it picked,
+        every step, or its logits, when somebody reads them. Prefill
+        errors parked by :meth:`_unpack_prefill` ride along into
+        ``serve.kv.quant_error`` (host-side, after the traced program:
+        SC103-clean)."""
         with profiler.span(span_name, self._round) as wait:
-            logits = np.asarray(logits)  # blocks until the device is done
+            array = np.asarray(array)  # blocks until the device is done
         self._waited_s += wait.seconds
         if metrics.enabled():
-            metrics.inc("serve.logits.bytes", logits.nbytes)
+            metrics.inc("serve.logits.bytes", array.nbytes)
             for qerr in jax.device_get(self._pending_qerr):
                 metrics.observe_value("serve.kv.quant_error", float(qerr))
             for made, held, touched, fullest in jax.device_get(
@@ -937,7 +1003,7 @@ class ServeEngine:
                 metrics.observe_value("serve.moe.load_max", float(fullest))
         self._pending_qerr.clear()
         self._pending_moe.clear()
-        return logits
+        return array
 
     def _upload(self, *arrays) -> list:
         """Host arrays to the device, counted in ``serve.upload.bytes``."""
@@ -975,30 +1041,30 @@ class ServeEngine:
                 fn = self._paged_prefill_fn(pad)
                 row, toks = self._upload(
                     self._paging.allocator.table[req.slot], tokens)
-                out = fn(self.params, self.cache, row, toks,
-                         jnp.int32(plen), jnp.int32(setup.start),
-                         *self._state_slot(req))
-                logits = self._unpack_prefill(out)
+                last = self._unpack_prefill(
+                    fn(self.params, self.cache, row, toks, jnp.int32(plen),
+                       jnp.int32(setup.start), *self._state_slot(req)))
                 self._paging.register_prefill(req.slot, req.prompt)
             else:
                 pad = _pad_to_pow2(plen, hi=self.max_len)
                 tokens = np.zeros(pad, np.int32)
                 tokens[:plen] = seq
                 fn = self._prefill_fn(pad)
-                self.cache, logits = fn(self.params, self.cache,
-                                        *self._upload(tokens),
-                                        jnp.int32(plen), jnp.int32(req.slot))
+                last = self._unpack_prefill(
+                    fn(self.params, self.cache, *self._upload(tokens),
+                       jnp.int32(plen), jnp.int32(req.slot)))
             req.prefill_pos = plen
-            self._first_token(req, logits, plen)
+            self._first_token(req, last, plen)
 
-    def _first_token(self, req: Request, logits, plen: int) -> None:
-        """The end of a prefill: read the last position's logits back,
-        stamp, emit the first generated token."""
+    def _first_token(self, req: Request, last: tuple, plen: int) -> None:
+        """The end of a prefill: read the token picked at the last
+        position back, stamp, emit the first generated token."""
         # Materialize BEFORE stamping first-token time: jax dispatch is
         # async, so the pre-readback clock() under-reported TTFT against
         # any client-observed wall clock (the PR 12 wart).
-        logits = self._to_host(logits, "serve.step.first_token_wait")
-        token = self._pick(logits)
+        logits, token = last
+        token = self._to_host(token, "serve.step.first_token_wait")
+        token = self._pick(_LogitsRow(_Picked(self, logits, token)))
         now = self.clock()
         done = self.scheduler.record_token(req, token, now=now)
         metrics.inc("serve.tokens.generated")
@@ -1054,29 +1120,28 @@ class ServeEngine:
                 fn = self._paged_prefill_fn(pad)
                 row, toks = self._upload(
                     self._paging.allocator.table[req.slot], tokens)
-                out = fn(self.params, self.cache, row, toks,
-                         jnp.int32(end), jnp.int32(startpos),
-                         *self._state_slot(req))
-                logits = self._unpack_prefill(out)
+                last = self._unpack_prefill(
+                    fn(self.params, self.cache, row, toks, jnp.int32(end),
+                       jnp.int32(startpos), *self._state_slot(req)))
                 if self.plan.recurrent:
                     metrics.inc("serve.prefill.scan_chunks",
                                 self.plan.state_layers
                                 * -(-pad // hybrid.SCAN_BLOCK))
             else:
                 fn = self._chunk_fn(pad)
-                self.cache, logits = fn(self.params, self.cache,
-                                        *self._upload(tokens),
-                                        jnp.int32(end), jnp.int32(req.slot),
-                                        jnp.int32(startpos))
+                last = self._unpack_prefill(
+                    fn(self.params, self.cache, *self._upload(tokens),
+                       jnp.int32(end), jnp.int32(req.slot),
+                       jnp.int32(startpos)))
             req.prefill_pos = end
             self._lengths[req.slot] = end
             metrics.inc("serve.prefill.chunks")
             if end < plen:
-                return  # more chunks owed; a mid-chunk's logits are unused
+                return  # more chunks owed; a mid-chunk's pick is unused
             self.scheduler.dequeue_prefill(req)
             if self.paged:
                 self._paging.register_prefill(req.slot, req.prompt)
-            self._first_token(req, logits, plen)
+            self._first_token(req, last, plen)
 
     def step(self) -> int:
         """One scheduling round: deadline evictions → admissions (each
@@ -1206,14 +1271,16 @@ class ServeEngine:
             timer.start()
         try:
             with profiler.span("serve.step.decode_dispatch", rnd):
-                self.cache, logits, *moe = fn(self.params, self.cache, *args)
+                self.cache, logits, tokens, *moe = fn(
+                    self.params, self.cache, *args)
             if moe and metrics.enabled():
                 self._pending_moe.append(moe[0])
             if self.fault_injector is not None:
                 # Inside the watchdog window on purpose: a decode_stall
                 # fault must look exactly like a hung runtime call.
                 self.fault_injector.on_decode()
-            logits = self._to_host(logits, "serve.step.decode_wait")
+            picked = _Picked(self, logits, self._to_host(
+                tokens, "serve.step.decode_wait"))
         finally:
             if timer is not None:
                 timer.cancel()
@@ -1229,7 +1296,7 @@ class ServeEngine:
             now = self.clock()
             completed = []
             for req in ready:
-                token = self._pick(logits[req.slot])
+                token = self._pick(_LogitsRow(picked, req.slot))
                 self._lengths[req.slot] += 1
                 self._tokens[req.slot] = token
                 done = self.scheduler.record_token(req, token, now=now)
