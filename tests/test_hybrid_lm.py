@@ -473,42 +473,58 @@ def test_a_hybrid_model_is_saved_and_loaded_layer_for_layer(family, cfg):
             "DeltaAttention", "RoutedExperts"} <= seen
 
 
-# -- the GPT-2 family's programs ---------------------------------------------------
+# -- the programs the serve cells run -----------------------------------------
 
-#: sha256 (first 16 hex digits) of the lowered text of the two programs the
-#: GPT-2 serve cells run, at a small size, as the PARENT commit (PR 27)
-#: lowers them: the hybrid family's cache kinds and the one positions
-#: helper leave them byte for byte. To renew after a deliberate change:
-#: run this test's ``_digests`` on the commit before it.
+#: sha256 (first 16 hex digits) of the lowered text of the decode and the
+#: prefill program the serve cells run, at a small size, as the PARENT
+#: commit lowers them. ``int8`` and ``bfloat16``: the GPT-2 plan over such
+#: a pool (taken at PR 27: the hybrid family's cache kinds and the one
+#: positions helper left them byte for byte). ``hybrid``: the hybrid plan
+#: at its rehearsal widths over a bfloat16 pool (taken at PR 31's parent).
+#: A program that lowers to the same text has the same key in the compile
+#: cache and loads the same executable. To renew after a deliberate
+#: change: run ``_digest`` on the commit before it.
 PARENT_DIGESTS = {
-    "int8": ("e8f1b89dcbc4d35b", "2b1131b1fd2308b7"),
-    "bfloat16": ("b7e9c46cc4e32c1a", "23f7f9cff5af7765"),
+    ("int8", "decode"): "e8f1b89dcbc4d35b",
+    ("int8", "prefill"): "2b1131b1fd2308b7",
+    ("bfloat16", "decode"): "b7e9c46cc4e32c1a",
+    ("bfloat16", "prefill"): "23f7f9cff5af7765",
+    ("hybrid", "decode"): "f1138db48ae372dd",
+    ("hybrid", "prefill"): "e0af96c75ae1e56c",
 }
 
 
-def _digests(dtype):
-    from tpu_dist.models.transformer import build_transformer_lm
-
-    set_policy("mixed_bfloat16")
-    model = build_transformer_lm(512, 128, d_model=64, depth=2, num_heads=4,
-                                 ff_dim=256)
+def _digest(model, dtype, program, **pool_kw):
     plan = kv_cache.build_plan(model)
     params = jax.eval_shape(lambda: model.init(0))["params"]
     pool = jax.eval_shape(lambda: kv_cache.init_page_pool(
-        plan, num_pages=24, page_size=16, dtype=dtype))
+        plan, num_pages=24, page_size=16, dtype=dtype, **pool_kw))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    decode = jax.jit(functools.partial(
-        kv_cache.paged_decode_ragged, plan, walk=False)).lower(
-            params, pool, i32(4, 8), i32(4), i32(4),
-            jax.ShapeDtypeStruct((4,), jnp.bool_)).as_text()
-    prefill = jax.jit(functools.partial(kv_cache.paged_prefill, plan)).lower(
-        params, pool, i32(8), i32(32), i32(), i32()).as_text()
-    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
-                 for t in (decode, prefill))
+    if program == "decode":
+        lowered = jax.jit(functools.partial(
+            kv_cache.paged_decode_ragged, plan, walk=False)).lower(
+                params, pool, i32(4, 8), i32(4), i32(4),
+                jax.ShapeDtypeStruct((4,), jnp.bool_))
+    else:
+        slot = (i32(),) if plan.recurrent else ()
+        lowered = jax.jit(functools.partial(kv_cache.paged_prefill, plan)).lower(
+            params, pool, i32(8), i32(32), i32(), i32(), *slot)
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16],
-                         ids=["int8-pool", "bfloat16-pool"])
-def test_gpt2_paged_programs_lower_as_the_parent_lowered_them(dtype):
+@pytest.mark.parametrize("pinned, program", list(PARENT_DIGESTS),
+                         ids=["-".join(k) for k in PARENT_DIGESTS])
+def test_serve_programs_lower_as_the_parent_lowered_them(
+        pinned, program, family, cfg):
+    from tpu_dist.models.transformer import build_transformer_lm
+
+    set_policy("mixed_bfloat16")
     with jax.default_matmul_precision("default"):
-        assert _digests(dtype) == PARENT_DIGESTS[jnp.dtype(dtype).name]
+        if pinned == "hybrid":
+            got = _digest(family.build_program(cfg, 1), jnp.bfloat16,
+                          program, slots=4)
+        else:
+            model = build_transformer_lm(512, 128, d_model=64, depth=2,
+                                         num_heads=4, ff_dim=256)
+            got = _digest(model, jnp.dtype(pinned), program)
+    assert got == PARENT_DIGESTS[pinned, program]

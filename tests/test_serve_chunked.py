@@ -319,6 +319,34 @@ class TestChunkedDeadline:
         again = engine.generate(prompt, max_new_tokens=4)
         assert again == cold
 
+    def test_eviction_leaves_a_chunk_in_flight_its_table_row(self, model):
+        """Dispatch is asynchronous and nothing waits for a mid-prompt
+        chunk: the row its program was handed must not be the allocator's
+        own memory, which the eviction resets to scratch. (On the CPU
+        ``jnp.asarray`` aliases a 64-byte-aligned numpy buffer; the table
+        is aligned here by construction, elsewhere by chance.)"""
+        clock = _FakeClock()
+        engine = ServeEngine(model, max_batch=1, max_len=48, paged=True,
+                             page_size=8, prefill_chunk=8, clock=clock)
+        alloc = engine._paging.allocator
+        raw = np.zeros(alloc.table.nbytes + 64, np.uint8)
+        at = -raw.ctypes.data % 64
+        aligned = raw[at:at + alloc.table.nbytes].view(
+            alloc.table.dtype).reshape(alloc.table.shape)
+        aligned[...] = alloc.table
+        alloc.table = aligned
+        handed = []
+        upload = engine._upload
+        engine._upload = lambda *a: handed.append(upload(*a)) or handed[-1]
+        engine.submit(list(range(1, 31)), max_new_tokens=4, deadline_s=5.0)
+        engine.step()  # admit + first chunk only
+        row = alloc.table[0].copy()
+        assert row[0] != alloc.scratch
+        clock.t = 6.0
+        engine.step()  # evicts: the allocator's row is all scratch now
+        assert np.all(alloc.table[0] == alloc.scratch)
+        np.testing.assert_array_equal(np.asarray(handed[0][0]), row)
+
 
 class TestChunkedNoRetrace:
     def test_contiguous_steady_state_never_retraces(self, model):

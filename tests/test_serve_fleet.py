@@ -158,6 +158,16 @@ class TestFleetFaultGrammar:
         assert main(["--fleet", "--plan", "engine_crash@req0"]) == 2
         assert "--chaos" in capsys.readouterr().err
 
+    def test_fleet_cli_leaves_the_registry_off(self):
+        """``run_fleet`` records while it runs and, however it returns,
+        hands the registry back off: a later test (or caller) in the same
+        process finds spans recording nothing."""
+        from tpu_dist.observe import metrics
+        from tpu_dist.serve.cli import main
+        assert not metrics.enabled()
+        assert main(["--fleet", "--plan", "engine_crash@req0"]) == 2
+        assert not metrics.enabled()
+
     def test_fleet_ctor_rejects_solo_kinds(self, model):
         plan = FaultPlan.parse("engine_crash@req0")
         with pytest.raises(ValueError, match="--chaos"):

@@ -1118,8 +1118,12 @@ class ServeEngine:
             if self.paged:
                 self._paging.extend_prefill(req.slot, end)
                 fn = self._paged_prefill_fn(pad)
+                # The row as a copy: nothing waits for a mid-prompt chunk,
+                # on the CPU ``jnp.asarray`` aliases a 64-byte-aligned numpy
+                # buffer, and an eviction resets the allocator's own row in
+                # place.
                 row, toks = self._upload(
-                    self._paging.allocator.table[req.slot], tokens)
+                    self._paging.allocator.table[req.slot].copy(), tokens)
                 last = self._unpack_prefill(
                     fn(self.params, self.cache, row, toks, jnp.int32(end),
                        jnp.int32(startpos), *self._state_slot(req)))

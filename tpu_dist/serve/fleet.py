@@ -886,10 +886,17 @@ def run_fleet(args) -> int:
     """``python -m tpu_dist.serve --fleet``: run the sessioned workload
     through a fleet, compare every token stream against an uninterrupted
     solo baseline, and gate on routing/failover/pinning invariants."""
-    from tpu_dist.serve.cli import _build_engine
-
     metrics.get_registry().reset()
     metrics.enable()
+    try:
+        return _run_fleet(args)
+    finally:
+        metrics.disable()  # as the CLI's other modes leave it
+
+
+def _run_fleet(args) -> int:
+    from tpu_dist.serve.cli import _build_engine
+
     plan = (FaultPlan.parse(args.plan)
             if getattr(args, "plan", None) else FaultPlan())
     foreign = sorted({f.kind for f in plan.faults
