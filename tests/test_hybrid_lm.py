@@ -301,12 +301,100 @@ def test_a_share_leaves_out_what_absent_experts_would_add(family, cfg):
                            p["shared_wd"])
     assert float(jnp.abs(shared - got[1]).max()) < SHARE_TOL
     assert float(jnp.abs(ref[1] - got[1]).max()) > 100 * SHARE_TOL
-    made, held, touched, fullest = (int(v) for v in stats)
+    made, held, touched, fullest = (int(v) for v in stats[:4])
     assert made == 24 * 2 and 0 < held < made    # the valid row only
     assert 1 <= touched <= 8 and fullest >= held / touched
     # Every row valid: twice the tokens, and the other row's experts too.
     _, both = layer.forward(p, x)
     assert int(both[0]) == 2 * made and int(both[1]) > held
+
+
+def _unblocked(layer, params, x, valid=None):
+    """The routed part as one ``ragged_dot`` over ALL the sorted rows (the
+    product off the TPU, and on it before the kernel): the oracle.
+    Returns ``(y, the first four stats)``."""
+    first, count = layer.experts_held
+    flat = x.reshape(-1, x.shape[-1])
+    t, k = flat.shape[0], layer.top_k
+    chosen, weights = layer.choose(params, flat)
+    local = chosen - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held &= valid.reshape(-1)[:, None]
+    group = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+    rows = flat[order // k]
+    dot = lambda a, w: jax.lax.ragged_dot(a, w.astype(a.dtype), sizes)
+    h = jax.nn.silu(dot(rows, params["wg"])) * dot(rows, params["wu"])
+    out = dot(h, params["wd"])
+    scale = jnp.where(held, weights, 0.0).reshape(-1)[order]
+    out = jnp.where(scale[:, None] != 0.0, out * scale[:, None], 0.0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    y = out[back].reshape(t, k, -1).sum(axis=1)
+    made = (t if valid is None else jnp.sum(valid)) * k
+    return y.reshape(x.shape), [int(made), int(jnp.sum(sizes)),
+                                int(jnp.sum(sizes > 0)), int(jnp.max(sizes))]
+
+
+def _first_tokens(n):
+    return lambda shape: (jnp.arange(shape[0] * shape[1]) < n).reshape(shape)
+
+
+#: name: (experts held, x's leading shape, which tokens are somebody's,
+#: row tiles the held rows lie in). Two choices a token, 16 experts, row
+#: tiles of ``ROW_TILE`` = 128 sorted rows.
+TILE_CASES = {
+    "no-row-held": ((0, 16), (2, 70), _first_tokens(0), 0),
+    "fewer-than-a-tile": ((12, 4), (1, 100), None, 1),
+    "exactly-a-tile": ((0, 16), (1, 100), _first_tokens(64), 1),
+    "an-expert-astride-two-tiles": ((0, 8), (4, 100), None, 3),
+    "every-assignment-held": ((0, 16), (1, 150), None, 3),
+    "a-whole-row-masked": (
+        (0, 16), (2, 100), lambda shape: jnp.ones(shape, bool).at[1].set(False),
+        2),
+}
+
+
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_the_tiled_product_is_the_unblocked_one(case, monkeypatch):
+    """On a TPU the grouped product is a kernel that walks row tiles of
+    ``ROW_TILE`` sorted rows, and only the tiles that hold a row with an
+    expert (here under the Pallas interpreter): the layer gives what one
+    ``ragged_dot`` over all the rows gives, whatever the held rows number,
+    and the fifth of ``stats`` counts the rows of the tiles visited."""
+    from tpu_dist.ops.grouped_matmul import ROW_TILE
+    from tpu_dist.parallel import routed_experts
+
+    monkeypatch.setattr(routed_experts, "grouped_dot", functools.partial(
+        routed_experts.grouped_dot, interpret=True))
+    held, lead, mask, tiles = TILE_CASES[case]
+    layer = _expert_layer(held, 0)
+    p, _, _ = layer.init(jax.random.PRNGKey(5), (*lead, 64))
+    p["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(6), (16,))
+    x = jax.random.normal(jax.random.PRNGKey(7), (*lead, 64))
+    valid = None if mask is None else mask(lead)
+    want, counts = _unblocked(layer, p, x, valid)
+    got, stats = jax.jit(layer.forward)(p, x, valid)
+    assert float(jnp.abs(got - want).max()) <= SHARE_TOL
+    assert [int(v) for v in stats[:4]] == counts
+    n_rows, n_held = lead[0] * lead[1] * 2, counts[1]
+    tile = ROW_TILE
+    assert n_rows > tile and -(-n_held // tile) == tiles
+    assert [int(v) for v in stats[4:]] == [min(tiles * tile, n_rows), n_rows]
+    if case == "exactly-a-tile":
+        assert n_held == tile
+    if case == "every-assignment-held":
+        assert n_held == n_rows and n_rows % tile   # the rows are padded
+    if case == "no-row-held":
+        assert not np.asarray(got).any()
+    if case == "an-expert-astride-two-tiles":
+        # Some expert's rows begin in one tile and end in the next.
+        first, count = held
+        chosen, _ = layer.choose(p, x.reshape(-1, 64))
+        ends = np.cumsum(np.bincount(
+            np.asarray(chosen).ravel(), minlength=16)[first:first + count])
+        assert any(e % tile for e in ends[:-1])
 
 
 # -- the engine ----------------------------------------------------------------
@@ -401,6 +489,10 @@ def test_prefix_caching_asked_for_is_served_without_reuse(family, cfg):
     assert snap["gauges"]["serve.prefix.disabled_recurrent"] == 1.0
     assert "serve.prefix.hits" not in snap["counters"]
     assert snap["counters"]["serve.moe.assignments"] > 0
+    # The sorted rows in the grouped product's visited row tiles, of all
+    # the rows the expert layers sorted in the decode steps.
+    assert (0 < snap["counters"]["serve.moe.rows_multiplied"]
+            <= snap["counters"]["serve.moe.rows_sorted"])
     assert snap["counters"]["serve.prefill.scan_chunks"] > 0
     assert first.generated == again.generated
     assert np.array_equal(np.stack(rows[first.rid]),
@@ -480,7 +572,10 @@ def test_a_hybrid_model_is_saved_and_loaded_layer_for_layer(family, cfg):
 #: commit lowers them. ``int8`` and ``bfloat16``: the GPT-2 plan over such
 #: a pool (taken at PR 27: the hybrid family's cache kinds and the one
 #: positions helper left them byte for byte). ``hybrid``: the hybrid plan
-#: at its rehearsal widths over a bfloat16 pool (taken at PR 31's parent).
+#: at its rehearsal widths over a bfloat16 pool (prefill taken at PR 31's
+#: parent; decode at PR 32, which meant to change it: two more counts in
+#: the expert layers' ``stats``. Off the TPU the grouped product lowers
+#: as it did; the TPU's kernel is pinned in ``test_tpu_compile.py``).
 #: A program that lowers to the same text has the same key in the compile
 #: cache and loads the same executable. To renew after a deliberate
 #: change: run ``_digest`` on the commit before it.
@@ -489,7 +584,7 @@ PARENT_DIGESTS = {
     ("int8", "prefill"): "2b1131b1fd2308b7",
     ("bfloat16", "decode"): "b7e9c46cc4e32c1a",
     ("bfloat16", "prefill"): "23f7f9cff5af7765",
-    ("hybrid", "decode"): "f1138db48ae372dd",
+    ("hybrid", "decode"): "9189c7c1d0971b6e",
     ("hybrid", "prefill"): "e0af96c75ae1e56c",
 }
 

@@ -173,24 +173,33 @@ def test_paged_ragged_decode_walks_pages_and_copies_no_pool(
 # -- the hybrid family's decode program at its published widths -----------------
 
 
-def test_hybrid_decode_program_compiles_at_published_widths(v5e_chip):
+def _grouped_kernels(text) -> int:
+    """Calls of the grouped matmul kernel in a compiled program."""
+    return len(re.findall(r"%grouped_matmul[.\d]* = .*tpu_custom_call", text))
+
+
+def test_hybrid_decode_program_compiles_at_published_widths(
+        v5e_chip, monkeypatch):
     """One whole period of the ``ling-3.0-flash`` cut (two dense layers,
     then KDA x 3, MLA, so six layers: every kind of layer), 64 slots, 8192
     latent pages: the grouped product over the experts held lowers to the
-    chip's ragged-dot kernel, the logits stay ``f32[slots, vocabulary]``
-    (how the trace readers find the decode program), and the program fits
-    beside its weights."""
+    chip's grouped matmul kernel (``ops/grouped_matmul.py``; the program
+    is built as on a TPU: no chip is attached), the logits stay
+    ``f32[slots, vocabulary]`` (how the trace readers find the decode
+    program), and the program fits beside its weights."""
     import json
     import pathlib
 
     from tpu_dist.models.hybrid import build_hybrid_lm
     from tpu_dist.models.policy import policy, set_policy
+    from tpu_dist.ops import grouped_matmul
     from tpu_dist.serve import kv_cache
 
     root = pathlib.Path(__file__).resolve().parents[1]
     cfg = json.loads(
         (root / "tpubench/configs/ling-3.0-flash.json").read_text())
     cfg = {**cfg, "num_hidden_layers": 6}
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
     slots, pages, page_size, max_pages = 64, 8192, 16, 128
     before = policy()
     set_policy("mixed_bfloat16")
@@ -224,7 +233,32 @@ def test_hybrid_decode_program_compiles_at_published_widths(v5e_chip):
     text = compiled.as_text()
     assert pool["latent"].shape == (1, pages + 1, page_size, 576)
     assert pool["state"].shape == (5, slots, 32, 128, 128)
-    # Four expert layers: three grouped products and their metadata each.
-    assert text.count('op_name="ragged-dot') >= 4 * 4
+    # Four expert layers, three grouped products each, all the kernel's.
+    assert _grouped_kernels(text) == 4 * 3
+    # None is XLA's ragged-dot, which sizes its row tile by the rows it is
+    # handed (all 512: "512,512,256") and makes every touched expert pay it.
+    assert "ragged_dot_tiling" not in text
     assert f"f32[{slots},{cfg['vocab_size']}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("rows, depth, width", [
+    (512, 2560, 768),     # the cell's decode step, gate and up
+    (4096, 768, 2560),    # the cell's prefill chunk, down
+    (520, 4096, 1536),    # rows padded to whole tiles, the matrix in 4 tiles
+    (64, 7168, 2048),     # fewer rows than a tile, the matrix in 8 tiles
+])
+def test_grouped_matmul_kernel_compiles(v5e_chip, monkeypatch, rows, depth,
+                                        width):
+    """The routed experts' grouped product on a TPU: row tiles of
+    ``ROW_TILE`` and a group's whole matrix a tile, or a part of its
+    columns where the whole would not fit twice in fast memory."""
+    from tpu_dist.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+    text = _compiled_text(
+        grouped_matmul.grouped_dot, shape((rows, depth), jnp.bfloat16),
+        shape((16, depth, width), jnp.bfloat16), shape((16,), jnp.int32))
+    assert _grouped_kernels(text) == 1
+    assert "ragged_dot_tiling" not in text

@@ -14,10 +14,14 @@ chips or their traffic; the parts that all the shares give, with the
 shared expert counted once, add up to the uncut layer (tests pin it).
 
 The product: the ``tokens x top_k`` assignments are sorted by expert, the
-rows of held experts first and in expert order, and three
-``jax.lax.ragged_dot`` calls run over the groups (on a TPU XLA lowers that
-to a grouped matmul that reads only the experts that have rows). Router,
-scores and weights are float32, the router's product at ``highest``.
+rows of held experts first and in expert order, and three grouped products
+run over the groups (``ops/grouped_matmul.grouped_dot``: on a TPU a kernel
+that visits only the row tiles of ``ROW_TILE`` sorted rows which hold a
+row with an expert, and reads each touched expert's matrix once a tile;
+elsewhere ``jax.lax.ragged_dot``). Whatever the router does, nothing is
+dropped and no bound is assumed: if every choice falls on a held expert,
+every row tile is visited. Router, scores and weights are float32, the
+router's product at ``highest``.
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 
 from tpu_dist.models.layers import Layer
 from tpu_dist.ops import initializers
-
+from tpu_dist.ops.grouped_matmul import ROW_TILE, grouped_dot
 
 def route(scores, bias, *, top_k: int, n_group: int, topk_group: int,
           scaling: float):
@@ -112,8 +116,9 @@ class RoutedExperts(Layer):
         slot, a chunk's padding) choose nothing, so they reach no expert
         but the shared one and are not counted. ``stats``, int32:
         ``[assignments made, those that fell on held experts, held experts
-        touched, fullest held expert's tokens]``: what the grouped product
-        below computes."""
+        touched, fullest held expert's tokens, sorted rows in the row
+        tiles of ``ROW_TILE`` that hold a row with an expert, sorted rows
+        in all]``: what the grouped product below computes."""
         first, count = self.experts_held
         d = x.shape[-1]
         flat = x.reshape(-1, d)
@@ -129,9 +134,9 @@ class RoutedExperts(Layer):
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
         rows = flat[order // k]                                    # [T*k, d]
-        dot = lambda a, w: jax.lax.ragged_dot(a, w.astype(a.dtype), sizes)
-        h = jax.nn.silu(dot(rows, params["wg"])) * dot(rows, params["wu"])
-        out = dot(h, params["wd"])
+        h = (jax.nn.silu(grouped_dot(rows, params["wg"], sizes))
+             * grouped_dot(rows, params["wu"], sizes))
+        out = grouped_dot(h, params["wd"], sizes)
         scale = jnp.where(held, weights, 0.0).reshape(-1)[order]
         out = jnp.where(scale[:, None] != 0.0,
                         out.astype(jnp.float32) * scale[:, None], 0.0)
@@ -143,9 +148,13 @@ class RoutedExperts(Layer):
             y = y + swiglu(flat, params["shared_wg"], params["shared_wu"],
                            params["shared_wd"])
         made = (t if valid is None else jnp.sum(valid)) * k
-        stats = jnp.stack([jnp.asarray(made, jnp.int32), jnp.sum(sizes),
+        n_held = jnp.sum(sizes)
+        stats = jnp.stack([jnp.asarray(made, jnp.int32), n_held,
                            jnp.sum(sizes > 0).astype(jnp.int32),
-                           jnp.max(sizes)])
+                           jnp.max(sizes),
+                           jnp.minimum(-(-n_held // ROW_TILE) * ROW_TILE,
+                                       t * k),
+                           jnp.asarray(t * k, jnp.int32)])
         return y.reshape(x.shape), stats
 
     def apply(self, params, state, x, *, training=False, rng=None):

@@ -50,7 +50,8 @@ against those its table rows address); and once a round
 ``serve.step.host_s``, the
 round's duration less its two waits for the device. A model with routed
 experts adds ``serve.moe.assignments``, ``.assignments_held``,
-``.experts_touched`` and the distribution ``serve.moe.load_max`` (device
+``.experts_touched``, ``.rows_multiplied``, ``.rows_sorted`` and the
+distribution ``serve.moe.load_max`` (device
 scalars of the decode steps that ride the logits' read-back); one with
 recurrent layers adds ``serve.prefill.scan_chunks``, the gauges
 ``serve.state.slots_live``, ``serve.state.bytes`` and
@@ -995,12 +996,14 @@ class ServeEngine:
             metrics.inc("serve.logits.bytes", array.nbytes)
             for qerr in jax.device_get(self._pending_qerr):
                 metrics.observe_value("serve.kv.quant_error", float(qerr))
-            for made, held, touched, fullest in jax.device_get(
+            for made, held, touched, fullest, walked, rows in jax.device_get(
                     self._pending_moe):
                 metrics.inc("serve.moe.assignments", int(made))
                 metrics.inc("serve.moe.assignments_held", int(held))
                 metrics.inc("serve.moe.experts_touched", int(touched))
                 metrics.observe_value("serve.moe.load_max", float(fullest))
+                metrics.inc("serve.moe.rows_multiplied", int(walked))
+                metrics.inc("serve.moe.rows_sorted", int(rows))
         self._pending_qerr.clear()
         self._pending_moe.clear()
         return array

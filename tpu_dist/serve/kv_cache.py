@@ -1085,8 +1085,9 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
     if moe_stats:
         # Summed over the expert layers; the fullest expert is a maximum.
         stacked = jnp.stack(moe_stats)
+        sums = jnp.sum(stacked, axis=0)
         return pool, logits, jnp.concatenate(
-            [jnp.sum(stacked[:, :3], axis=0), jnp.max(stacked[:, 3:], axis=0)])
+            [sums[:3], jnp.max(stacked[:, 3:4], axis=0), sums[4:]])
     return pool, logits
 
 
