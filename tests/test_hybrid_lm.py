@@ -255,27 +255,54 @@ def _expert_layer(held, shared):
 SHARE_TOL = 2e-5
 
 
-def test_the_shares_add_up_to_the_uncut_layer(family, cfg):
-    """Four chips hold four experts each: their routed parts, plus the
-    shared expert counted once, are the whole layer, in the program and
-    against the reference's uncut layer."""
-    whole = _expert_layer((0, 16), 32)
+#: The two families' expert layers at rehearsal widths: how many experts
+#: the router spans, how many a chip holds, and the choice among them.
+SHARES = {
+    "ling-4x4-of-16": dict(
+        experts=16, held=4, top_k=2, n_group=4, topk_group=2,
+        reference="ling_hybrid.py", config="ling-3.0-flash.json"),
+    "exaone-8x16-of-128": dict(
+        experts=128, held=16, top_k=8, n_group=1, topk_group=1,
+        reference="exaone_moe.py", config="k-exaone-236b-a23b.json"),
+}
+
+
+@pytest.mark.parametrize("shape", list(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(shape):
+    """The chips of a layer hold a range of its experts each: their routed
+    parts, plus the shared expert counted once, are the whole layer, in the
+    program and against the family's reference of the uncut layer."""
+    s = SHARES[shape]
+    n, held, k = s["experts"], s["held"], s["top_k"]
+    layer = lambda held, shared: RoutedExperts(
+        num_experts=n, experts_held=held, top_k=k, n_group=s["n_group"],
+        topk_group=s["topk_group"], ff_dim=32, shared_ff_dim=shared,
+        routed_scaling=2.5)
+    whole = layer((0, n), 32)
     p, _, _ = whole.init(jax.random.PRNGKey(0), (24, 64))
-    p["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(1), (16,))
+    p["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(1), (n,))
     x = jax.random.normal(jax.random.PRNGKey(2), (3, 24, 64))
     want, stats = whole.forward(p, x)
-    assert int(stats[0]) == int(stats[1]) == 3 * 24 * 2  # all are held here
-    routed_only = {k: v for k, v in p.items() if not k.startswith("shared")}
+    assert int(stats[0]) == int(stats[1]) == 3 * 24 * k  # all are held here
+    routed_only = {k_: v for k_, v in p.items() if not k_.startswith("shared")}
     total = hybrid.swiglu(x, p["shared_wg"], p["shared_wu"], p["shared_wd"])
     held_sum = 0
-    for first in (0, 4, 8, 12):
-        part = {**routed_only, **{k: p[k][first:first + 4]
-                                  for k in ("wg", "wu", "wd")}}
-        y, stats = _expert_layer((first, 4), 0).forward(part, x)
+    for first in range(0, n, held):
+        part = {**routed_only, **{k_: p[k_][first:first + held]
+                                  for k_ in ("wg", "wu", "wd")}}
+        y, stats = layer((first, held), 0).forward(part, x)
         total, held_sum = total + y, held_sum + int(stats[1])
-    assert held_sum == 3 * 24 * 2          # every assignment on one chip
+    assert held_sum == 3 * 24 * k          # every assignment on one chip
     assert float(jnp.abs(total - want).max()) < SHARE_TOL
-    ref_cfg = {**cfg, "num_experts": 16, "experts_held": [0, 16]}
+    spec = importlib.util.spec_from_file_location(
+        "family_for_shares", ROOT / "tpubench/reference" / s["reference"])
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    full = json.loads((ROOT / "tpubench/configs" / s["config"]).read_text())
+    ref_cfg = {**full, **full["rehearsal"], "num_experts": n,
+               "num_experts_published": n, "experts_held": [0, n],
+               "num_experts_per_tok": k, "moe_intermediate_size": 32,
+               "n_group": s["n_group"], "topk_group": s["topk_group"]}
     w = {"router": p["router"], "bias": p["bias"], "ewg": p["wg"],
          "ewu": p["wu"], "ewd": p["wd"], "swg": p["shared_wg"],
          "swu": p["shared_wu"], "swd": p["shared_wd"]}

@@ -247,6 +247,8 @@ def test_hybrid_decode_program_compiles_at_published_widths(
     (4096, 768, 2560),    # the cell's prefill chunk, down
     (520, 4096, 1536),    # rows padded to whole tiles, the matrix in 4 tiles
     (64, 7168, 2048),     # fewer rows than a tile, the matrix in 8 tiles
+    (512, 6144, 2048),    # exaone_moe's decode step, gate and up: 8 tiles
+    (4096, 2048, 6144),   # exaone_moe's prefill chunk, down: 8 tiles of 768
 ])
 def test_grouped_matmul_kernel_compiles(v5e_chip, monkeypatch, rows, depth,
                                         width):
@@ -262,3 +264,92 @@ def test_grouped_matmul_kernel_compiles(v5e_chip, monkeypatch, rows, depth,
         shape((16, depth, width), jnp.bfloat16), shape((16,), jnp.int32))
     assert _grouped_kernels(text) == 1
     assert "ragged_dot_tiling" not in text
+
+
+# -- the exaone_moe family's programs at their published widths ---------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_exaone_programs_compile_at_published_widths(v5e_chip, monkeypatch,
+                                                     program):
+    """One period of the ``k-exaone-236b-a23b`` cut (layers 0-3: the dense
+    layer, three window layers, one full layer), 64 slots of 8192
+    positions: the decode step's full layer walks its pages through the
+    kernel with 8 query heads a K/V head, the window layers read their
+    rings in XLA, the expert layers' products are the grouped kernel's;
+    the chunk program walks the full layer's pages in key blocks and builds
+    no score over the table row."""
+    import json
+    import pathlib
+
+    from tpu_dist.models.hybrid import build_exaone_moe_lm
+    from tpu_dist.models.policy import policy, set_policy
+    from tpu_dist.ops import grouped_matmul, paged_attention
+    from tpu_dist.serve import kv_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads(
+        (root / "tpubench/configs/k-exaone-236b-a23b.json").read_text())
+    cfg = {**cfg, "num_hidden_layers": 4}
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        paged_attention, "paged_attention",
+        functools.partial(paged_attention.paged_attention, interpret=False))
+    kv_cache._walked_attention.clear_cache()
+    slots, pages, page_size, max_pages, chunk = 64, 8192, 16, 512, 512
+    before = policy()
+    set_policy("mixed_bfloat16")
+    try:
+        model = build_exaone_moe_lm(cfg)
+        plan = kv_cache.build_plan(model)
+
+        def on_chip(tree, matrices=None):
+            return jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(
+                    s.shape, matrices if matrices and s.ndim >= 2
+                    and s.shape[-1] > 512 else s.dtype, sharding=v5e_chip),
+                tree)
+
+        # Matrices as the benchmark's family hands them: bfloat16.
+        params = on_chip(jax.eval_shape(lambda: model.init(0))["params"],
+                         jnp.bfloat16)
+        pool = on_chip(jax.eval_shape(lambda: kv_cache.init_page_pool(
+            plan, num_pages=pages, page_size=page_size, dtype=jnp.bfloat16,
+            slots=slots)))
+        assert pool["k"].shape == (1, pages + 1, page_size, 8 * 128)
+        assert pool["wk"].shape == (3, slots, 128, 8 * 128)
+        assert paged_attention.supported(pool["k"], max_pages)
+        arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                     sharding=v5e_chip)
+        if program == "decode":
+            compiled = jax.jit(
+                functools.partial(kv_cache.paged_decode_ragged, plan,
+                                  walk=True),
+                donate_argnums=(1,)).lower(
+                    params, pool, arr((slots, max_pages), jnp.int32),
+                    arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+                    arr((slots,), jnp.bool_)).compile()
+        else:
+            compiled = jax.jit(
+                functools.partial(kv_cache.paged_prefill, plan),
+                donate_argnums=(1,)).lower(
+                    params, pool, arr((max_pages,), jnp.int32),
+                    arr((chunk,), jnp.int32), arr((), jnp.int32),
+                    arr((), jnp.int32), arr((), jnp.int32)).compile()
+    finally:
+        set_policy(before)
+        kv_cache._walked_attention.clear_cache()
+    text = compiled.as_text()
+    # Three expert layers, three grouped products each.
+    assert _grouped_kernels(text) == 3 * 3
+    assert "ragged_dot_tiling" not in text
+    if program == "decode":
+        # One page walk (the full layer), and no slot's table row gathered.
+        assert text.count("tpu_custom_call") == 3 * 3 + 1
+        assert f"[{slots},{max_pages * page_size},1024]" not in text
+        assert f"f32[{slots},{cfg['vocab_size']}]" in text
+    else:
+        # Scores a key block wide, never the table row's 8192 positions.
+        assert f",{chunk},{max_pages * page_size}]" not in text
+        assert f",{chunk},{kv_cache.PREFILL_KEY_BLOCK}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

@@ -1,6 +1,8 @@
-"""Hybrid-attention LM layers: RMSNorm, interleaved RoPE, a gated (SwiGLU)
-MLP, latent attention (MLA) and delta-rule linear attention (KDA), and the
-constructor that builds a causal LM from published ``config.json`` keys.
+"""Hybrid-attention LM layers: RMSNorm, RoPE (interleaved or half-split
+pairs), a gated (SwiGLU) MLP, latent attention (MLA), delta-rule linear
+attention (KDA) and grouped-query attention with an optional window, and
+the constructors that build a causal LM from published ``config.json``
+keys (:func:`build_hybrid_lm`, :func:`build_exaone_moe_lm`).
 
 The layers follow the protocol of ``layers.py`` (immutable descriptions,
 parameters owned by the caller), so :func:`build_hybrid_lm` returns the
@@ -20,6 +22,13 @@ weights and code with the plain forward:
   ``o_t = S_t^T q_t``. :func:`delta_rule_chunked` is the WY/UT form over
   blocks of 64 (full forward, prefill; state carried in and out),
   :func:`delta_rule_step` one token (decode).
+* :class:`GroupedQueryAttention` — ``num_heads`` query heads over
+  ``num_kv_heads`` K/V heads, a per-head RMSNorm on ``q`` and ``k``,
+  optional RoPE, optional window. ``project`` gives the normed, rotated
+  ``q`` and the ``k``/``v`` rows a cache holds; ``attend`` is the softmax
+  over whatever keys a caller hands it (the whole sequence, a ring of the
+  last ``window`` keys plus a chunk's own, a slot's gathered pages) and
+  ``attend_blocks`` the same softmax a block of keys at a time.
 
 Precision: matrices multiply in the policy's compute dtype; norms,
 softmax, gates, the recurrent state and everything that touches it are
@@ -63,15 +72,21 @@ def rms_norm(x, gamma, eps: float):
     return (y * gamma).astype(x.dtype)
 
 
-def rope(x, pos, theta: float):
-    """Rotary positions on ``x`` [..., L, n], interleaved pairs
-    ``(x[2i], x[2i+1])`` turned by ``pos * theta ** (-2i / n)``; ``pos``
-    is ``[L]`` or broadcastable ``[..., L]`` absolute positions. float32."""
+def rope(x, pos, theta: float, *, interleaved: bool = True):
+    """Rotary positions on ``x`` [..., L, n]: pair ``i`` is turned by
+    ``pos * theta ** (-2i / n)``; ``pos`` is ``[L]`` or broadcastable
+    ``[..., L]`` absolute positions. float32. The pairs are
+    ``(x[2i], x[2i+1])`` when ``interleaved``, else the half-split
+    ``(x[i], x[i + n/2])`` of the Hugging Face ``rotate_half``."""
     n = x.shape[-1]
     inv = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
     ang = pos[..., None].astype(jnp.float32) * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
+    if not interleaved:
+        a, b = xf[..., :n // 2], xf[..., n // 2:]
+        return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                               axis=-1)
     a, b = xf[..., 0::2], xf[..., 1::2]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape)
@@ -101,6 +116,21 @@ class StreamEmbedding(Embedding):
 
     def apply(self, params, state, x, *, training=False, rng=None):
         return params["table"][x].astype(jnp.float32), state
+
+
+@dataclasses.dataclass(frozen=True, repr=False)
+class ComputeCast(Layer):
+    """Hands a sublayer the float32 residual stream in the policy's
+    compute dtype: what a pre-norm block's :class:`RMSNorm` does on the
+    way, for a block whose norm sits on the sublayer's OUTPUT."""
+
+    def init(self, key, in_shape):
+        return {}, {}, in_shape
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from tpu_dist.models.policy import compute_dtype
+
+        return x.astype(compute_dtype()), state
 
 
 @dataclasses.dataclass(frozen=True, repr=False)
@@ -238,6 +268,152 @@ class LatentAttention(Layer):
         o = self.attend_expanded(params, q_nope, q_rope,
                                  latent.astype(x.dtype), mask)
         return self.output(params, x, jnp.moveaxis(o, -3, -2)), state
+
+
+# -- grouped-query attention ---------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True, repr=False)
+class GroupedQueryAttention(Layer):
+    """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
+    K/V heads (query head ``h`` reads K/V head ``h // (num_heads //
+    num_kv_heads)``), no biases: per-head RMSNorm on ``q`` and ``k`` (one
+    ``gamma`` each a layer), then RoPE over the whole head in half-split
+    pairs where ``rope_theta`` is given, a softmax in float32 over the keys
+    ``j <= t`` and, with a ``window``, ``t - j < window`` (a query sees
+    itself and the ``window - 1`` before it)."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: int | None = None
+    rope_theta: float | None = None
+    epsilon: float = 1e-6
+
+    def __post_init__(self):
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads do not share "
+                f"{self.num_kv_heads} K/V heads evenly")
+
+    @property
+    def kv_width(self) -> int:
+        """Values of one cached ``k`` (or ``v``) row: every K/V head."""
+        return self.num_kv_heads * self.head_dim
+
+    def init(self, key, in_shape):
+        d, dk = in_shape[-1], self.head_dim
+        ks = jax.random.split(key, 4)
+        return ({
+            "wq": _glorot(ks[0], (d, self.num_heads * dk)),
+            "wk": _glorot(ks[1], (d, self.kv_width)),
+            "wv": _glorot(ks[2], (d, self.kv_width)),
+            "q_norm": jnp.ones((dk,), jnp.float32),
+            "k_norm": jnp.ones((dk,), jnp.float32),
+            "wo": _glorot(ks[3], (self.num_heads * dk, d)),
+        }, {}, in_shape)
+
+    def project(self, p, x, pos):
+        """``x`` [.., L, d] at absolute positions ``pos`` ([L], or [.., L]
+        where rows differ: one token a row in decode) -> ``(q [.., H, L,
+        dk]`` float32, ``k``, ``v`` [.., L, G * dk])``: the rows a cache
+        holds, ``k`` normed and rotated, in ``x``'s dtype."""
+        *lead, ln, _ = x.shape
+        dk = self.head_dim
+        heads = lambda w, n: (x @ p[w].astype(x.dtype)).reshape(
+            *lead, ln, n, dk)
+        q = rms_norm(heads("wq", self.num_heads).astype(jnp.float32),
+                     p["q_norm"], self.epsilon)
+        k = rms_norm(heads("wk", self.num_kv_heads).astype(jnp.float32),
+                     p["k_norm"], self.epsilon)
+        q, k = jnp.moveaxis(q, -2, -3), jnp.moveaxis(k, -2, -3)  # [.,n,L,dk]
+        if self.rope_theta is not None:
+            at = pos if pos.ndim == 1 else pos[..., None, :]     # over heads
+            q = rope(q, at, self.rope_theta, interleaved=False)
+            k = rope(k, at, self.rope_theta, interleaved=False)
+        k = jnp.moveaxis(k, -3, -2).reshape(*lead, ln, self.kv_width)
+        return q, k.astype(x.dtype), x @ p["wv"].astype(x.dtype)
+
+    def sees(self, q_pos, k_pos):
+        """Whether a query at ``q_pos`` [.., L] attends a key at ``k_pos``
+        [.., S]: -> bool [.., L, S]. A negative ``k_pos`` is no key."""
+        q_pos, k_pos = q_pos[..., :, None], k_pos[..., None, :]
+        ok = (k_pos <= q_pos) & (k_pos >= 0)
+        if self.window is not None:
+            ok &= q_pos - k_pos < self.window
+        return ok
+
+    def scores(self, q, k):
+        """``q`` [.., H, L, dk] against cached rows ``k`` [.., S, G * dk]
+        -> scaled scores [.., G, H / G, L, S] float32; the operands enter
+        the product in the rows' dtype."""
+        *lead, h, ln, dk = q.shape
+        g = self.num_kv_heads
+        q = q.reshape(*lead, g, h // g, ln, dk).astype(k.dtype)
+        k = k.reshape(*k.shape[:-1], g, dk)
+        s = jnp.einsum("...grqd,...sgd->...grqs", q, k,
+                       preferred_element_type=jnp.float32)
+        return s / math.sqrt(dk)
+
+    def weigh(self, prob, v):
+        """Probabilities [.., G, H / G, L, S] over cached rows ``v``
+        [.., S, G * dk] -> [.., H, L, dk] float32."""
+        g, dk = self.num_kv_heads, self.head_dim
+        v = v.reshape(*v.shape[:-1], g, dk)
+        o = jnp.einsum("...grqs,...sgd->...grqd", prob.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(*o.shape[:-4], self.num_heads, *o.shape[-2:])
+
+    def attend(self, q, k, v, mask):
+        """Softmax attention of ``q`` [.., H, L, dk] over the rows ``k``,
+        ``v`` [.., S, G * dk] under ``mask`` [.., L, S] (True = attend;
+        every query sees a key). Returns ``[.., H, L, dk]`` float32."""
+        s = jnp.where(mask[..., None, None, :, :], self.scores(q, k),
+                      -jnp.inf)
+        return self.weigh(jax.nn.softmax(s, axis=-1), v)
+
+    def attend_blocks(self, q, q_pos, fetch, n_blocks, block: int):
+        """The same softmax over keys ``0 .. n_blocks * block - 1``, a
+        block at a time: ``fetch(i)`` hands over block ``i``'s rows ``(k,
+        v)`` [block, G * dk], whose key ``j`` is position ``i * block +
+        j``. Running maximum, sum and accumulator in float32; no score
+        wider than a block is ever built. ``q`` [H, L, dk] at ``q_pos``
+        [L]; ``n_blocks`` may be traced; block 0 holds a key every query
+        sees. Returns ``[H, L, dk]`` float32."""
+        h, ln, dk = q.shape
+        g = self.num_kv_heads
+        shape = (g, h // g, ln)
+
+        def one(i, carry):
+            m, l, acc = carry
+            k, v = fetch(i)
+            k_pos = i * block + jnp.arange(block)
+            s = jnp.where(self.sees(q_pos, k_pos), self.scores(q, k),
+                          -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = alpha.reshape(h, ln, 1) * acc + self.weigh(p, v)
+            return m_new, l, acc
+
+        m, l, acc = jax.lax.fori_loop(
+            0, n_blocks, one,
+            (jnp.full(shape, -jnp.inf, jnp.float32),
+             jnp.zeros(shape, jnp.float32),
+             jnp.zeros((h, ln, dk), jnp.float32)))
+        return acc / l.reshape(h, ln, 1)
+
+    def output(self, p, x, o):
+        """``o`` [.., H, L, dk] -> ``W_o`` of the heads side by side."""
+        o = jnp.moveaxis(o, -3, -2).astype(x.dtype)
+        return o.reshape(*o.shape[:-2], -1) @ p["wo"].astype(x.dtype)
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        pos = jnp.arange(x.shape[-2])
+        q, k, v = self.project(params, x, pos)
+        o = self.attend(q, k, v, self.sees(pos, pos))
+        return self.output(params, x, o), state
 
 
 # -- delta-rule linear attention ---------------------------------------------
@@ -484,3 +660,73 @@ def build_hybrid_lm(cfg: dict):
     layers += [RMSNorm(eps), Dense(cfg["vocab_size"], use_bias=False)]
     return Sequential(layers, input_shape=(cfg.get("served_positions", 64),),
                       name="hybrid_lm")
+
+
+def exaone_layer_kinds(cfg: dict) -> list:
+    """``[(attention, ffn)]`` a layer from ``layer_types`` and
+    ``mlp_layer_types`` (the published lists, of which the first
+    ``num_hidden_layers`` are built): ``"window"`` | ``"full"``,
+    ``"dense"`` | ``"moe"``."""
+    n = cfg["num_hidden_layers"]
+    attn = {"sliding_attention": "window", "full_attention": "full"}
+    ffn = {"dense": "dense", "sparse": "moe"}
+    return [(attn[a], ffn[f]) for a, f in zip(cfg["layer_types"][:n],
+                                              cfg["mlp_layer_types"][:n])]
+
+
+def build_exaone_moe_lm(cfg: dict):
+    """A causal LM of the ``exaone_moe`` shape from the published keys of
+    its ``config.json`` (``cfg``): token embedding, blocks of grouped-query
+    attention (window layers rotate ``q`` and ``k`` and see
+    ``sliding_window`` keys; full layers carry no rotary positions) and a
+    dense or expert FFN, final RMSNorm, an untied bias-free head. **The
+    norms sit on the sublayers' OUTPUT** (``x <- x + RMSNorm(Attn(x))``,
+    ``x <- x + RMSNorm(FFN(x))``, the family's own placement): no norm on
+    a sublayer's input, so the float32 stream reaches it through
+    :class:`ComputeCast`. ``experts_held``, ``num_experts_published`` and
+    ``vocab_size`` as :func:`build_hybrid_lm` reads them."""
+    from tpu_dist.models.model import Sequential
+    from tpu_dist.parallel.routed_experts import RoutedExperts
+
+    d, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+    routed = cfg.get("num_experts_published", cfg["num_experts"])
+    held = tuple(cfg.get("experts_held", (0, routed)))
+    kinds = exaone_layer_kinds(cfg)
+    if [f for _, f in kinds] != [
+            "dense" if i < cfg["first_k_dense_replace"] else "moe"
+            for i in range(len(kinds))]:
+        raise ValueError("first_k_dense_replace and mlp_layer_types disagree")
+
+    def attention(kind):
+        window = kind == "window"
+        return GroupedQueryAttention(
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg["head_dim"],
+            window=int(cfg["sliding_window"]) if window else None,
+            rope_theta=(float(cfg["rope_parameters"]["rope_theta"])
+                        if window else None),
+            epsilon=eps)
+
+    def ffn(kind):
+        if kind == "dense":
+            return GatedMLP(cfg["intermediate_size"])
+        return RoutedExperts(
+            num_experts=routed, experts_held=held,
+            top_k=cfg["num_experts_per_tok"], n_group=cfg["n_group"],
+            topk_group=cfg["topk_group"],
+            ff_dim=cfg["moe_intermediate_size"],
+            shared_ff_dim=(cfg["num_shared_experts"]
+                           * cfg["moe_intermediate_size"]),
+            routed_scaling=float(cfg["routed_scaling_factor"]))
+
+    layers = [StreamEmbedding(cfg["vocab_size"], d)]
+    for attn, mlp in kinds:
+        layers.append(Block(layers=(
+            Residual(main=(ComputeCast(), attention(attn), RMSNorm(eps)),
+                     shortcut=(), activation=None),
+            Residual(main=(ComputeCast(), ffn(mlp), RMSNorm(eps)),
+                     shortcut=(), activation=None))))
+    layers += [RMSNorm(eps), Dense(cfg["vocab_size"], use_bias=False)]
+    return Sequential(layers, input_shape=(cfg.get("served_positions", 64),),
+                      name="exaone_moe_lm")

@@ -32,6 +32,14 @@ else:
     masked by the slot's key count: scores, scale rows and payload
     alike, since what no copy filled holds whatever was there.
 
+**Grouped heads** (a float pool whose page rows hold ``G`` K/V heads for
+``H = G * r`` query heads): the slab is ``[page_size, G * dk]``, the
+block-diagonal query ``[H, G * dk]`` holds ``q_h`` in the columns of K/V
+head ``h // r``, so ``S`` is still ``[H, T]`` and one product serves all
+the query heads of a block; the accumulator's row ``h`` is read back from
+those columns into an ``[H, dk]`` output. With ``r = 1`` it is the kernel
+above, instruction for instruction.
+
 The scale rows reach the kernel gathered by table row (``[b, H, S]``
 fp32, a sixteenth of the payload's bytes at ``dk = 64``): their pages are
 too narrow for a copy of their own (``page_size`` x ``H`` floats against
@@ -104,7 +112,8 @@ def log_declined(shape: tuple, max_pages: int, reason: str) -> None:
 
 
 def _kernel(layer_ref, n_keys_ref, tables_ref, q_ref, *refs, num_heads: int,
-            max_pages: int, ppb: int, sm_scale: float, quantized: bool):
+            max_pages: int, ppb: int, sm_scale: float, quantized: bool,
+            group: int = 1):
     if quantized:
         ks_ref, vs_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
     else:
@@ -114,7 +123,7 @@ def _kernel(layer_ref, n_keys_ref, tables_ref, q_ref, *refs, num_heads: int,
     n_keys = n_keys_ref[b]
     ps, width = k_buf.shape[2], k_buf.shape[3]
     block = ppb * ps
-    dk = width // num_heads
+    dk = width * group // num_heads
     n_pages = (n_keys + ps - 1) // ps
     n_blocks = (n_keys + block - 1) // block
     mxu = q_ref.dtype
@@ -143,12 +152,18 @@ def _kernel(layer_ref, n_keys_ref, tables_ref, q_ref, *refs, num_heads: int,
             0, ppb, lambda j, _: page_copy(blk, buf, j, lambda c: c.wait()),
             None)
 
-    # Row h of the block-diagonal query keeps q's columns of head h.
+    # Row h of the block-diagonal query keeps q's columns of head h: its
+    # own where every query head has a K/V head, else those of the K/V
+    # head it shares (``group`` query heads to one).
     head_of_col = jax.lax.broadcasted_iota(
         jnp.int32, (num_heads, width), 1) // dk
-    own = head_of_col == jax.lax.broadcasted_iota(
-        jnp.int32, (num_heads, width), 0)
-    qbd = jnp.where(own, q_ref[...].astype(jnp.float32), 0.0).astype(mxu)
+    head_of_row = jax.lax.broadcasted_iota(jnp.int32, (num_heads, width), 0)
+    q = q_ref[...].astype(jnp.float32)
+    if group > 1:
+        head_of_row = head_of_row // group
+        q = jnp.concatenate([q] * (width // dk), axis=1)   # [H, G * dk]
+    own = head_of_col == head_of_row
+    qbd = jnp.where(own, q, 0.0).astype(mxu)
 
     @pl.when(n_keys > 0)
     def _():
@@ -205,7 +220,13 @@ def _kernel(layer_ref, n_keys_ref, tables_ref, q_ref, *refs, num_heads: int,
     # A slot with no keys has l == 0: zeros, never NaN (its row goes on
     # through the layers and is written to the scratch page).
     out = jnp.where(own, acc / jnp.where(l > 0.0, l, 1.0), 0.0)
-    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if group > 1:
+        # Row h's values lie in its K/V head's columns: fold the column
+        # groups onto one another (all but one are noughts in each row).
+        o_ref[...] = sum(out[:, g * dk:(g + 1) * dk]
+                         for g in range(width // dk)).astype(o_ref.dtype)
+    else:
+        o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, layer, tables, n_keys, *,
@@ -216,7 +237,9 @@ def paged_attention(q, k_pages, v_pages, layer, tables, n_keys, *,
       q: ``[b, H, dk]``, the compute dtype.
       k_pages / v_pages: the pool's arrays, ``[L, P + 1, page_size,
         H * dk]``, int8 or float; only layer ``layer`` is read (an
-        operand, so every layer's call is the same kernel).
+        operand, so every layer's call is the same kernel). A float pool
+        may hold fewer K/V heads, ``[.., G * dk]`` with ``G`` dividing
+        ``H``: query head ``h`` then reads K/V head ``h // (H // G)``.
       tables: int32 ``[b, max_pages]`` page-table rows.
       n_keys: int32 ``[b]`` — positions ``0 .. n_keys - 1`` of a slot are
         attended; 0 visits no page and yields zeros, and a count past
@@ -236,11 +259,19 @@ def paged_attention(q, k_pages, v_pages, layer, tables, n_keys, *,
     max_pages = tables.shape[1]
     ppb = pages_per_block(max_pages, ps)
     quantized = scales is not None
+    group = num_heads * dk // width
+    if group * width != num_heads * dk or (group > 1 and quantized):
+        raise ValueError(
+            f"paged attention: {num_heads} query heads of {dk} over page "
+            f"rows of {width} values (grouped heads take a float pool)")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     row = lambda i, *_: (i, 0, 0)
-    in_specs = [pl.BlockSpec((None, 1, width), row)]
-    operands = [q.reshape(b, 1, width)]
+    # One query row a slot, all heads side by side; grouped heads come as
+    # [H, dk] and leave so.
+    q_block = (None, 1, width) if group == 1 else (None, num_heads, dk)
+    in_specs = [pl.BlockSpec(q_block, row)]
+    operands = [q.reshape(b, *q_block[1:])]
     if quantized:
         in_specs += [pl.BlockSpec((None, num_heads, max_pages * ps), row)] * 2
         operands += list(scales)
@@ -249,18 +280,19 @@ def paged_attention(q, k_pages, v_pages, layer, tables, n_keys, *,
     out = pl.pallas_call(
         functools.partial(
             _kernel, num_heads=num_heads, max_pages=max_pages, ppb=ppb,
-            sm_scale=dk ** -0.5, quantized=quantized),
+            sm_scale=dk ** -0.5, quantized=quantized,
+            **({"group": group} if group > 1 else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((None, 1, width), row),
+            out_specs=pl.BlockSpec(q_block, row),
             scratch_shapes=[
                 pltpu.VMEM((2, ppb, ps, width), k_pages.dtype),
                 pltpu.VMEM((2, ppb, ps, width), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, *q_block[1:]), q.dtype),
         name="paged_decode_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),

@@ -56,7 +56,13 @@ scalars of the decode steps that ride the logits' read-back); one with
 recurrent layers adds ``serve.prefill.scan_chunks``, the gauges
 ``serve.state.slots_live``, ``serve.state.bytes`` and
 ``serve.prefix.disabled_recurrent``, and a ``serve.step.state_swap`` span
-where compaction moves a slot's state on the device. With whole-prompt
+where compaction moves a slot's state on the device; one with
+grouped-query layers adds ``serve.decode.keys_read`` and
+``.window_keys_read`` (key positions its decode steps attended, all layers
+and the window layers' part; device scalars beside the experts'),
+``serve.prefill.keys_visited`` over ``.keys_addressed`` (the key blocks a
+full layer's chunks walk against the table row's positions) and the gauges
+``serve.cache.window_bytes`` and ``serve.cache.window_slots_live``. With whole-prompt
 prefill (``prefill_chunk=0``) the prompt is one chunk and its span lies
 inside ``serve.step.admit``, whose self time is then the admission alone.
 
@@ -270,10 +276,10 @@ class ServeEngine:
         not given, else guards the explicit pool the same way.
       prefix_caching: paged mode only — disable to keep paging without
         cross-request prefix sharing (parity baselines use this). A model
-        with recurrent layers is served with it OFF whatever is passed:
-        a slot's state is not in its pages, so shared pages would be
-        attached to a state that was not built over them (logged once;
-        gauge ``serve.prefix.disabled_recurrent``).
+        with recurrent or window layers is served with it OFF whatever is
+        passed: a slot's state or ring is not in its pages, so shared
+        pages would be attached to a state that was not built over them
+        (logged once; gauge ``serve.prefix.disabled_recurrent``).
       prefill_chunk: when > 0, split each admitted prompt's prefill into
         chunks of this many positions (power of two >= 8) and interleave
         them with decode steps, so one long prompt no longer stalls
@@ -332,10 +338,11 @@ class ServeEngine:
                  ragged: bool = False):
         self.model = model
         self.plan = kv_cache.build_plan(model)
-        if (self.plan.latent_layers or self.plan.state_layers) and not paged:
+        if self.plan.paged_only and not paged:
             raise ValueError(
-                "serve: latent pages and per-slot recurrent state are kinds "
-                "of the paged cache — pass paged=True")
+                "serve: latent pages, grouped K/V pages and per-slot state "
+                "or window rings are kinds of the paged cache — pass "
+                "paged=True")
         if max_len is None and self.plan.max_position >= 2 ** 30:
             raise ValueError(
                 "serve: the model has no positional table to bound a slot "
@@ -415,8 +422,8 @@ class ServeEngine:
             # tokens, and this engine snapshots no state: no reuse.
             if prefix_caching:
                 logger.info(
-                    "serve: the model has recurrent layers — prefix reuse "
-                    "is off (a slot's state is not in its pages)")
+                    "serve: the model has recurrent or window layers — prefix "
+                    "reuse is off (a slot's state is not in its pages)")
             prefix_caching = False
             metrics.set_gauge("serve.prefix.disabled_recurrent", 1.0)
         if self.paged:
@@ -453,7 +460,9 @@ class ServeEngine:
                 slots=self.max_batch, max_pages=max_pages,
                 bytes_per_token=per_token, prefix_caching=prefix_caching,
                 state_bytes_per_slot=kv_cache.state_nbytes_per_slot(
-                    self.plan))
+                    self.plan),
+                window_bytes_per_slot=kv_cache.window_nbytes_per_slot(
+                    self.plan, cache_dtype))
             logger.info(
                 "serve: paged — %d slots, %d pages x %d positions "
                 "(+scratch), pool %.1f MiB (%s), prefix caching %s, "
@@ -996,8 +1005,11 @@ class ServeEngine:
             metrics.inc("serve.logits.bytes", array.nbytes)
             for qerr in jax.device_get(self._pending_qerr):
                 metrics.observe_value("serve.kv.quant_error", float(qerr))
-            for made, held, touched, fullest, walked, rows in jax.device_get(
-                    self._pending_moe):
+            for (made, held, touched, fullest, walked, rows,
+                 *keys) in jax.device_get(self._pending_moe):
+                for name, n in zip(("serve.decode.keys_read",
+                                    "serve.decode.window_keys_read"), keys):
+                    metrics.inc(name, int(n))
                 metrics.inc("serve.moe.assignments", int(made))
                 metrics.inc("serve.moe.assignments_held", int(held))
                 metrics.inc("serve.moe.experts_touched", int(touched))
@@ -1130,10 +1142,20 @@ class ServeEngine:
                 last = self._unpack_prefill(
                     fn(self.params, self.cache, row, toks, jnp.int32(end),
                        jnp.int32(startpos), *self._state_slot(req)))
-                if self.plan.recurrent:
+                if self.plan.state_layers:
                     metrics.inc("serve.prefill.scan_chunks",
                                 self.plan.state_layers
                                 * -(-pad // hybrid.SCAN_BLOCK))
+                if self.plan.kv_heads:
+                    # Full layers, a chunk: the key blocks walked against
+                    # the positions the table row addresses.
+                    metrics.inc("serve.prefill.keys_visited",
+                                self.plan.num_layers
+                                * kv_cache.prefill_keys_visited(
+                                    len(row), self.page_size, end))
+                    metrics.inc("serve.prefill.keys_addressed",
+                                self.plan.num_layers * len(row)
+                                * self.page_size)
             else:
                 fn = self._chunk_fn(pad)
                 last = self._unpack_prefill(

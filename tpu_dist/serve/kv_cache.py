@@ -38,11 +38,15 @@ equivalence test meaningful. Models outside the servable family
 time with a pointed error.
 
 The plan gives every attention layer a **cache kind**: K/V pages
-(``MultiHeadAttention``), latent pages (``LatentAttention``: one row a
-token for all heads, the same tables) or a per-slot recurrent state
-(``DeltaAttention``). The last two exist on the paged path only (see
-"latent pages and per-slot state" below); ``RoutedExperts`` is a
-token-wise op that also returns its routing counts.
+(``MultiHeadAttention``; a ``GroupedQueryAttention`` layer without a
+window, whose pages hold its K/V heads, fewer than its query heads), latent
+pages (``LatentAttention``: one row a token for all heads, the same
+tables), a per-slot recurrent state (``DeltaAttention``) or **window K/V**
+(a ``GroupedQueryAttention`` layer with a window: a ring of its last
+``window`` keys a slot, whatever the request's length). The last three
+exist on the paged path only (see "latent pages and per-slot state" and
+"grouped-query layers" below); ``RoutedExperts`` is a token-wise op that
+also returns its routing counts.
 """
 
 from __future__ import annotations
@@ -54,9 +58,9 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from tpu_dist.models.hybrid import (DeltaAttention, GatedMLP,
-                                    LatentAttention, RMSNorm, causal_conv,
-                                    delta_rule_step)
+from tpu_dist.models.hybrid import (ComputeCast, DeltaAttention, GatedMLP,
+                                    GroupedQueryAttention, LatentAttention,
+                                    RMSNorm, causal_conv, delta_rule_step)
 from tpu_dist.models.layers import (Block, Dense, Layer, Residual,
                                     _activation)
 from tpu_dist.models.model import Sequential
@@ -71,11 +75,13 @@ from tpu_dist.parallel.routed_experts import RoutedExperts
 
 #: Plan op tags. Ops are plain tuples so the plan stays hashable/static
 #: under jit closures: ("embed"|"pos"|"point"|"moe", layer, path),
-#: ("attn"|"latent"|"state", layer, path, index among its cache kind),
-#: ("res_start",), ("res_end", activation_name). The last three attention
-#: tags are the CACHE KINDS: K/V pages, latent pages (one row a token
-#: shared by all heads), and a per-slot recurrent state.
-_POINTWISE = (LayerNormalization, Dense, RMSNorm, GatedMLP)
+#: ("attn"|"gqa"|"latent"|"state"|"window", layer, path, index among its
+#: cache kind), ("res_start",), ("res_end", activation_name). The attention
+#: tags are the CACHE KINDS: K/V pages ("attn", and "gqa" where the pages
+#: hold fewer K/V heads than the layer has query heads), latent pages (one
+#: row a token shared by all heads), a per-slot recurrent state, and a
+#: per-slot ring of the last ``window`` keys.
+_POINTWISE = (LayerNormalization, Dense, RMSNorm, GatedMLP, ComputeCast)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,26 +101,43 @@ class DecodePlan:
     state_dim: int = 0  #: the state is [heads, state_dim, state_dim]
     conv_taps: int = 0  #: the state layers' short convolution
     moe_layers: int = 0
+    kv_heads: int = 0  #: K/V heads a page row holds (0: ``num_heads``)
+    window_layers: int = 0  #: layers holding a ring of keys a slot
+    window: int = 0  #: keys a window layer's query sees, itself included
 
     @property
     def conv_width(self) -> int:
         return 3 * self.state_heads * self.state_dim
 
     @property
+    def kv_width(self) -> int:
+        """Values of one cached K (or V) row: every K/V head."""
+        return (self.kv_heads or self.num_heads) * self.key_dim
+
+    @property
+    def paged_only(self) -> bool:
+        """Some layer's cache is a kind that only the paged pool holds."""
+        return bool(self.latent_layers or self.state_layers
+                    or self.window_layers or self.kv_heads)
+
+    @property
     def recurrent(self) -> bool:
-        """A slot holds state that its pages do not: prefix reuse would
-        attach pages whose matching state the slot does not have."""
-        return self.state_layers > 0
+        """A slot holds what its pages do not (a recurrent state, a ring
+        of window keys): prefix reuse would attach pages whose matching
+        state the slot does not have."""
+        return self.state_layers > 0 or self.window_layers > 0
 
 
 def _unsupported(layer: Layer, why: str) -> TypeError:
     return TypeError(
         f"serve: {type(layer).__name__} is not servable ({why}); the KV-"
         "cache decode path covers token/positional embeddings, pre-norm "
-        "residual blocks, LayerNorm/RMSNorm, Dense and gated MLPs, default "
-        "causal attention over K/V pages, and on the paged path latent "
-        "attention over latent pages, delta-rule attention over a per-slot "
-        "state, and routed experts held by share")
+        "residual blocks with the norm before or behind the sublayer, "
+        "LayerNorm/RMSNorm, Dense and gated MLPs, default causal attention "
+        "over K/V pages, and on the paged path grouped-query attention "
+        "(q/k norm, RoPE; full layers over K/V pages, window layers over a "
+        "per-slot ring), latent attention over latent pages, delta-rule "
+        "attention over a per-slot state, and routed experts held by share")
 
 
 def build_plan(model: Sequential) -> DecodePlan:
@@ -126,6 +149,8 @@ def build_plan(model: Sequential) -> DecodePlan:
     attn_layers: list[MultiHeadAttention] = []
     latent_layers: list[LatentAttention] = []
     state_layers: list[DeltaAttention] = []
+    gqa_layers: list[GroupedQueryAttention] = []
+    window_layers: list[GroupedQueryAttention] = []
     pos_layers: list[PositionalEmbedding] = []
     moe_layers: list[RoutedExperts] = []
 
@@ -155,6 +180,11 @@ def build_plan(model: Sequential) -> DecodePlan:
             elif isinstance(layer, DeltaAttention):
                 ops.append(("state", layer, p, len(state_layers)))
                 state_layers.append(layer)
+            elif isinstance(layer, GroupedQueryAttention):
+                kind = window_layers if layer.window else gqa_layers
+                ops.append(("window" if layer.window else "gqa", layer, p,
+                            len(kind)))
+                kind.append(layer)
             elif isinstance(layer, RoutedExperts):
                 ops.append(("moe", layer, p))
                 moe_layers.append(layer)
@@ -174,13 +204,25 @@ def build_plan(model: Sequential) -> DecodePlan:
                 raise _unsupported(layer, "no decode rule for this layer")
 
     walk(model.layers, model.layer_names, ())
-    if not (attn_layers or latent_layers or state_layers):
+    if not (attn_layers or latent_layers or state_layers or gqa_layers
+            or window_layers):
         raise TypeError("serve: model has no attention layers to cache")
-    heads = {(l.num_heads, l.key_dim) for l in attn_layers} or {(0, 0)}
-    if len(heads) > 1:
+    if attn_layers and (gqa_layers or window_layers):
         raise TypeError(
-            f"serve: attention layers disagree on (num_heads, key_dim) "
-            f"({sorted(heads)}); a stacked KV cache needs uniform shapes")
+            "serve: MultiHeadAttention beside GroupedQueryAttention layers "
+            "in one model has no pool layout yet")
+    # (query heads, K/V heads, key_dim): one K/V pool and one ring serve
+    # every layer, so full and window layers alike agree on all three.
+    heads = ({(l.num_heads, l.num_heads, l.key_dim) for l in attn_layers}
+             | {(l.num_heads, l.num_kv_heads, l.head_dim)
+                for l in gqa_layers + window_layers}) or {(0, 0, 0)}
+    windows = {l.window for l in window_layers} or {0}
+    if len(heads) > 1 or len(windows) > 1:
+        raise TypeError(
+            f"serve: attention layers disagree on (query heads, K/V heads, "
+            f"key_dim) ({sorted(heads)}) or on their window "
+            f"({sorted(windows)}); a stacked KV cache needs uniform shapes "
+            "(head counts that differ by layer kind have no layout yet)")
     widths = {l.latent_width for l in latent_layers} or {0}
     states = ({(l.num_heads, l.head_dim, l.conv_size) for l in state_layers}
               or {(0, 0, 0)})
@@ -194,18 +236,22 @@ def build_plan(model: Sequential) -> DecodePlan:
         raise TypeError(
             "serve: expected a Dense vocabulary head as the final layer, "
             f"got {type(last).__name__}")
-    (num_heads, key_dim), = heads
+    (num_heads, kv_heads, key_dim), = heads
     (state_heads, state_dim, conv_taps), = states
     max_position = min((l.max_len for l in pos_layers),
                       default=2 ** 30)
-    return DecodePlan(ops=tuple(ops), num_layers=len(attn_layers),
+    return DecodePlan(ops=tuple(ops),
+                      num_layers=len(attn_layers) + len(gqa_layers),
                       num_heads=num_heads, key_dim=key_dim,
                       max_position=max_position, vocab_size=last.units,
                       latent_layers=len(latent_layers),
                       latent_width=widths.pop(),
                       state_layers=len(state_layers),
                       state_heads=state_heads, state_dim=state_dim,
-                      conv_taps=conv_taps, moe_layers=len(moe_layers))
+                      conv_taps=conv_taps, moe_layers=len(moe_layers),
+                      kv_heads=kv_heads if gqa_layers or window_layers else 0,
+                      window_layers=len(window_layers),
+                      window=windows.pop())
 
 
 def init_cache(plan: DecodePlan, *, max_batch: int, max_len: int,
@@ -297,10 +343,14 @@ def _learned_positions(params, path, x, pos):
                 else rows[:, None, :])
 
 
+_PAGED_TAGS = ("latent", "state", "moe", "gqa", "window")
+
+
 def _paged_only(op):
     return TypeError(
-        f"serve: {type(op[1]).__name__} keeps its cache in latent pages or "
-        "per-slot state, which the paged engine manages — pass paged=True")
+        f"serve: {type(op[1]).__name__} keeps its cache in latent pages, "
+        "grouped K/V pages or per-slot state, which the paged engine "
+        "manages — pass paged=True")
 
 
 # -- prefill ------------------------------------------------------------------
@@ -351,7 +401,7 @@ def prefill(plan: DecodePlan, params, cache: dict, tokens, length, slot,
             x = _attn_out(layer, p, out)
         elif tag == "pos":
             x = _learned_positions(params, op[2], x, jnp.arange(pad_len))
-        elif tag in ("latent", "state", "moe"):
+        elif tag in _PAGED_TAGS:
             raise _paged_only(op)
         else:  # "embed" / "point": the layer's own stateless apply
             _, layer, path = op
@@ -412,7 +462,7 @@ def prefill_chunk_step(plan: DecodePlan, params, cache: dict, tokens,
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
             x = _learned_positions(params, op[2], x, pos)
-        elif tag in ("latent", "state", "moe"):
+        elif tag in _PAGED_TAGS:
             raise _paged_only(op)
         elif tag == "attn":
             _, layer, path, idx = op
@@ -479,7 +529,7 @@ def decode_step(plan: DecodePlan, params, cache: dict, tokens, lengths,
             x = _activation(op[1])(residuals.pop() + x)
         elif tag == "pos":
             x = _learned_positions(params, op[2], x, pos)
-        elif tag in ("latent", "state", "moe"):
+        elif tag in _PAGED_TAGS:
             raise _paged_only(op)
         elif tag == "attn":
             _, layer, path, idx = op
@@ -590,7 +640,7 @@ def page_nbytes(plan: DecodePlan, *, page_size: int,
                 dtype=jnp.float32) -> int:
     """HBM one page pins across every layer, k and v. An int8 page also
     carries its fp32 scale rows (k and v, per head per position)."""
-    n = (2 * plan.num_layers * plan.num_heads * page_size * plan.key_dim
+    n = (2 * plan.num_layers * page_size * plan.kv_width
          + plan.latent_layers * page_size * plan.latent_width)
     dt = jnp.dtype(dtype)
     if dt == jnp.int8:
@@ -605,6 +655,13 @@ def state_nbytes_per_slot(plan: DecodePlan) -> int:
     s = plan.state_heads * plan.state_dim * plan.state_dim
     tail = max(plan.conv_taps - 1, 0) * plan.conv_width
     return plan.state_layers * (s + tail) * 4
+
+
+def window_nbytes_per_slot(plan: DecodePlan, dtype=jnp.float32) -> int:
+    """HBM one slot's window layers pin beside its pages: a ring of
+    ``window`` K and V rows a layer, whatever the request's length."""
+    return (2 * plan.window_layers * plan.window * plan.kv_width
+            * jnp.dtype(dtype).itemsize)
 
 
 def page_pool_nbytes(plan: DecodePlan, *, num_pages: int, page_size: int,
@@ -632,7 +689,11 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
     with state layers gets, for ``slots`` slots, ``state``
     ``[state_layers, slots, heads, dim, dim]`` and ``conv``
     ``[state_layers, slots, taps - 1, conv_width]``, float32 and indexed
-    by SLOT, not by page.
+    by SLOT, not by page. A plan with grouped-query layers gets ``k``/``v``
+    of ``[num_layers, num_pages + 1, page_size, kv_heads * key_dim]`` for
+    its full layers and, for its window layers, the rings ``wk``/``wv``
+    ``[window_layers, slots, window, kv_heads * key_dim]``, indexed by slot:
+    position ``p`` of a slot's sequence lies at ring row ``p % window``.
 
     Like :func:`init_cache`, ``budget_bytes`` raises a loud sizing error
     (how many pages DO fit) instead of deferring to an XLA OOM.
@@ -654,6 +715,8 @@ def init_page_pool(plan: DecodePlan, *, num_pages: int, page_size: int,
                 "page(s). Lower num_pages/page_size or raise the budget.")
     if plan.latent_layers or plan.state_layers:
         return _init_hybrid_pool(plan, num_pages, page_size, dtype, slots)
+    if plan.kv_heads:
+        return _init_grouped_pool(plan, num_pages, page_size, dtype, slots)
     shape = (plan.num_layers, num_pages + 1, page_size,
              plan.num_heads * plan.key_dim)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -695,6 +758,28 @@ def _init_hybrid_pool(plan: DecodePlan, num_pages: int, page_size: int,
         pool["conv"] = jnp.zeros(
             (plan.state_layers, slots, plan.conv_taps - 1, plan.conv_width),
             jnp.float32)
+    return pool
+
+
+def _init_grouped_pool(plan: DecodePlan, num_pages: int, page_size: int,
+                       dtype, slots: int) -> dict:
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError(
+            "serve: int8 pages carry a scale row a head, laid out for equal "
+            "query and K/V heads; grouped-query pages and window rings have "
+            "none — use kv_dtype 'bf16' or 'fp32'")
+    if not plan.num_layers:
+        raise TypeError(
+            "serve: a model whose every attention layer has a window has "
+            "nothing to page; the paged engine needs a full layer")
+    shape = (plan.num_layers, num_pages + 1, page_size, plan.kv_width)
+    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if plan.window_layers:
+        if slots < 1:
+            raise ValueError("serve: a window's ring is held by slot — "
+                             "pass slots")
+        ring = (plan.window_layers, slots, plan.window, plan.kv_width)
+        pool["wk"], pool["wv"] = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
     return pool
 
 
@@ -744,10 +829,11 @@ def _gather_kv(pool: dict, name: str, layer_idx: int, page_rows,
 
 
 def _write_rows(pool: dict, layer_idx: int, pages, offsets, k, v):
-    """Write one position's K/V per row of ``k``/``v`` (``[n, H, dk]``)
-    at ``(pages[i], offsets[i])`` of layer ``layer_idx``, quantizing for
-    an int8 pool. Returns the per-row max-abs dequantization error
-    (``[n]`` fp32) for an int8 pool, else None."""
+    """Write one position's K/V per row of ``k``/``v`` (``[n, H, dk]``;
+    for a float pool also the heads side by side, ``[n, H * dk]``) at
+    ``(pages[i], offsets[i])`` of layer ``layer_idx``, quantizing for an
+    int8 pool. Returns the per-row max-abs dequantization error (``[n]``
+    fp32) for an int8 pool, else None."""
     n = k.shape[0]
     err = None
     for name, new in (("k", k), ("v", v)):
@@ -858,6 +944,157 @@ def _state_decode(op, params, pool, x, active):
         return layer.output(p, x, o[:, None])
 
 
+# -- grouped-query layers: K/V pages by length, window rings by slot ------------
+#
+# A grouped-query layer without a window keeps its K/V heads' rows in pages
+# under the one table ("gqa"): a decode step walks a slot's pages through
+# the kernel (query head h in the columns of K/V head h // group) or
+# gathers them in XLA off the TPU; a prefill chunk walks them in KEY BLOCKS
+# up to its own last position with a running softmax, so it neither builds
+# a score over the table row nor reads the row behind its length. A layer
+# with a window ("window") keeps a RING of its last ``window`` keys a slot,
+# position p at ring row p % window: what slid out of the window is
+# overwritten, so a slot's window layers hold ``window`` rows whatever the
+# request's length. A decode step writes one row and attends the ring; a
+# chunk attends the ring's rows and its own under the band mask and leaves
+# the newest ``window`` positions behind. A ring row's position is known
+# from the slot's length alone; a request's first chunk (``start == 0``)
+# finds every row empty, whatever the slot's last holder left there. The
+# mathematics is the layer's own (``GroupedQueryAttention``).
+
+#: Keys a block of a prefill chunk's walk over a full layer's pages holds
+#: at most: a [heads, chunk, block] float32 score, never the table row's.
+PREFILL_KEY_BLOCK = 512
+
+
+def prefill_key_block(max_pages: int, page_size: int) -> int:
+    """Keys of one block of the prefill walk: whole pages, at most
+    ``PREFILL_KEY_BLOCK`` keys and never more than the table row."""
+    return max(1, min(max_pages, PREFILL_KEY_BLOCK // page_size)) * page_size
+
+
+def prefill_keys_visited(max_pages: int, page_size: int, length) -> int:
+    """Key positions a full layer's chunk that ends at ``length`` walks:
+    whole blocks up to its last position (the table row addresses
+    ``max_pages * page_size``)."""
+    block = prefill_key_block(max_pages, page_size)
+    return -(-length // block) * block
+
+
+def _ring_positions(upto, window: int):
+    """The position each ring row holds once positions ``0 .. upto`` are
+    written: the newest one congruent to the row, negative for a row not
+    reached yet. ``upto`` [..] -> [.., window]."""
+    upto = jnp.asarray(upto)[..., None]
+    return upto - (upto - jnp.arange(window)) % window
+
+
+def _gqa_prefill(op, params, pool, page_row, x, pos, valid_q, length):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    num_pages, ps = pool["k"].shape[1] - 1, pool["k"].shape[2]
+    max_pages = page_row.shape[0]
+    block = prefill_key_block(max_pages, ps)
+    ppb = block // ps
+    with jax.named_scope("tpu_dist.gqa.full"):
+        q, k, v = layer.project(p, x, pos)
+        pg = jnp.where(valid_q,
+                       page_row[jnp.minimum(pos // ps, max_pages - 1)],
+                       num_pages)
+        _write_rows(pool, idx, pg, pos % ps, k[0], v[0])
+        # Whole blocks of pages; what lies behind the row is the scratch
+        # page, behind every query's own position like all that it holds.
+        row = jnp.pad(page_row, (0, -max_pages % ppb),
+                      constant_values=num_pages)
+
+        def fetch(i):
+            pages = jax.lax.dynamic_slice_in_dim(row, i * ppb, ppb)
+            return (pool["k"][idx, pages].reshape(block, -1),
+                    pool["v"][idx, pages].reshape(block, -1))
+
+        o = layer.attend_blocks(q[0], pos, fetch, -(-length // block), block)
+        return layer.output(p, x, o[None])
+
+
+def _gqa_decode(op, params, pool, tables, x, pos, active, n_keys, walk):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    num_pages, ps = pool["k"].shape[1] - 1, pool["k"].shape[2]
+    b, max_pages = tables.shape
+    with jax.named_scope("tpu_dist.gqa.full"):
+        q, k, v = layer.project(p, x, pos[:, None])      # q [b, H, 1, dk]
+        pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
+        if active is not None:
+            pg = jnp.where(active, pg, num_pages)
+        _write_rows(pool, idx, pg, pos % ps, k[:, 0], v[:, 0])
+        if walk:
+            o = _walked_attention({"k": pool["k"], "v": pool["v"]},
+                                  jnp.int32(idx), tables,
+                                  q.astype(pool["k"].dtype), n_keys)
+        else:
+            rows = lambda name: pool[name][idx][tables].reshape(
+                b, max_pages * ps, -1)
+            valid = jnp.arange(max_pages * ps)[None, :] < n_keys[:, None]
+            o = layer.attend(q, rows("k"), rows("v"), valid[:, None, :])
+        return layer.output(p, x, o)
+
+
+def _window_prefill(op, params, pool, slot, x, pos, start, length):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    window, pad = pool["wk"].shape[2], pos.shape[0]
+    with jax.named_scope("tpu_dist.gqa.window"):
+        q, k, v = layer.project(p, x, pos)
+        ring = {n: pool[n][idx, slot] for n in ("wk", "wv")}
+        new = {"wk": k[0].astype(ring["wk"].dtype),
+               "wv": v[0].astype(ring["wv"].dtype)}
+        # The ring as earlier chunks left it, then the chunk's own rows: a
+        # padded row keeps its position, which no valid query reaches.
+        k_pos = jnp.concatenate([_ring_positions(start - 1, window), pos])
+        o = layer.attend(q[0], jnp.concatenate([ring["wk"], new["wk"]]),
+                         jnp.concatenate([ring["wv"], new["wv"]]),
+                         layer.sees(pos, k_pos))
+        # What the chunk leaves behind: each ring row's newest position up
+        # to the chunk's last valid one, from the chunk where it is there.
+        held = _ring_positions(length - 1, window)
+        src = jnp.clip(held - start, 0, pad - 1)
+        for n in ("wk", "wv"):
+            pool[n] = pool[n].at[idx, slot].set(
+                jnp.where((held >= start)[:, None], new[n][src], ring[n]))
+        return layer.output(p, x, o[None])
+
+
+def _window_decode(op, params, pool, x, pos, active):
+    _, layer, path, idx = op
+    p = _params_at(params, path)
+    window, b = pool["wk"].shape[2], x.shape[0]
+    with jax.named_scope("tpu_dist.gqa.window"):
+        q, k, v = layer.project(p, x, pos[:, None])
+        # A slot that is not decoding (empty, or mid-prefill with a real
+        # ring) writes nowhere: a row behind the ring is dropped.
+        at = pos % window
+        if active is not None:
+            at = jnp.where(active, at, window)
+        for name, new in (("wk", k), ("wv", v)):
+            pool[name] = pool[name].at[idx, jnp.arange(b), at].set(
+                new[:, 0].astype(pool[name].dtype), mode="drop")
+        held = _ring_positions(pos, window)                  # [b, window]
+        o = layer.attend(q, pool["wk"][idx, :b], pool["wv"][idx, :b],
+                         layer.sees(pos[:, None], held))
+        return layer.output(p, x, o)
+
+
+def _keys_attended(plan: DecodePlan, pos, active):
+    """int32 ``[all layers, the window layers' part]``: key positions
+    the grouped-query layers of one decode step attend for its live
+    slots."""
+    live = jnp.ones_like(pos, bool) if active is None else active
+    full = jnp.sum(jnp.where(live, pos + 1, 0))
+    ring = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, plan.window), 0))
+    ring = plan.window_layers * ring
+    return jnp.stack([plan.num_layers * full + ring, ring]).astype(jnp.int32)
+
+
 def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
                   length, start, slot=None):
     """Causal forward over the UNCACHED suffix of one prompt, writing
@@ -880,10 +1117,11 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
         ``start .. length - 1``, padded past ``length - start``.
       length: scalar int32 total valid positions (prefix + suffix).
       start: scalar int32 cached-prefix length (``< length``).
-      slot: scalar int32, the slot whose recurrent state this chunk
-        carries (plans with state layers only). ``start == 0`` is a
-        request's first chunk: its state starts from zero whatever the
-        slot's last holder left there, and nothing of it is read.
+      slot: scalar int32, the slot whose recurrent state or window rings
+        this chunk carries (plans with state or window layers only).
+        ``start == 0`` is a request's first chunk: its state starts from
+        zero and its rings empty whatever the slot's last holder left
+        there, and nothing of it is read.
 
     Returns:
       ``(pool, last_logits)`` for float pools; int8 pools return
@@ -914,6 +1152,11 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
             x = _latent_prefill(op, params, pool, page_row, x, pos, valid_q)
         elif tag == "state":
             x = _state_prefill(op, params, pool, slot, x, start, valid_q)
+        elif tag == "gqa":
+            x = _gqa_prefill(op, params, pool, page_row, x, pos, valid_q,
+                             length)
+        elif tag == "window":
+            x = _window_prefill(op, params, pool, slot, x, pos, start, length)
         elif tag == "moe":
             _, layer, path = op
             with jax.named_scope("tpu_dist.moe"):
@@ -1057,6 +1300,11 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
             x = _latent_decode(op, params, pool, tables, x, pos, active)
         elif tag == "state":
             x = _state_decode(op, params, pool, x, active)
+        elif tag == "gqa":
+            x = _gqa_decode(op, params, pool, tables, x, pos, active, n_keys,
+                            walk)
+        elif tag == "window":
+            x = _window_decode(op, params, pool, x, pos, active)
         elif tag == "moe":
             _, layer, path = op
             with jax.named_scope("tpu_dist.moe"):
@@ -1082,12 +1330,20 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
             _, layer, path = op
             x, _ = layer.apply(_params_at(params, path), {}, x)
     logits = x[:, 0, :].astype(jnp.float32)      # [b, vocab]
+    counts = None
     if moe_stats:
         # Summed over the expert layers; the fullest expert is a maximum.
         stacked = jnp.stack(moe_stats)
         sums = jnp.sum(stacked, axis=0)
-        return pool, logits, jnp.concatenate(
+        counts = jnp.concatenate(
             [sums[:3], jnp.max(stacked[:, 3:4], axis=0), sums[4:]])
+    if plan.kv_heads:
+        # Behind the experts' six counts (noughts where there are none).
+        counts = jnp.concatenate([
+            jnp.zeros((6,), jnp.int32) if counts is None else counts,
+            _keys_attended(plan, pos, active)])
+    if counts is not None:
+        return pool, logits, counts
     return pool, logits
 
 
@@ -1165,11 +1421,14 @@ def copy_page(pool: dict, src, dst):
 
 def swap_state(pool: dict, i, j):
     """Exchange slots ``i`` and ``j`` of the entries held by slot (the
-    recurrent state and its convolution tail): the device half of a
-    compaction move under paging, whose pages move by a host pointer swap.
-    Traced scalars: one compiled program serves every swap."""
+    recurrent state and its convolution tail, the window layers' rings):
+    the device half of a compaction move under paging, whose pages move by
+    a host pointer swap. Traced scalars: one compiled program serves every
+    swap."""
     out = dict(pool)
-    for name in ("state", "conv"):
+    for name in pool:
+        if name in _PAGED_ENTRIES:
+            continue
         a = pool[name]
         ri, rj = jnp.take(a, i, axis=1), jnp.take(a, j, axis=1)
         out[name] = a.at[:, i].set(rj).at[:, j].set(ri)

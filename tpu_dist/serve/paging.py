@@ -29,7 +29,9 @@ owns everything about which page holds what:
   admission headroom checks, prefix lookup + page-table construction at
   prefill, tail-page writability for decode appends, registration +
   release at finish, and the pointer-swap that replaces the contiguous
-  engine's ``swap_slots`` device program.
+  engine's ``swap_slots`` device program. It also keeps the books of the
+  cache kinds held by slot (a recurrent state, the window layers' rings):
+  which slots hold one, and their bytes in the gauges.
 
 The invariant everything hangs on: **a page is writable by a slot iff
 its refcount is exactly 1** (the slot's own reference). The prefix cache
@@ -465,25 +467,29 @@ class PagedKVState:
     def __init__(self, *, num_pages: int, page_size: int, slots: int,
                  max_pages: int, bytes_per_token: int,
                  prefix_caching: bool = True,
-                 state_bytes_per_slot: int = 0) -> None:
-        if state_bytes_per_slot and prefix_caching:
+                 state_bytes_per_slot: int = 0,
+                 window_bytes_per_slot: int = 0) -> None:
+        if (state_bytes_per_slot or window_bytes_per_slot) and prefix_caching:
             raise ValueError(
-                "serve: a slot with recurrent state cannot be handed shared "
-                "pages — its state was not built over them; pass "
-                "prefix_caching=False")
+                "serve: a slot with recurrent state or window rings cannot "
+                "be handed shared pages — they were not built over them; "
+                "pass prefix_caching=False")
         self.allocator = PageAllocator(
             num_pages=num_pages, page_size=page_size, slots=slots,
             max_pages=max_pages)
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.allocator) if prefix_caching else None)
         self._bytes_per_token = bytes_per_token
-        #: The second kind of cache: a recurrent state held BY SLOT on the
-        #: device (0 bytes: the model has none). A slot holds one from
-        #: :meth:`begin` to :meth:`finish`; it moves with the slot's pages
-        #: in :meth:`swap_slots` (the engine moves the device rows), and a
-        #: request's first prefill chunk starts it from zero, so what a
-        #: finished request left is never read.
+        #: The kinds of cache held BY SLOT on the device: a recurrent state,
+        #: and the window layers' rings of their last keys (0 bytes: the
+        #: model has none). A slot holds them from :meth:`begin` to
+        #: :meth:`finish`; they move with the slot's pages in
+        #: :meth:`swap_slots` (the engine moves the device rows), and a
+        #: request's first prefill chunk starts them empty, so what a
+        #: finished request left is never read. A ring's bytes do not grow
+        #: with the request: ``window`` keys a layer, whatever its length.
         self._state_bytes_per_slot = int(state_bytes_per_slot)
+        self._window_bytes_per_slot = int(window_bytes_per_slot)
         self.state_live = np.zeros(slots, bool)
 
     # -- admission ------------------------------------------------------------
@@ -536,7 +542,7 @@ class PagedKVState:
         ps = alloc.page_size
         need = self.pages_needed(total_tokens)
         alloc.bind_reservation(slot, need)
-        if self._state_bytes_per_slot:
+        if self._state_bytes_per_slot or self._window_bytes_per_slot:
             if self.state_live[slot]:
                 raise AssertionError(
                     f"slot {slot} still holds a live recurrent state")
@@ -654,9 +660,14 @@ class PagedKVState:
             per_page = self.allocator.page_size * self._bytes_per_token
             metrics.set_gauge("serve.pages.bytes_per_slot",
                               held * per_page / occupied)
+        live = int(self.state_live.sum())
         if self._state_bytes_per_slot:
-            live = int(self.state_live.sum())
             metrics.set_gauge("serve.prefix.disabled_recurrent", 1.0)
             metrics.set_gauge("serve.state.slots_live", float(live))
             metrics.set_gauge("serve.state.bytes",
                               float(live * self._state_bytes_per_slot))
+        if self._window_bytes_per_slot:
+            metrics.set_gauge("serve.prefix.disabled_recurrent", 1.0)
+            metrics.set_gauge("serve.cache.window_slots_live", float(live))
+            metrics.set_gauge("serve.cache.window_bytes",
+                              float(live * self._window_bytes_per_slot))
