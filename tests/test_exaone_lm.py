@@ -523,6 +523,7 @@ def test_a_window_slots_bytes_do_not_grow_with_length(family, cfg):
                 counters["serve.prefill.keys_addressed"])
             assert counters["serve.moe.assignments"] > 0
             assert "serve.prefill.scan_chunks" not in counters
+            assert "serve.state.slots_visited" not in counters
             assert gauges["serve.prefix.disabled_recurrent"] == 1.0
         assert seen[20][:2] == seen[110][:2] == (
             2 * 2 * 6 * 16 * 32 * 4, 2.0)          # float32 rings, 2 slots

@@ -142,6 +142,157 @@ def test_padded_positions_leave_the_state_alone():
     assert np.abs(np.asarray(s_pad - s_cut)).max() < DELTA_TOL
 
 
+# -- the decode step's state update, by blocks of slots -----------------------
+
+
+def _whole_batch_state_decode(op, params, pool, x, active):
+    """``kv_cache._state_decode`` as it stood before it went by slot
+    blocks (PR 33): every row of the batch read, stepped, selected back
+    and written. The blocked form's reference."""
+    _, layer, path, idx = op
+    p = kv_cache._params_at(params, path)
+    b = x.shape[0]
+    qkv, beta, g = layer.project(p, x)
+    tail, old = pool["conv"][idx, :b], pool["state"][idx, :b]
+    qkv, window = hybrid.causal_conv(qkv, tail, p["conv"])
+    q, k, v = layer.heads(qkv)
+    o, s = hybrid.delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0], old)
+    tail_new = window[:, 1:]
+    if active is not None:
+        s = jnp.where(active[:, None, None, None], s, old)
+        tail_new = jnp.where(active[:, None, None], tail_new, tail)
+    pool["state"] = pool["state"].at[idx, :b].set(s)
+    pool["conv"] = pool["conv"].at[idx, :b].set(tail_new)
+    return layer.output(p, x, o[:, None])
+
+
+def _state_case(b, slots, seed=0):
+    """One delta layer (the second of two in the pool), a pool of
+    ``slots`` slots full of states and tails, ``b`` rows to decode."""
+    layer = hybrid.DeltaAttention(num_heads=2, head_dim=16)
+    params, _, _ = layer.init(jax.random.PRNGKey(seed), (b, 1, 24))
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    pool = {"state": jax.random.normal(ks[0], (2, slots, 2, 16, 16)),
+            "conv": jax.random.normal(ks[1], (2, slots, 3, 96))}
+    x = jax.random.normal(ks[2], (b, 1, 24))
+    return ("state", layer, ("kda",), 1), {"kda": params}, pool, x
+
+
+def _run_state_decode(fn, op, params, pool, x, active):
+    def program(params, pool, x, active):
+        pool = dict(pool)
+        out = fn(op, params, pool, x, active)
+        return out, pool
+
+    return jax.jit(program)(params, pool, x, active)
+
+
+#: Decoding slots of a batch of 24 (three blocks of 8) by where the
+#: highest one stands, and the slots the step then visits.
+ACTIVE_CASES = {
+    "highest-0": ([0], 8),
+    "highest-7-holes-below": ([2, 7], 8),
+    "highest-8-holes-below": ([0, 3, 8], 16),
+    "highest-last-holes-below": ([1, 9, 23], 24),
+    "none-decodes": ([], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(ACTIVE_CASES))
+def test_the_state_update_by_slot_blocks_is_the_whole_batch_one(case):
+    """Every ACTIVE slot's state, convolution tail and output equal the
+    whole-batch form's bit for bit; every other slot keeps its state and
+    tail; behind the last visited block nothing is computed at all (a zero
+    output) and the other layer's pool rows are not touched."""
+    b = 24
+    live, visited = ACTIVE_CASES[case]
+    active = np.zeros(b, bool)
+    active[live] = True
+    op, params, pool, x = _state_case(b, b)
+    want, want_pool = _run_state_decode(_whole_batch_state_decode, op,
+                                        params, pool, x, active)
+    got, got_pool = _run_state_decode(kv_cache._state_decode, op, params,
+                                      pool, x, active)
+    assert kv_cache.state_slots_visited(max(live, default=-1) + 1,
+                                        b) == visited
+    for name in ("state", "conv"):
+        before, after = np.asarray(pool[name]), np.asarray(got_pool[name])
+        assert np.array_equal(after[1, live],
+                              np.asarray(want_pool[name])[1, live])
+        assert np.array_equal(after[1, ~active], before[1, ~active])
+        assert np.array_equal(after[0], before[0])
+        assert not live or not np.array_equal(after[1, live],
+                                              before[1, live])
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(got[live], want[live])
+    assert np.all(got[visited:] == 0.0)
+    assert not live or np.abs(got[live]).max() > 0.0
+
+
+@pytest.mark.parametrize("b, slots", [(4, 8), (12, 12), (16, 32)],
+                         ids=["bucket-4-of-8", "12-in-blocks-of-4",
+                              "bucket-16-of-32"])
+def test_without_a_mask_the_state_update_takes_every_row(b, slots):
+    """The bucketed program hands no mask: all ``b`` rows are stepped as
+    before, in blocks that divide ``b``, and the slots behind the bucket
+    keep what they hold."""
+    op, params, pool, x = _state_case(b, slots, seed=3)
+    want, want_pool = _run_state_decode(_whole_batch_state_decode, op,
+                                        params, pool, x, None)
+    got, got_pool = _run_state_decode(kv_cache._state_decode, op, params,
+                                      pool, x, None)
+    assert kv_cache.state_slots_visited(b, b) == b
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    for name in ("state", "conv"):
+        assert np.array_equal(got_pool[name], want_pool[name])
+        assert not np.array_equal(got_pool[name][1, :b], pool[name][1, :b])
+        assert np.array_equal(got_pool[name][1, b:], pool[name][1, b:])
+
+
+def test_a_slot_mid_prefill_keeps_its_state_through_a_decode_step(
+        family, cfg, monkeypatch):
+    """Slots 0 and 2 decode while slot 1 stands between two chunks of its
+    prompt, inside the blocks the step visits (blocks of 2 here: slot 1
+    shares its block with a decoding slot): its state, tail and latent
+    rows are what its first chunk left, and its second chunk's logits are
+    those of a prompt no decode step came between."""
+    monkeypatch.setattr(kv_cache, "STATE_SLOT_BLOCK", 2)
+    model = family.build_program(cfg, 11)
+    plan = kv_cache.build_plan(model)
+    params = model.init(0)["params"]
+    pool = kv_cache.init_page_pool(plan, num_pages=24, page_size=16,
+                                   dtype=jnp.float32, slots=4)
+    tables = np.arange(24, dtype=np.int32).reshape(4, 6)
+    prefill = jax.jit(functools.partial(kv_cache.paged_prefill, plan))
+    decode = jax.jit(functools.partial(kv_cache.paged_decode_ragged, plan,
+                                       walk=False))
+    rng = np.random.default_rng(4)
+    prompt = lambda n: np.pad(rng.integers(0, 512, size=n), (0, 32 - n))
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    tokens = np.zeros(4, np.int32)
+    for slot in (0, 2):
+        pool, logits = prefill(params, pool, i32(tables[slot]),
+                               i32(prompt(20)), i32(20), i32(0), i32(slot))
+        tokens[slot] = int(np.argmax(logits))
+    long = rng.integers(0, 512, size=50)
+    pool, _ = prefill(params, pool, i32(tables[1]), i32(long[:32]), i32(32),
+                      i32(0), i32(1))
+    second = (i32(tables[1]), i32(np.pad(long[32:], (0, 14))), i32(50),
+              i32(32), i32(1))
+    _, undisturbed = prefill(params, pool, *second)
+    stepped, *_ = decode(params, pool, i32(tables), i32(tokens),
+                         i32([20, 32, 20, 0]),
+                         jnp.asarray([True, False, True, False]))
+    for name in ("state", "conv"):
+        assert np.array_equal(stepped[name][:, 1], pool[name][:, 1])
+        assert np.array_equal(stepped[name][:, 3], pool[name][:, 3])
+        assert not np.array_equal(stepped[name][:, 0], pool[name][:, 0])
+        assert not np.array_equal(stepped[name][:, 2], pool[name][:, 2])
+    _, after = prefill(params, stepped, *second)
+    assert np.array_equal(np.asarray(after), np.asarray(undisturbed))
+
+
 # -- latent attention ---------------------------------------------------------
 
 
@@ -462,11 +613,16 @@ def _record_logits(engine):
 ENGINE_TOL = 2e-5
 
 
+@pytest.mark.parametrize("slot_block", [8, 2],
+                         ids=["one-slot-block", "two-slot-blocks"])
 def test_engine_prefill_by_chunks_then_decode_matches_the_full_forward(
-        family, cfg):
+        family, cfg, monkeypatch, slot_block):
     """Mixed lengths over 4 slots, more requests than slots, so slots are
     swapped on retirement and reused: a state that leaked from one request
-    to the next, or stayed behind in a swap, would show in the logits."""
+    to the next, or stayed behind in a swap, would show in the logits.
+    With slot blocks of 2 the decode step's state update stops after the
+    first block whenever slots 2 and 3 do not decode."""
+    monkeypatch.setattr(kv_cache, "STATE_SLOT_BLOCK", slot_block)
     engine = _engine(family, cfg)
     rows = _record_logits(engine)
     swaps = []
@@ -524,6 +680,64 @@ def test_prefix_caching_asked_for_is_served_without_reuse(family, cfg):
     assert first.generated == again.generated
     assert np.array_equal(np.stack(rows[first.rid]),
                           np.stack(rows[again.rid]))
+
+
+def test_the_engine_counts_the_state_slots_its_decode_steps_visit(
+        family, cfg, monkeypatch):
+    """Blocks of 2 over 4 slots. One request alone decodes in slot 0: a
+    block a step. Then four at once: every step's count follows from the
+    mask the decode program was handed. A plan without recurrent layers
+    counts neither."""
+    from tpu_dist.models.transformer import build_transformer_lm
+
+    monkeypatch.setattr(kv_cache, "STATE_SLOT_BLOCK", 2)
+    assert [kv_cache.state_slots_visited(hi, 4) for hi in range(5)] == [
+        0, 2, 2, 4, 4]
+    assert [kv_cache.state_slots_visited(hi, 64) for hi in (1, 14, 64)] == [
+        2, 14, 64]
+    counters = lambda: metrics.get_registry().snapshot()["counters"]
+    rng = np.random.default_rng(2)
+    prompt = lambda n: rng.integers(0, 512, size=n).tolist()
+    metrics.enable()
+    try:
+        metrics.get_registry().reset()
+        engine = _engine(family, cfg)
+        engine.submit(prompt(20), max_new_tokens=6)
+        engine.run_until_idle()
+        steps = counters()["serve.decode.steps"]
+        assert steps == 5       # the first token is the prefill's
+        assert counters()["serve.state.slots_visited"] == 2 * steps
+        assert counters()["serve.state.slots_addressed"] == 4 * steps
+
+        metrics.get_registry().reset()
+        masks = []
+        decode_fn = engine._paged_decode_fn
+        engine._paged_decode_fn = lambda bucket: (
+            lambda params, cache, *args: (
+                masks.append(np.asarray(args[-1])),
+                decode_fn(bucket)(params, cache, *args))[1])
+        for n, new in [(9, 12), (40, 3), (12, 7), (5, 9)]:
+            engine.submit(prompt(n), max_new_tokens=new)
+        engine.run_until_idle()
+        highest = [int(np.flatnonzero(m).max()) for m in masks]
+        assert {0, 1} & set(highest) and {2, 3} & set(highest)
+        assert counters()["serve.state.slots_visited"] == sum(
+            2 if h < 2 else 4 for h in highest)
+        assert counters()["serve.state.slots_addressed"] == 4 * len(masks)
+
+        metrics.get_registry().reset()
+        gpt2 = ServeEngine(
+            build_transformer_lm(512, 128, d_model=64, depth=2, num_heads=4,
+                                 ff_dim=256),
+            max_batch=4, max_len=128, paged=True, ragged=True,
+            kv_dtype="fp32", page_size=16, num_pages=40)
+        gpt2.submit(prompt(20), max_new_tokens=4)
+        gpt2.run_until_idle()
+        assert counters()["serve.decode.steps"] == 3
+        assert not [k for k in counters() if k.startswith("serve.state.")]
+    finally:
+        metrics.disable()
+        metrics.get_registry().reset()
 
 
 def test_the_engine_serves_the_weights_it_is_handed(family, cfg):
@@ -600,9 +814,10 @@ def test_a_hybrid_model_is_saved_and_loaded_layer_for_layer(family, cfg):
 #: a pool (taken at PR 27: the hybrid family's cache kinds and the one
 #: positions helper left them byte for byte). ``hybrid``: the hybrid plan
 #: at its rehearsal widths over a bfloat16 pool (prefill taken at PR 31's
-#: parent; decode at PR 32, which meant to change it: two more counts in
-#: the expert layers' ``stats``. Off the TPU the grouped product lowers
-#: as it did; the TPU's kernel is pinned in ``test_tpu_compile.py``).
+#: parent; decode renewed at PR 34, which meant to change it: the state
+#: update is a loop over slot blocks up to the highest decoding slot. Off
+#: the TPU the grouped product lowers as it did; the TPU's kernel is
+#: pinned in ``test_tpu_compile.py``).
 #: A program that lowers to the same text has the same key in the compile
 #: cache and loads the same executable. To renew after a deliberate
 #: change: run ``_digest`` on the commit before it.
@@ -611,7 +826,7 @@ PARENT_DIGESTS = {
     ("int8", "prefill"): "2b1131b1fd2308b7",
     ("bfloat16", "decode"): "b7e9c46cc4e32c1a",
     ("bfloat16", "prefill"): "23f7f9cff5af7765",
-    ("hybrid", "decode"): "9189c7c1d0971b6e",
+    ("hybrid", "decode"): "fff8a6b7e8b07b8c",
     ("hybrid", "prefill"): "e0af96c75ae1e56c",
 }
 

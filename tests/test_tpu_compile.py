@@ -186,7 +186,8 @@ def test_hybrid_decode_program_compiles_at_published_widths(
     chip's grouped matmul kernel (``ops/grouped_matmul.py``; the program
     is built as on a TPU: no chip is attached), the logits stay
     ``f32[slots, vocabulary]`` (how the trace readers find the decode
-    program), and the program fits beside its weights."""
+    program), the KDA layers' state is updated in place by blocks of
+    slots, and the program fits beside its weights."""
     import json
     import pathlib
 
@@ -239,6 +240,16 @@ def test_hybrid_decode_program_compiles_at_published_widths(
     # handed (all 512: "512,512,256") and makes every touched expert pay it.
     assert "ragged_dot_tiling" not in text
     assert f"f32[{slots},{cfg['vocab_size']}]" in text
+    # The state update: one loop a KDA layer over blocks of slots, its
+    # bound the block that holds the highest decoding slot, each block cut
+    # out of the donated pool and written back into it in place: the pool
+    # is never copied (1.34 GB here) and no layer's slice of it is either.
+    state = ",".join(map(str, pool["state"].shape))
+    assert len(re.findall(r"\) while\(", text)) == plan.state_layers == 5
+    assert not re.findall(r"= f32\[(?:%s|%s)\]\S* copy\(" % (
+        state, state.split(",", 1)[1]), text)
+    assert len(re.findall(r"dynamic-update-slice_fusion[.\d]* = f32\[%s\]"
+                          % state, text)) == plan.state_layers
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 

@@ -53,7 +53,10 @@ experts adds ``serve.moe.assignments``, ``.assignments_held``,
 ``.experts_touched``, ``.rows_multiplied``, ``.rows_sorted`` and the
 distribution ``serve.moe.load_max`` (device
 scalars of the decode steps that ride the logits' read-back); one with
-recurrent layers adds ``serve.prefill.scan_chunks``, the gauges
+recurrent layers adds ``serve.prefill.scan_chunks``,
+``serve.state.slots_visited`` over ``.slots_addressed`` (the slot blocks a
+decode step's state update reads and writes, up to its highest decoding
+slot, against the batch), the gauges
 ``serve.state.slots_live``, ``serve.state.bytes`` and
 ``serve.prefix.disabled_recurrent``, and a ``serve.step.state_swap`` span
 where compaction moves a slot's state on the device; one with
@@ -1288,6 +1291,15 @@ class ServeEngine:
                 for req in ready:
                     active[req.slot] = True
                 host.append(active)
+                if self.plan.state_layers and metrics.enabled():
+                    # Slots of a state layer this step reads and writes:
+                    # whole blocks up to its highest decoding slot.
+                    metrics.inc("serve.state.slots_visited",
+                                kv_cache.state_slots_visited(
+                                    max(req.slot for req in ready) + 1,
+                                    self.max_batch))
+                    metrics.inc("serve.state.slots_addressed",
+                                self.max_batch)
             args = self._upload(*host)
         t0 = self.clock()
         timer = None

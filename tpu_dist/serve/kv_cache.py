@@ -857,10 +857,12 @@ def _write_rows(pool: dict, layer_idx: int, pages, offsets, k, v):
 # A latent layer caches ONE row a token, shared by all heads, in pages the
 # same tables address; a state layer holds, for each slot, a float32 state
 # and the last inputs of its short convolution, read and written whole at
-# every step. Prefill runs the layers' sequence forms (keys and values
-# rebuilt from the latent rows; the chunked delta rule), decode their
-# one-token forms (W_kvb absorbed into the query and the output; one step
-# of the rule). The mathematics is the layers' own (models/hybrid.py).
+# every step of that slot: a decode step walks the slots in blocks from
+# slot 0 to the highest one that decodes. Prefill runs the layers'
+# sequence forms (keys and values rebuilt from the latent rows; the
+# chunked delta rule), decode their one-token forms (W_kvb absorbed into
+# the query and the output; one step of the rule). The mathematics is the
+# layers' own (models/hybrid.py).
 
 
 def _latent_prefill(op, params, pool, page_row, x, pos, valid_q):
@@ -922,25 +924,71 @@ def _state_prefill(op, params, pool, slot, x, start, valid_q):
         return layer.output(p, x, o[None])
 
 
+#: Slots of the state pool one visit of a decode step reads and writes:
+#: 16.8 MB of float32 state a layer at the hybrid family's 32 heads of
+#: 128 x 128, and a divisor of the batches the cells decode (32, 64).
+STATE_SLOT_BLOCK = 8
+
+
+def state_slot_block(batch: int) -> int:
+    """Slots of one block of the decode step's walk over a state layer:
+    ``STATE_SLOT_BLOCK``, or the largest divisor it shares with a batch it
+    does not divide."""
+    return math.gcd(batch, STATE_SLOT_BLOCK)
+
+
+def state_slots_visited(hi, batch: int):
+    """Slots of a state layer a decode step of ``batch`` rows reads and
+    writes when its highest decoding slot is ``hi - 1``: whole blocks from
+    slot 0 up, and nothing behind them. ``hi`` may be traced."""
+    block = state_slot_block(batch)
+    return -(-hi // block) * block
+
+
 def _state_decode(op, params, pool, x, active):
     _, layer, path, idx = op
     p = _params_at(params, path)
     b = x.shape[0]
+    block = state_slot_block(b)
     with jax.named_scope("tpu_dist.kda"):
         qkv, beta, g = layer.project(p, x)                 # [b, 1, *]
-        tail, old = pool["conv"][idx, :b], pool["state"][idx, :b]
-        qkv, window = causal_conv(qkv, tail, p["conv"])
-        q, k, v = layer.heads(qkv)                         # [b, 1, H, dk]
-        o, s = delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                               beta[:, 0], old)
-        tail_new = window[:, 1:]
-        if active is not None:
-            # A slot that is not decoding (empty, or mid-prefill with a
-            # real state) keeps what it holds.
-            s = jnp.where(active[:, None, None, None], s, old)
-            tail_new = jnp.where(active[:, None, None], tail_new, tail)
-        pool["state"] = pool["state"].at[idx, :b].set(s)
-        pool["conv"] = pool["conv"].at[idx, :b].set(tail_new)
+        # The engine keeps live slots at the front (``_retire`` moves the
+        # last one into a freed slot): nothing decodes behind ``hi``.
+        hi = b if active is None else jnp.max(
+            jnp.where(active, jnp.arange(1, b + 1), 0))
+
+        def one(i, carry):
+            state, conv, o = carry
+            at = i * block
+            rows = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block)
+            # Out of the whole pool, not out of a layer's copy of it.
+            old = jax.lax.dynamic_slice(
+                state, (idx, at, 0, 0, 0), (1, block) + state.shape[2:])[0]
+            tail = jax.lax.dynamic_slice(
+                conv, (idx, at, 0, 0), (1, block) + conv.shape[2:])[0]
+            y, window = causal_conv(rows(qkv), tail, p["conv"])
+            q, k, v = layer.heads(y)                       # [block, 1, H, dk]
+            o_new, s = delta_rule_step(q[:, 0], k[:, 0], v[:, 0],
+                                       rows(g)[:, 0], rows(beta)[:, 0], old)
+            tail_new = window[:, 1:]
+            if active is not None:
+                # A slot that is not decoding (empty, or mid-prefill with a
+                # real state) keeps what it holds.
+                live = rows(active)
+                s = jnp.where(live[:, None, None, None], s, old)
+                tail_new = jnp.where(live[:, None, None], tail_new, tail)
+            return (jax.lax.dynamic_update_slice(state, s[None],
+                                                 (idx, at, 0, 0, 0)),
+                    jax.lax.dynamic_update_slice(conv, tail_new[None],
+                                                 (idx, at, 0, 0)),
+                    jax.lax.dynamic_update_slice_in_dim(o, o_new, at, 0))
+
+        # A row behind the last visited block keeps a zero output: it has
+        # no expert and the host reads no token of it.
+        pool["state"], pool["conv"], o = jax.lax.fori_loop(
+            0, state_slots_visited(hi, b) // block, one,
+            (pool["state"], pool["conv"],
+             jnp.zeros((b, layer.num_heads, layer.head_dim), jnp.float32)))
         return layer.output(p, x, o[:, None])
 
 
