@@ -464,6 +464,55 @@ def test_engine_prefill_by_chunks_then_decode_matches_the_reference(
                            weights=silent) > 50 * ENGINE_TOL
 
 
+def test_the_pipelined_round_serves_what_the_round_by_round_order_serves(
+        family, cfg):
+    """The window plan pipelined (round n's decode dispatched before round
+    n - 1's picks are read; rings swapped on the device after it) against
+    the same engine read round by round: token for token, and every served
+    row against the reference, with the first request stopping on EOS
+    while its extra decode is in flight and later requests reusing its
+    slot and ring."""
+    reference = _engine(family, cfg)
+    reference._pipelined = False   # the round-by-round order
+    rng = np.random.default_rng(13)
+    shapes = [(40, 9), (9, 12), (70, 5), (33, 8), (12, 6), (5, 6)]
+    prompts = [rng.integers(0, 512, size=n).tolist() for n, _ in shapes]
+    stream = reference.generate(prompts[0], max_new_tokens=9)
+    eos = next(t for k, t in enumerate(stream)
+               if 2 <= k <= 5 and t not in stream[:k])
+    reg = metrics.get_registry()
+
+    def serve(engine):
+        swaps = []
+        swap_fn = engine._swap_state_fn
+        engine._swap_state_fn = lambda c, i, j: (
+            swaps.append(engine._picks is not None), swap_fn(c, i, j))[1]
+        reg.reset()
+        metrics.enable()
+        try:
+            reqs = [engine.submit(p, max_new_tokens=new,
+                                  eos_id=eos if k == 0 else None)
+                    for k, (p, (_, new)) in enumerate(zip(prompts, shapes))]
+            engine.run_until_idle()
+            return reqs, dict(reg.snapshot()["counters"]), swaps
+        finally:
+            metrics.disable()
+            reg.reset()
+
+    want, theirs, _ = serve(reference)
+    engine = _engine(family, cfg)
+    rows = _record_logits(engine)
+    got, ours, swaps = serve(engine)
+    assert engine._pipelined and any(swaps)
+    assert got[0].finish_reason == "eos"
+    assert [r.generated for r in got] == [r.generated for r in want]
+    assert ours["serve.decode.rows_discarded"] == 1
+    assert ours["serve.decode.overlapped"] == ours["serve.decode.steps"] - 1
+    assert ours["serve.decode.window_keys_read"] > 0
+    assert "serve.decode.overlapped" not in theirs
+    assert _served_against(family, cfg, got, rows) < ENGINE_TOL
+
+
 def test_control_a_bfloat16_router_fails_the_engines_tolerance(family, cfg):
     """The router states float32: the reference with the router's product
     rounded to bfloat16 chooses other experts for some tokens and is not

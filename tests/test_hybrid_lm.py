@@ -740,6 +740,84 @@ def test_the_engine_counts_the_state_slots_its_decode_steps_visit(
         metrics.get_registry().reset()
 
 
+def _round_by_round(engine):
+    """The reference order on the engine's own programs: every program's
+    picks read in the round that made them, a request retired in the round
+    its last token was read (a sampling engine's order)."""
+    engine._pipelined = False
+    return engine
+
+
+def _eos_case(family, cfg, reference, seed):
+    """Prompts over 4 slots with more requests than slots, the first of
+    which stops on EOS with budget left: a token its round-by-round stream
+    (on ``reference``) picks first at its third to sixth place."""
+    rng = np.random.default_rng(seed)
+    shapes = [(20, 9), (9, 12), (40, 5), (33, 8), (12, 6), (5, 6)]
+    prompts = [rng.integers(0, 512, size=n).tolist() for n, _ in shapes]
+    stream = reference.generate(prompts[0], max_new_tokens=9)
+    reference.finished.clear()
+    eos = next(t for k, t in enumerate(stream)
+               if 2 <= k <= 5 and t not in stream[:k])
+    return [(p, new, eos if k == 0 else None)
+            for k, (p, (_, new)) in enumerate(zip(prompts, shapes))]
+
+
+def _serve_counting(engine, case):
+    """Serve ``case``; the requests, the counters, and whether the state
+    was swapped on the device while a decode's picks were unread."""
+    swaps = []
+    swap_fn = engine._swap_state_fn
+    engine._swap_state_fn = lambda c, i, j: (
+        swaps.append(engine._picks is not None), swap_fn(c, i, j))[1]
+    reg = metrics.get_registry()
+    reg.reset()
+    metrics.enable()
+    try:
+        reqs = [engine.submit(p, max_new_tokens=new, eos_id=eos)
+                for p, new, eos in case]
+        engine.run_until_idle()
+        counters = dict(reg.snapshot()["counters"])
+    finally:
+        metrics.disable()
+        reg.reset()
+    return reqs, counters, swaps
+
+
+def test_the_pipelined_round_serves_what_the_round_by_round_order_serves(
+        family, cfg):
+    """The hybrid plan's state swaps run on the device after the decode in
+    flight. The request that reads EOS was decoded once more than needed:
+    that pick is dropped, and every request after it in its slot serves
+    the full forward's logits, so the state its extra decode left was not
+    carried over."""
+    reference = _round_by_round(_engine(family, cfg))
+    case = _eos_case(family, cfg, reference, seed=12)
+    want, theirs, _ = _serve_counting(reference, case)
+    engine = _engine(family, cfg)
+    rows = _record_logits(engine)
+    got, ours, swaps = _serve_counting(engine, case)
+    assert engine._pipelined and any(swaps)
+    assert got[0].finish_reason == want[0].finish_reason == "eos"
+    assert [r.generated for r in got] == [r.generated for r in want]
+    assert ours["serve.tokens.generated"] == theirs["serve.tokens.generated"]
+    assert ours["serve.decode.rows_discarded"] == 1
+    assert ours["serve.decode.overlapped"] == ours["serve.decode.steps"] - 1
+    assert "serve.decode.overlapped" not in theirs
+    assert not engine._paging.state_live.any()
+    params = family.make_params(family.seed_key(11), cfg)
+    forward = jax.jit(functools.partial(family.forward, cfg=cfg))
+    worst = 0.0
+    for r in got:
+        seq = r.prompt + r.generated
+        x = np.zeros((1, 128), np.int32)
+        x[0, :len(seq)] = seq
+        ref = np.asarray(forward(params, jnp.asarray(x))[0])
+        ref = ref[len(r.prompt) - 1:len(r.prompt) - 1 + len(r.generated)]
+        worst = max(worst, np.abs(np.stack(rows[r.rid]) - ref).max())
+    assert worst < ENGINE_TOL, worst
+
+
 def test_the_engine_serves_the_weights_it_is_handed(family, cfg):
     """bfloat16 matrices stay bfloat16 and are not copied: the tree the
     model's init made IS the engine's."""

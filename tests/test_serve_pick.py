@@ -55,6 +55,10 @@ PROGRAMS = {
                "paged_decode": [4], "paged_prefill": [8, 16, 32]},
 }
 
+#: The families whose greedy rounds are pipelined: the decode of round n is
+#: dispatched before round n - 1's picks are read.
+PIPELINED = {"paged-ragged-fp32", "paged-ragged-int8", "hybrid"}
+
 pytestmark = pytest.mark.parametrize("family", list(FAMILIES))
 
 
@@ -109,20 +113,21 @@ def _serve(engine, family):
 
 def _spy(engine, read):
     """One event a picked token, in order: the row ``_pick`` was handed
-    (``read(row)`` of it, taken inside the pick), the token it returned and
-    the request, slot and round it was for."""
+    (``read(row)`` of it, taken inside the pick), the token it returned,
+    the request, slot and round it was for, and the row's index in its
+    program's result (a decode's slot as the step was dispatched)."""
     events = []
     pick, record = engine._pick, engine.scheduler.record_token
 
     def spying(row):
-        spying.last = (read(row), pick(row))
+        spying.last = (read(row), pick(row), row.index)
         return spying.last[1]
 
     def recording(req, token, *, now):
-        seen, picked = spying.last
+        seen, picked, index = spying.last
         events.append({"row": seen, "token": picked, "rid": req.rid,
-                       "slot": req.slot, "round": engine._round,
-                       "first": not req.generated})
+                       "slot": req.slot, "index": index,
+                       "round": engine._round, "first": not req.generated})
         return record(req, token, now=now)
 
     engine._pick, engine.scheduler.record_token = spying, recording
@@ -208,16 +213,19 @@ def test_a_row_is_the_programs_and_a_step_crosses_once(family, recording):
     crowded = max({e["round"] for e in decode},
                   key=lambda rnd: sum(e["round"] == rnd for e in decode))
     a, b = [e for e in decode if e["round"] == crowded][:2]
-    program = np.asarray(outputs[crowded, 2])
+    # A pipelined round reads the picks of the decode the round before it
+    # dispatched; round by round a decode is read in its own round.
+    program = np.asarray(outputs[crowded - engine._pipelined, 2])
+    assert engine._pipelined == (family in PIPELINED)
     assert len(a["row"]) == len(b["row"]) == _vocab(family) == program.shape[1]
-    assert np.array_equal(np.asarray(a["row"]), program[a["slot"]])
+    assert np.array_equal(np.asarray(a["row"]), program[a["index"]])
     assert recording()["serve.logits.bytes"] == before + program.nbytes
-    assert np.array_equal(np.asarray(b["row"]), program[b["slot"]])
-    assert np.array_equal(np.asarray(a["row"]), program[a["slot"]])
+    assert np.array_equal(np.asarray(b["row"]), program[b["index"]])
+    assert np.array_equal(np.asarray(a["row"]), program[a["index"]])
     assert recording()["serve.logits.bytes"] == before + program.nbytes
     # A copy asked for is a copy: the step's rows stay what they were.
     np.array(a["row"])[:] = 0.0
-    assert np.array_equal(np.asarray(a["row"]), program[a["slot"]])
+    assert np.array_equal(np.asarray(a["row"]), program[a["index"]])
     # A first token's row is the prefill program's whole result (the last
     # prefill of its round: one round may admit several requests).
     first = [e for e in events if e["first"]][-1]

@@ -35,9 +35,16 @@ A round of :meth:`ServeEngine.step` is one ``serve.step`` span
 (``utils.profiler.span``, ``ident`` = the round's number) whose children
 stand at the phase boundaries: ``serve.step.admit``, one
 ``serve.step.prefill_chunk`` a chunk (with ``serve.step.first_token_wait``
-inside a last chunk), ``serve.step.decode_prep``, ``.decode_dispatch``,
-``.decode_wait``, ``.pick`` and ``.journal_flush`` (and, inside ``.pick``,
-one ``serve.step.logits_read`` in a step whose logits somebody read).
+inside a last chunk where the round reads its picks round by round),
+``serve.step.decode_prep``, ``.decode_dispatch``, ``.decode_wait``,
+``.pick`` and ``.journal_flush`` (and, inside ``.pick``, one
+``serve.step.logits_read`` in a step whose logits somebody read). A
+pipelined round (greedy, paged, ragged: :meth:`ServeEngine.step`)
+dispatches its decode before it reads: its ``.decode_wait`` is the read of
+the previous round's decode and this round's first tokens, and it counts
+``serve.decode.overlapped`` (decodes dispatched while the previous one's
+picks were unread) and ``serve.decode.rows_discarded`` (picks of a request
+that finished or was evicted while they were in flight).
 Counters at the same boundaries: ``serve.step.rounds``,
 ``serve.upload.bytes`` and ``serve.logits.bytes`` (what crosses to and
 from the device: the token ids a program picked, and a step's logits only
@@ -100,7 +107,7 @@ from tpu_dist.parallel import mesh as mesh_lib
 from tpu_dist.parallel.strategy import get_strategy
 from tpu_dist.serve import kv_cache, paging
 from tpu_dist.serve import journal as journal_lib
-from tpu_dist.serve.scheduler import DONE, SHED, Request, Scheduler
+from tpu_dist.serve.scheduler import ACTIVE, DONE, SHED, Request, Scheduler
 from tpu_dist.utils import profiler
 
 logger = logging.getLogger(__name__)
@@ -161,6 +168,30 @@ def _picking(body):
     return program
 
 
+@jax.jit
+def _next_inputs(tokens, order, slot, token):
+    """A pipelined decode's input tokens, made on the device where the
+    previous decode left its picks: ``tokens[order]`` (``order[i]`` is the
+    slot, as that decode was dispatched, whose pick slot ``i`` holds now),
+    with ``token`` put at ``slot`` (a slot of ``len(tokens)`` puts
+    nothing). One shape a capacity, traced once."""
+    return tokens[order].at[slot].set(token, mode="drop")
+
+
+class _Unread:
+    """Picks a program made that the host has not read: ``tokens`` and
+    ``logits`` on the device, ``rows`` the ``(request, index)`` each pick
+    is for (a decode's slot as it was dispatched, ``()`` for a prefill's
+    one token), and for a decode when it was dispatched and its device
+    counters (``stats``, read only while recording)."""
+
+    __slots__ = ("tokens", "logits", "rows", "dispatched_s", "stats")
+
+    def __init__(self, tokens, logits, rows, dispatched_s=None, stats=None):
+        self.tokens, self.logits, self.rows = tokens, logits, rows
+        self.dispatched_s, self.stats = dispatched_s, stats
+
+
 class _Picked:
     """One program execution's two results: the token ids it picked, on
     the host (the read the round waited for, 4 bytes a slot), and the
@@ -184,20 +215,21 @@ class _LogitsRow:
     """What :meth:`ServeEngine._pick` is handed: the token the program
     picked, ``len()`` the vocabulary, and the float32 row itself to
     whoever asks (``np.asarray(row)``), which is when the step's logits
-    cross. ``index`` is the slot's row of a decode step; a prefill's one
-    row is ``[()]`` of its ``[vocab]`` logits and of its scalar token."""
+    cross. ``index`` is the slot's row of a decode step (the slot as the
+    step was dispatched); a prefill's one row is ``[()]`` of its
+    ``[vocab]`` logits and of its scalar token."""
 
-    __slots__ = ("token", "_picked", "_index")
+    __slots__ = ("token", "index", "_picked")
 
     def __init__(self, picked: _Picked, index=()):
         self.token = int(picked.tokens[index])
-        self._picked, self._index = picked, index
+        self._picked, self.index = picked, index
 
     def __len__(self) -> int:
         return self._picked._logits.shape[-1]
 
     def __array__(self, dtype=None, copy=None):
-        row = self._picked.logits()[self._index]
+        row = self._picked.logits()[self.index]
         if dtype is not None and row.dtype != dtype:
             return row.astype(dtype)
         return row.copy() if copy else row
@@ -320,7 +352,11 @@ class ServeEngine:
         exactly ONE decode program and stream token-identically to
         bucketed ones (tests + serve-bench pin both). Default False:
         the bucketed family remains (it is the contiguous engine's only
-        mode and the bench's A/B control).
+        mode and the bench's A/B control). A greedy ragged engine also
+        runs its rounds PIPELINED (:meth:`step`): its batch layout never
+        changes shape and its input tokens are the previous decode's
+        picks, so round n's decode is dispatched before round n - 1's
+        picks are read.
     """
 
     @profiler.spanned("serve.engine.build")
@@ -508,6 +544,31 @@ class ServeEngine:
         self._pending_qerr: list = []
         #: Expert-routing counts of decode steps, likewise.
         self._pending_moe: list = []
+        #: The round order follows what the engine can see: where the
+        #: decode batch is the whole capacity and the pick is made on the
+        #: device, the next decode's input tokens exist on the device
+        #: before the host reads them, so a round dispatches its decode
+        #: first and reads the previous one's picks after. A sampling
+        #: engine needs its pick before the next input exists, and a
+        #: bucketed or contiguous engine's batch can change between
+        #: rounds: they read a step's picks in the round that made them.
+        self._pipelined = self.paged and self.ragged and self.temperature <= 0
+        #: Picks dispatched and not read yet, oldest first.
+        self._unread: list[_Unread] = []
+        #: Pipelined: this round's first tokens, still on the device, for
+        #: the decode's input; the last decode's picks; the compaction
+        #: swaps since it was dispatched (None: none).
+        self._firsts: list = []
+        self._picks = None
+        self._order: Optional[np.ndarray] = None
+        #: When the host last read a decode's picks.
+        self._read_s: Optional[float] = None
+        if self._pipelined:
+            # What the input feed takes where no decode or no first token
+            # is in flight, placed as a program's picks are.
+            self._no_picks, self._no_pick = self.strategy.replicate(
+                (np.zeros(self.max_batch, np.int32), np.zeros((), np.int32)))
+            self._same_order = jnp.arange(self.max_batch, dtype=jnp.int32)
 
         # CPU XLA has no buffer donation — donating there only logs
         # warnings; on TPU the cache updates in place (no per-step copy).
@@ -929,6 +990,11 @@ class ServeEngine:
                                        jnp.int32(j))
         self._tokens[[i, j]] = self._tokens[[j, i]]
         self._lengths[[i, j]] = self._lengths[[j, i]]
+        if self._picks is not None:
+            # The decode in flight picked in the layout it was handed.
+            if self._order is None:
+                self._order = np.arange(self.max_batch, dtype=np.int32)
+            self._order[[i, j]] = self._order[[j, i]]
 
     def _release_pages(self, req: Request) -> None:
         """Paged reclaim for a request that just left its slot: index its
@@ -994,18 +1060,22 @@ class ServeEngine:
         state layers: the slot whose state the chunk carries."""
         return (jnp.int32(req.slot),) if self.plan.recurrent else ()
 
-    def _to_host(self, array, span_name: str) -> np.ndarray:
+    def _to_host(self, array, span_name: str):
         """The one place the host waits for the device: ``np.asarray`` of
-        a program's result, under ``span_name``: the token ids it picked,
-        every step, or its logits, when somebody reads them. Prefill
-        errors parked by :meth:`_unpack_prefill` ride along into
-        ``serve.kv.quant_error`` (host-side, after the traced program:
-        SC103-clean)."""
+        a program's result (or of a list of them, in one read-back), under
+        ``span_name``: the token ids it picked, every step, or its logits,
+        when somebody reads them. Prefill errors parked by
+        :meth:`_unpack_prefill` ride along into ``serve.kv.quant_error``
+        (host-side, after the traced program: SC103-clean)."""
         with profiler.span(span_name, self._round) as wait:
-            array = np.asarray(array)  # blocks until the device is done
+            # Blocks until the device is done.
+            array = (jax.device_get(array) if isinstance(array, list)
+                     else np.asarray(array))
         self._waited_s += wait.seconds
         if metrics.enabled():
-            metrics.inc("serve.logits.bytes", array.nbytes)
+            metrics.inc("serve.logits.bytes",
+                        sum(a.nbytes for a in array)
+                        if isinstance(array, list) else array.nbytes)
             for qerr in jax.device_get(self._pending_qerr):
                 metrics.observe_value("serve.kv.quant_error", float(qerr))
             for (made, held, touched, fullest, walked, rows,
@@ -1024,11 +1094,16 @@ class ServeEngine:
         return array
 
     def _upload(self, *arrays) -> list:
-        """Host arrays to the device, counted in ``serve.upload.bytes``."""
+        """Host arrays to the device, counted in ``serve.upload.bytes``.
+        Copies: dispatch is asynchronous, nothing waits for a mid-prompt
+        chunk or a pipelined decode before the host moves on, and on the
+        CPU ``jnp.asarray`` aliases a 64-byte-aligned numpy buffer, while
+        the host resets, swaps and extends its table rows, lengths and
+        masks in place."""
         if metrics.enabled():
             metrics.inc("serve.upload.bytes",
                         sum(a.nbytes for a in arrays))
-        return [jnp.asarray(a) for a in arrays]
+        return [jnp.asarray(a.copy()) for a in arrays]
 
     def _prefill(self, req: Request) -> None:
         # A journal-recovered request re-prefills with prompt + everything
@@ -1075,23 +1150,20 @@ class ServeEngine:
             self._first_token(req, last, plen)
 
     def _first_token(self, req: Request, last: tuple, plen: int) -> None:
-        """The end of a prefill: read the token picked at the last
-        position back, stamp, emit the first generated token."""
-        # Materialize BEFORE stamping first-token time: jax dispatch is
-        # async, so the pre-readback clock() under-reported TTFT against
-        # any client-observed wall clock (the PR 12 wart).
+        """The end of a prefill: the token picked at the last position is
+        the first generated one. Round by round it is read back here,
+        stamped and emitted; a pipelined round leaves it on the device for
+        the decode's input and reads it with the decode's read-back."""
         logits, token = last
-        token = self._to_host(token, "serve.step.first_token_wait")
-        token = self._pick(_LogitsRow(_Picked(self, logits, token)))
-        now = self.clock()
-        done = self.scheduler.record_token(req, token, now=now)
-        metrics.inc("serve.tokens.generated")
-        if self.journal is not None:
-            self.journal.record_token(req.rid, token)
-        self._tokens[req.slot] = token
         self._lengths[req.slot] = plen
-        if done or plen >= self.max_len:
-            self._retire(req, now=now, status=DONE)
+        req.unread += 1
+        unread = _Unread(token, logits, [(req, ())])
+        if self._pipelined:
+            self._unread.append(unread)
+            self._firsts.append((req, token))
+            return
+        self._take([unread], [self._to_host(token,
+                                             "serve.step.first_token_wait")])
 
     def _begin_chunked_prefill(self, req: Request) -> None:
         """Admission under ``prefill_chunk > 0``: set up the slot (page
@@ -1136,12 +1208,8 @@ class ServeEngine:
             if self.paged:
                 self._paging.extend_prefill(req.slot, end)
                 fn = self._paged_prefill_fn(pad)
-                # The row as a copy: nothing waits for a mid-prompt chunk,
-                # on the CPU ``jnp.asarray`` aliases a 64-byte-aligned numpy
-                # buffer, and an eviction resets the allocator's own row in
-                # place.
                 row, toks = self._upload(
-                    self._paging.allocator.table[req.slot].copy(), tokens)
+                    self._paging.allocator.table[req.slot], tokens)
                 last = self._unpack_prefill(
                     fn(self.params, self.cache, row, toks, jnp.int32(end),
                        jnp.int32(startpos), *self._state_slot(req)))
@@ -1176,9 +1244,28 @@ class ServeEngine:
             self._first_token(req, last, plen)
 
     def step(self) -> int:
-        """One scheduling round: deadline evictions → admissions (each
-        pays its prefill and emits its first token) → one decode step for
-        the active bucket. Returns the number of still-active requests.
+        """One scheduling round: deadline evictions → admissions → at most
+        ``prefill_interleave`` prefill chunks → one decode step over the
+        slots still owed a token. Returns the number of still-active
+        requests.
+
+        Round by round, a step reads each program's picks in the round
+        that made it: a last chunk's first token before the decode, the
+        decode's picks right after it. A PIPELINED engine (greedy, paged,
+        ragged) dispatches round n's decode before it reads anything: its
+        input tokens are made on the device from round n - 1's decode and
+        this round's first tokens (:func:`_next_inputs`), its lengths
+        advance at dispatch, and a request whose last token is in flight
+        sits the round out. Then it reads round n - 1's picks and this
+        round's first tokens in one read-back, records them (stamped when
+        the host has them) and retires the finished requests, while the
+        device runs round n. Their requests stay ``active`` until then, so
+        :meth:`run_until_idle` drains the round in flight. A request that
+        finished by EOS, or was evicted, with a decode in flight was
+        decoded once more than needed: that pick is dropped and counted in
+        ``serve.decode.rows_discarded``; its K/V write lands in pages its
+        admission reserved, and the state or ring row it leaves is reset by
+        the next prefill of that slot.
 
         Durability contract: everything journaled this round (submits,
         tokens, finishes) is flushed — one append + fsync — at the END of
@@ -1192,8 +1279,8 @@ class ServeEngine:
         if metrics.enabled():
             metrics.inc("serve.step.rounds")
             # What the host did itself: the round less its waits for the
-            # device. The engine is synchronous, so the device idles for
-            # this long, except where a prefill chunk is still in flight.
+            # device. Round by round the device idles for most of it; a
+            # pipelined round does it while the device runs its decode.
             metrics.observe_value("serve.step.host_s",
                                   whole.seconds - self._waited_s)
         return active
@@ -1202,6 +1289,14 @@ class ServeEngine:
         if self.journal is not None:
             with profiler.span("serve.step.journal_flush", self._round):
                 self.journal.flush()
+
+    def _decoding(self) -> list[Request]:
+        """This round's decode rows: fully prefilled requests still owed a
+        token once the picks in flight are counted and whose slot has room
+        for one more position."""
+        return [r for r in self.scheduler.ready()
+                if len(r.generated) + r.unread < r.max_new_tokens
+                and self._lengths[r.slot] < self.max_len]
 
     def _round_body(self) -> int:
         rnd = self._round
@@ -1231,38 +1326,84 @@ class ServeEngine:
                     break
                 self._prefill_chunk_one(head)
 
-        n = self.scheduler.num_active
         if self.paged:
             self._paging.note_usage()
-        if n == 0:
+        decoding = self._decoding()
+        if not decoding and not self._unread:
+            self._picks = self._order = None
             self._flush_journal()
-            return 0
-        # Decode covers only fully-prefilled slots; a mid-chunk slot's
-        # cursor excludes it until its last chunk lands (ready() is all
-        # of active() when chunking is off).
-        ready = self.scheduler.ready()
-        if not ready:
-            self._flush_journal()
-            return n
+            return self.scheduler.num_active
+        if decoding:
+            fn, args, bucket = self._prepare_decode(decoding)
+        timer = None
+        if self.stall_timeout_s is not None:
+            info = {"timeout_s": self.stall_timeout_s,
+                    "bucket": bucket if decoding else None,
+                    "active": self.scheduler.num_active}
+            timer = threading.Timer(self.stall_timeout_s,
+                                    self.stall_action, args=(info,))
+            timer.daemon = True
+            timer.start()
+        try:
+            if decoding:
+                self._dispatch_decode(fn, args, decoding)
+                if self.fault_injector is not None:
+                    # Inside the watchdog window on purpose: a decode_stall
+                    # fault must look exactly like a hung runtime call.
+                    self.fault_injector.on_decode()
+            else:
+                self._picks = self._order = None
+            self._firsts.clear()
+            # A pipelined round leaves the decode it just dispatched
+            # running and reads everything before it.
+            ahead = 1 if decoding and self._pipelined else 0
+            reads = self._unread[:len(self._unread) - ahead]
+            del self._unread[:len(reads)]
+            picks = self._read(reads)
+        finally:
+            if timer is not None:
+                timer.cancel()
+        with profiler.span("serve.step.pick", rnd):
+            self._take(reads, picks)
+            if not self.scheduler.num_active and self._unread:
+                # Every request finished with a decode in flight (EOS or
+                # eviction): its picks are nobody's, and nothing reads them.
+                for unread in self._unread:
+                    for req, _ in unread.rows:
+                        req.unread -= 1
+                    metrics.inc("serve.decode.rows_discarded",
+                                len(unread.rows))
+                self._unread.clear()
+                self._picks = self._order = None
+        if self.fault_injector is not None:
+            self.fault_injector.on_step_end(self._done_count)
+        self._flush_journal()
+        return self.scheduler.num_active
+
+    def _prepare_decode(self, decoding: list[Request]):
+        """Host-side page bookkeeping and the uploads of a decode step:
+        returns its program, its arguments after the weights and the
+        cache, and its bucket."""
         # Ragged mode decodes the whole slot capacity in one program —
         # the scheduler's pow2 bucket is never consulted, so occupancy
         # is measured against true capacity.
         bucket = (self.max_batch if self.paged and self.ragged
                   else self.scheduler.bucket())
-        metrics.observe_value("serve.batch.occupancy", len(ready) / bucket)
-        with profiler.span("serve.step.decode_prep", rnd):
+        metrics.observe_value("serve.batch.occupancy",
+                              len(decoding) / bucket)
+        with profiler.span("serve.step.decode_prep", self._round):
             if self.paged:
                 # Host-side page bookkeeping for this round's appends:
                 # cross a page boundary -> allocate the next page (covered
                 # by the admission reservation); tail page shared with the
                 # prefix cache -> copy-on-write it private before the
                 # scatter.
-                for req in ready:
+                for req in decoding:
                     for src, dst in self._paging.prepare_append(
                             req.slot, int(self._lengths[req.slot])):
                         self.cache = self._copy_fn(
                             self.cache, jnp.int32(src), jnp.int32(dst))
-            host = [self._tokens, self._lengths]
+            host = [self._lengths]
             if self.paged:
                 fn = self._paged_decode_fn(bucket)
                 table = self._paging.allocator.table
@@ -1270,25 +1411,26 @@ class ServeEngine:
                 if metrics.enabled():
                     # Pages this step's attention reads against those
                     # its table rows address: the kernel stops at each
-                    # ready slot's length, the XLA body gathers every
+                    # decoding slot's length, the XLA body gathers every
                     # row of the bucket whole.
                     addressed = bucket * table.shape[1]
                     metrics.inc("serve.decode.pages_addressed", addressed)
                     metrics.inc(
                         "serve.decode.pages_read",
                         sum(int(self._lengths[req.slot]) // self.page_size
-                            + 1 for req in ready)
+                            + 1 for req in decoding)
                         if self._walks_pages else addressed)
             else:
                 fn = self._decode_fn(bucket)
             if self.paged and self.ragged:
-                # Per-slot active mask: only fully-prefilled decoding
-                # slots write to their real tail pages — empty slots AND
-                # slots mid-chunked-prefill (whose table rows hold real
-                # pages a stray decode write must not touch) route their
-                # garbage write to the scratch page inside the kernel.
+                # Per-slot active mask: only decoding slots write to their
+                # real tail pages — empty slots, slots mid-chunked-prefill
+                # (whose table rows hold real pages a stray decode write
+                # must not touch) and slots whose last token is in flight
+                # route their garbage write to the scratch page inside the
+                # kernel.
                 active = np.zeros(self.max_batch, bool)
-                for req in ready:
+                for req in decoding:
                     active[req.slot] = True
                 host.append(active)
                 if self.plan.state_layers and metrics.enabled():
@@ -1296,63 +1438,104 @@ class ServeEngine:
                     # whole blocks up to its highest decoding slot.
                     metrics.inc("serve.state.slots_visited",
                                 kv_cache.state_slots_visited(
-                                    max(req.slot for req in ready) + 1,
+                                    max(req.slot for req in decoding) + 1,
                                     self.max_batch))
                     metrics.inc("serve.state.slots_addressed",
                                 self.max_batch)
             args = self._upload(*host)
-        t0 = self.clock()
-        timer = None
-        if self.stall_timeout_s is not None:
-            info = {"timeout_s": self.stall_timeout_s, "bucket": bucket,
-                    "active": n}
-            timer = threading.Timer(self.stall_timeout_s,
-                                    self.stall_action, args=(info,))
-            timer.daemon = True
-            timer.start()
-        try:
-            with profiler.span("serve.step.decode_dispatch", rnd):
-                self.cache, logits, tokens, *moe = fn(
-                    self.params, self.cache, *args)
-            if moe and metrics.enabled():
-                self._pending_moe.append(moe[0])
-            if self.fault_injector is not None:
-                # Inside the watchdog window on purpose: a decode_stall
-                # fault must look exactly like a hung runtime call.
-                self.fault_injector.on_decode()
-            picked = _Picked(self, logits, self._to_host(
-                tokens, "serve.step.decode_wait"))
-        finally:
-            if timer is not None:
-                timer.cancel()
+            tokens = (self._decode_tokens() if self._pipelined
+                      else self._upload(self._tokens)[0])
+            args.insert(1 if self.paged else 0, tokens)
+        return fn, args, bucket
+
+    def _decode_tokens(self):
+        """A pipelined decode's input tokens, on the device: the previous
+        decode's picks in the slots compaction has moved them to since,
+        with this round's first tokens put in at their slots."""
+        tokens = self._no_picks if self._picks is None else self._picks
+        if self._order is None and not self._firsts:
+            return tokens
+        order = (self._same_order if self._order is None
+                 else self._upload(self._order)[0])
+        for req, token in self._firsts or [(None, self._no_pick)]:
+            slot = self.max_batch if req is None else req.slot
+            tokens = _next_inputs(tokens, order, jnp.int32(slot), token)
+            order = self._same_order
+        return tokens
+
+    def _dispatch_decode(self, fn, args, decoding: list[Request]) -> None:
+        if any(u.dispatched_s is not None for u in self._unread):
+            metrics.inc("serve.decode.overlapped")
+        dispatched_s = self.clock()
+        with profiler.span("serve.step.decode_dispatch", self._round):
+            self.cache, logits, tokens, *stats = fn(
+                self.params, self.cache, *args)
         metrics.inc("serve.decode.steps")
-        if self.virtual_step_s > 0.0 and hasattr(self.clock, "advance"):
-            self.clock.advance(self.virtual_step_s)
-        dt = self.clock() - t0
-        if dt > 0.0:
-            self._step_ema_s = (dt if self._step_ema_s is None else
-                                _EMA_ALPHA * dt
-                                + (1.0 - _EMA_ALPHA) * self._step_ema_s)
-        with profiler.span("serve.step.pick", rnd):
+        for req in decoding:
+            req.unread += 1
+            self._lengths[req.slot] += 1
+        self._unread.append(_Unread(
+            tokens, logits, [(req, req.slot) for req in decoding],
+            dispatched_s, stats[0] if stats and metrics.enabled() else None))
+        if self._pipelined:
+            self._picks, self._order = tokens, None
+
+    def _read(self, reads: list[_Unread]) -> list:
+        """One read-back of the picks in ``reads``. A decode's counters
+        ride along, and the decode-step estimate behind admission takes
+        the time from its dispatch, or from the read of the decode before
+        it where that came later, to this read: the period between two
+        read-backs when rounds are pipelined."""
+        if not reads:
+            return []
+        self._pending_moe.extend(u.stats for u in reads
+                                 if u.stats is not None)
+        picks = self._to_host([u.tokens for u in reads],
+                              "serve.step.decode_wait")
+        for unread in reads:
+            if unread.dispatched_s is None:
+                continue
+            if self.virtual_step_s > 0.0 and hasattr(self.clock, "advance"):
+                self.clock.advance(self.virtual_step_s)
             now = self.clock()
-            completed = []
-            for req in ready:
-                token = self._pick(_LogitsRow(picked, req.slot))
-                self._lengths[req.slot] += 1
-                self._tokens[req.slot] = token
+            dt = now - max(unread.dispatched_s, self._read_s or 0.0)
+            self._read_s = now
+            if dt > 0.0:
+                self._step_ema_s = (dt if self._step_ema_s is None else
+                                    _EMA_ALPHA * dt
+                                    + (1.0 - _EMA_ALPHA) * self._step_ema_s)
+        return picks
+
+    def _take(self, reads: list[_Unread], picks: list) -> None:
+        """Emit the picks just read, in the order they were dispatched:
+        stamp each token once the host has it (after the read and the
+        pick, never at dispatch: the pre-readback stamp under-reported
+        TTFT against any client's clock), journal it, and retire the
+        requests it finishes, highest slot first (each swap moves the
+        untouched last slot)."""
+        completed, now = [], None
+        for unread, tokens in zip(reads, picks):
+            picked = _Picked(self, unread.logits, tokens)
+            for req, index in unread.rows:
+                req.unread -= 1
+                if req.status != ACTIVE:
+                    # Finished or evicted while this pick was in flight.
+                    metrics.inc("serve.decode.rows_discarded")
+                    continue
+                token = self._pick(_LogitsRow(picked, index))
+                now = self.clock()
                 done = self.scheduler.record_token(req, token, now=now)
                 metrics.inc("serve.tokens.generated")
                 if self.journal is not None:
                     self.journal.record_token(req.rid, token)
-                if done or self._lengths[req.slot] >= self.max_len:
+                self._tokens[req.slot] = token
+                # A slot of max_len positions holds the prompt and all but
+                # the newest token.
+                if (done or len(req.prompt) + len(req.generated)
+                        > self.max_len):
                     completed.append(req)
-            # Highest slot first: each swap moves the (untouched) last slot.
-            for req in sorted(completed, key=lambda r: r.slot, reverse=True):
-                self._retire(req, now=now, status=DONE)
-        if self.fault_injector is not None:
-            self.fault_injector.on_step_end(self._done_count)
-        self._flush_journal()
-        return self.scheduler.num_active
+        for req in sorted(completed, key=lambda r: r.slot, reverse=True):
+            self._retire(req, now=now, status=DONE)
 
     def run_until_idle(self, *, max_steps: int = 100_000) -> list[Request]:
         """Drive :meth:`step` until queue and batch drain; returns all

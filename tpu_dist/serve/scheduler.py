@@ -89,6 +89,10 @@ class Request:
     #: release time to bound prefix-cache registration to pages that
     #: were actually written.
     prefill_pos: int = 0
+    #: Tokens that programs dispatched for this request have picked and
+    #: the host has not read yet: a pipelined engine's first token and
+    #: decode in flight. The request stays ACTIVE until they are read.
+    unread: int = 0
 
     @property
     def latency_s(self) -> Optional[float]:
@@ -275,8 +279,9 @@ class Scheduler:
 
     def record_token(self, req: Request, token: int, *, now: float) -> bool:
         """Append a generated token; returns True when the request is now
-        complete (EOS or length). The caller still owns the slot until it
-        calls :meth:`finish`."""
+        complete (EOS or length). ``now`` is when the host has the token
+        (its read-back), never when its program was dispatched. The caller
+        still owns the slot until it calls :meth:`finish`."""
         if req.first_token_s is None:
             req.first_token_s = now
             if req.admit_s is not None and metrics.enabled():
