@@ -745,11 +745,13 @@ class TestPageWalkingKernel:
         walked = engine()
         if ragged:
             walked._paged_decode_fns[4] = walked._jit(
-                kv_cache.paged_decode_ragged, walk=True)
+                "paged_decode_ragged", kv_cache.paged_decode_ragged,
+                walk=True)
         else:
             for bucket in (1, 2, 4):
                 walked._paged_decode_fns[bucket] = walked._jit(
-                    kv_cache.paged_decode_step, bucket=bucket, walk=True)
+                    "paged_decode", kv_cache.paged_decode_step,
+                    bucket=bucket, walk=True)
         assert _drive(walked, workload) == want
 
     def test_the_kernel_stops_at_the_row_and_masks_the_scale_rows(self):

@@ -91,6 +91,43 @@ def test_fused_cross_entropy_compiles(v5e_chip):
     assert text.count("tpu_custom_call") >= 2  # forward and backward
 
 
+def test_training_walk_keeps_the_flash_kernels_names(v5e_chip, monkeypatch):
+    """The model's layer walk runs each layer under its device scope, all
+    but the attention: the flash kernels' instructions are named after
+    their name stack, and the trace's roofline readers
+    (``tpubench/layer_metrics/flash_*_roofline.json``) find them as
+    ``%jvp__`` and ``%transpose_jvp___``. The program is built as on a
+    TPU: the kernel's dispatch is told it runs on one."""
+    from tpu_dist.models.policy import policy, set_policy
+    from tpu_dist.models.transformer import build_transformer_lm
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    before = policy()
+    set_policy("mixed_bfloat16")
+    try:
+        model = build_transformer_lm(512, 1024, d_model=768, depth=1,
+                                     num_heads=12, ff_dim=3072)
+        params = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=v5e_chip),
+            jax.eval_shape(lambda: model.init(0))["params"])
+        x = jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=v5e_chip)
+
+        def loss(p, x):
+            logits, _ = model.apply(p, {}, x, training=True)
+            return logits.mean()
+
+        text = _compiled_text(jax.grad(loss), params, x)
+    finally:
+        set_policy(before)
+    lines = [line.strip().removeprefix("ROOT ") for line in text.splitlines()]
+    kernel = r' = .*custom_call_target="tpu_custom_call"'
+    for name in (r"^%jvp_+(\.\d+)?", r"^%transpose_jvp_+(\.\d+)?"):
+        assert any(re.match(name + kernel, line) for line in lines), name
+    for scope in ("embed", "dense", "head"):
+        assert f"/tpu_dist.{scope}/" in text or f"(tpu_dist.{scope})" in text
+
+
 def test_fused_adam_compiles(v5e_chip):
     tree = {"w": jax.ShapeDtypeStruct((768, 3072), jnp.float32,
                                       sharding=v5e_chip),

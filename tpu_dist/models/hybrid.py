@@ -163,6 +163,7 @@ class LatentAttention(Layer):
     """Multi-head latent attention (no query compression): keys and
     values are rebuilt from one cached latent row a token."""
 
+    scope_kind = "latent"
     num_heads: int
     kv_rank: int
     nope_dim: int
@@ -295,6 +296,10 @@ class GroupedQueryAttention(Layer):
             raise ValueError(
                 f"{self.num_heads} query heads do not share "
                 f"{self.num_kv_heads} K/V heads evenly")
+
+    @property
+    def scope_kind(self) -> str:
+        return "window" if self.window else "gqa"
 
     @property
     def kv_width(self) -> int:
@@ -512,6 +517,7 @@ class DeltaAttention(Layer):
     ``q``/``k``, a per-channel decay with a lower bound, the delta rule,
     a per-head RMSNorm and a head-wise output gate."""
 
+    scope_kind = "state"
     num_heads: int
     head_dim: int
     conv_size: int = 4

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist.ops import initializers
+from tpu_dist.utils import profiler
 
 Params = Any
 State = Any
@@ -64,6 +65,11 @@ def _pair(v) -> tuple[int, int]:
 
 class Layer:
     """Stateless layer description."""
+
+    #: The kind of device scope the layer's work runs under
+    #: (``profiler.LAYER_SCOPES``; a point layer's unless a kind says
+    #: otherwise); ``None`` for a container, whose layers carry their own.
+    scope_kind = "point"
 
     def init(self, key, in_shape: Shape) -> tuple[Params, State, Shape]:
         """Returns (params, state, out_shape); shapes exclude the batch dim."""
@@ -382,9 +388,11 @@ def init_chain(layers: Sequence[Layer], names: Sequence[str], key, in_shape):
 
 
 def apply_chain(layers: Sequence[Layer], names: Sequence[str], params, state,
-                x, *, training: bool, rng):
+                x, *, training: bool, rng, head: bool = False):
     """Apply a layer chain; returns (y, new_state). Dropout layers receive
-    per-layer keys folded from ``rng``."""
+    per-layer keys folded from ``rng``. Each layer runs under its device
+    scope (``scope_kind``); with ``head`` the last one is the model's
+    head."""
     new_state = dict(state) if state else {}
     for i, (layer, name) in enumerate(zip(layers, names)):
         p = params.get(name, {}) if params else {}
@@ -392,7 +400,10 @@ def apply_chain(layers: Sequence[Layer], names: Sequence[str], params, state,
         # Every layer gets a per-position key (containers thread it down to
         # nested Dropouts); layers that don't use randomness ignore it.
         layer_rng = jax.random.fold_in(rng, i) if rng is not None else None
-        x, s_new = layer.apply(p, s, x, training=training, rng=layer_rng)
+        kind = ("head" if head and i == len(layers) - 1
+                else layer.scope_kind)
+        with profiler.layer_scope(kind):
+            x, s_new = layer.apply(p, s, x, training=training, rng=layer_rng)
         if s_new:
             new_state[name] = s_new
     return x, new_state
@@ -405,6 +416,7 @@ class Block(Layer):
     sublayer names."""
 
     layers: tuple = ()
+    scope_kind = None
 
     def __post_init__(self):
         object.__setattr__(self, "layers", tuple(self.layers))
@@ -431,6 +443,7 @@ class Residual(Layer):
     main: tuple = ()
     shortcut: tuple = ()
     activation: Optional[str] = "relu"
+    scope_kind = None
 
     def __post_init__(self):
         object.__setattr__(self, "main", tuple(self.main))

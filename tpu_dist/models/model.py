@@ -263,6 +263,7 @@ class Sequential(Model):
     def _apply_layers(self, params, state, x, training, rng):
         from tpu_dist.models.layers import apply_chain
         from tpu_dist.models.policy import compute_dtype
+        from tpu_dist.utils import profiler
 
         # Mixed-precision entry/exit casts (policy.py): activations run in the
         # compute dtype, the returned logits in float32 for a stable loss.
@@ -270,9 +271,11 @@ class Sequential(Model):
         if x.dtype != dtype and jax.numpy.issubdtype(x.dtype, jax.numpy.floating):
             x = x.astype(dtype)
         y, new_state = apply_chain(self.layers, self.layer_names, params,
-                                   state, x, training=training, rng=rng)
+                                   state, x, training=training, rng=rng,
+                                   head=True)
         if jax.numpy.issubdtype(y.dtype, jax.numpy.floating):
-            y = y.astype(jax.numpy.float32)
+            with profiler.layer_scope("head"):
+                y = y.astype(jax.numpy.float32)
         return y, new_state
 
     def summary(self) -> str:

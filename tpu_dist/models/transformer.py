@@ -47,6 +47,7 @@ from tpu_dist.parallel.pipeline_parallel import PipelinedBlocks  # noqa: F401
 class Embedding(Layer):
     """Token embedding: int [L] -> float [L, dim] lookup table."""
 
+    scope_kind = "embed"
     vocab_size: int
     dim: int
     #: GPT-style init scale (normal); Keras' uniform(-0.05, 0.05) converges
@@ -68,6 +69,7 @@ class Embedding(Layer):
 class PositionalEmbedding(Layer):
     """Learned absolute positions, added to a [.., L, D] stream."""
 
+    scope_kind = "pos"
     max_len: int
     init_scale: float = 0.02
 
@@ -233,6 +235,10 @@ class MultiHeadAttention(Layer):
     callables can't serialize; save weights and rebuild in code instead.
     """
 
+    #: Unscoped in the training walk: the flash kernels' instructions
+    #: are named after their name stack (``%jvp__``, ``%transpose_jvp___``),
+    #: which a scope would rename. The serve walks scope it by its op.
+    scope_kind = None
     num_heads: int
     key_dim: int
     causal: bool = False

@@ -110,6 +110,7 @@ class MixtureOfExperts(Layer):
     aux loss is state the pipeline cannot thread.
     """
 
+    scope_kind = "moe"
     num_experts: int = 8
     ff_dim: int = 0
     top_k: int = 2

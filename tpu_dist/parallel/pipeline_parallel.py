@@ -133,6 +133,7 @@ class PipelinedBlocks(Layer):
     are equal in distribution, not bit-equal, across topologies.)
     """
 
+    scope_kind = None
     block: Layer = None
     num_stages: int = 2
     microbatches: int = 4

@@ -62,6 +62,7 @@ class RoutedExperts(Layer):
     module docstring. ``shared_ff_dim=0`` leaves the shared expert out
     (how a test counts it once over several shares)."""
 
+    scope_kind = "moe"
     num_experts: int                 #: the router's width, all chips
     experts_held: tuple              #: (first, count) of those held here
     top_k: int

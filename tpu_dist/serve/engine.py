@@ -153,6 +153,16 @@ def _current_job():
     return mod.current_job() if mod is not None else None
 
 
+def _named(name: str, fn):
+    """``fn`` as the program ``serve_<name>``: ``jax.jit`` of it lowers as
+    module ``jit_serve_<name>``, the name the trace's ``XLA Modules`` line
+    gives each of its executions."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = f"serve_{name}"
+    return program
+
+
 def _picking(body):
     """Wrap one of ``kv_cache``'s program bodies, which return ``(cache,
     logits, *rest)``, so that the program also returns the greedy pick:
@@ -163,12 +173,14 @@ def _picking(body):
     output: they are left on the device until somebody reads them."""
     def program(*args):
         cache, logits, *rest = body(*args)
-        return (cache, logits,
-                jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
+        with profiler.layer_scope("head"):
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (cache, logits, tokens, *rest)
     return program
 
 
 @jax.jit
+@functools.partial(_named, "next_inputs")
 def _next_inputs(tokens, order, slot, token):
     """A pipelined decode's input tokens, made on the device where the
     previous decode left its picks: ``tokens[order]`` (``order[i]`` is the
@@ -576,7 +588,7 @@ class ServeEngine:
         self._decode_fns: dict[int, callable] = {}
         self._prefill_fns: dict[int, callable] = {}
         self._donate = donate
-        self._swap_fn = jax.jit(kv_cache.swap_slots,
+        self._swap_fn = jax.jit(_named("swap_slots", kv_cache.swap_slots),
                                 donate_argnums=(0,) if donate else ())
         self._paged_decode_fns: dict[int, callable] = {}
         self._paged_prefill_fns: dict[int, callable] = {}
@@ -584,12 +596,13 @@ class ServeEngine:
         #: (The paged chunked path reuses _paged_prefill_fns — the paged
         #: prefill kernel already takes a traced window start.)
         self._chunk_fns: dict[int, callable] = {}
-        self._copy_fn = jax.jit(kv_cache.copy_page,
+        self._copy_fn = jax.jit(_named("copy_page", kv_cache.copy_page),
                                 donate_argnums=(0,) if donate else ())
         #: Moves the per-slot recurrent state when compaction swaps slots
         #: (their pages move by a host pointer swap).
-        self._swap_state_fn = jax.jit(kv_cache.swap_state,
-                                      donate_argnums=(0,) if donate else ())
+        self._swap_state_fn = jax.jit(
+            _named("swap_state", kv_cache.swap_state),
+            donate_argnums=(0,) if donate else ())
 
         # -- resilience state --------------------------------------------
         self.max_ttft_s = None if max_ttft_s is None else float(max_ttft_s)
@@ -733,19 +746,21 @@ class ServeEngine:
             self._job.program_key(self.model.name, self._serial, kind, key),
             build)
 
-    def _jit(self, body, **fixed):
+    def _jit(self, kind: str, body, **fixed):
         """The one place the engine compiles a program of ``kv_cache``
         that returns logits: its plan and ``fixed`` keywords bound, the
-        greedy pick added (:func:`_picking`), the cache donated."""
-        return jax.jit(_picking(functools.partial(body, self.plan, **fixed)),
-                       donate_argnums=self._donate)
+        greedy pick added (:func:`_picking`), the cache donated, named
+        ``serve_<kind>`` (:func:`_named`)."""
+        program = _picking(functools.partial(body, self.plan, **fixed))
+        return jax.jit(_named(kind, program), donate_argnums=self._donate)
 
     def _decode_fn(self, bucket: int):
         fn = self._decode_fns.get(bucket)
         if fn is None:
             fn = self._acquire_program(
                 "decode", bucket,
-                lambda: self._jit(kv_cache.decode_step, bucket=bucket))
+                lambda: self._jit("decode", kv_cache.decode_step,
+                                  bucket=bucket))
             self._decode_fns[bucket] = fn
         return fn
 
@@ -753,7 +768,8 @@ class ServeEngine:
         fn = self._prefill_fns.get(pad_len)
         if fn is None:
             fn = self._acquire_program(
-                "prefill", pad_len, lambda: self._jit(kv_cache.prefill))
+                "prefill", pad_len,
+                lambda: self._jit("prefill", kv_cache.prefill))
             self._prefill_fns[pad_len] = fn
         return fn
 
@@ -766,12 +782,14 @@ class ServeEngine:
                 # compiled_programs() reports the surface uniformly.
                 fn = self._acquire_program(
                     "paged_decode_ragged", bucket,
-                    lambda: self._jit(kv_cache.paged_decode_ragged,
+                    lambda: self._jit("paged_decode_ragged",
+                                      kv_cache.paged_decode_ragged,
                                       walk=self._walks_pages))
             else:
                 fn = self._acquire_program(
                     "paged_decode", bucket,
-                    lambda: self._jit(kv_cache.paged_decode_step,
+                    lambda: self._jit("paged_decode",
+                                      kv_cache.paged_decode_step,
                                       bucket=bucket,
                                       walk=self._walks_pages))
             self._paged_decode_fns[bucket] = fn
@@ -782,7 +800,7 @@ class ServeEngine:
         if fn is None:
             fn = self._acquire_program(
                 "paged_prefill", pad_len,
-                lambda: self._jit(kv_cache.paged_prefill))
+                lambda: self._jit("paged_prefill", kv_cache.paged_prefill))
             self._paged_prefill_fns[pad_len] = fn
         return fn
 
@@ -791,7 +809,8 @@ class ServeEngine:
         if fn is None:
             fn = self._acquire_program(
                 "prefill_chunk", pad_len,
-                lambda: self._jit(kv_cache.prefill_chunk_step))
+                lambda: self._jit("prefill_chunk",
+                                  kv_cache.prefill_chunk_step))
             self._chunk_fns[pad_len] = fn
         return fn
 

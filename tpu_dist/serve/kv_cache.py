@@ -70,6 +70,7 @@ from tpu_dist.models.transformer import (Embedding, LayerNormalization,
                                          _default_attention)
 from tpu_dist.ops import paged_attention
 from tpu_dist.parallel.routed_experts import RoutedExperts
+from tpu_dist.utils import profiler
 
 # -- plan: a flat, servable description of the Sequential ---------------------
 
@@ -346,6 +347,16 @@ def _learned_positions(params, path, x, pos):
 _PAGED_TAGS = ("latent", "state", "moe", "gqa", "window")
 
 
+def _scope(plan: DecodePlan, op):
+    """The device scope of one plan op, entered once a walk and op: its
+    tag's (``profiler.LAYER_SCOPES``); the residual's add a point op's;
+    the vocabulary head (the plan's last op) its own."""
+    if op is plan.ops[-1]:
+        return profiler.layer_scope("head")
+    return profiler.layer_scope(
+        {"res_start": None, "res_end": "point"}.get(op[0], op[0]))
+
+
 def _paged_only(op):
     return TypeError(
         f"serve: {type(op[1]).__name__} keeps its cache in latent pages, "
@@ -383,32 +394,34 @@ def prefill(plan: DecodePlan, params, cache: dict, tokens, length, slot,
     residuals: list = []
     for op in plan.ops:
         tag = op[0]
-        if tag == "res_start":
-            residuals.append(x)
-        elif tag == "res_end":
-            x = _activation(op[1])(residuals.pop() + x)
-        elif tag == "attn":
-            _, layer, path, idx = op
-            p = _params_at(params, path)
-            q, k, v = _qkv(layer, p, x)  # [1, H, pad_len, dk]
-            scale = 1.0 / math.sqrt(layer.key_dim)
-            out = attend(q, k, v, causal=True, scale=scale)
-            dt = cache["k"].dtype
-            for name, new in (("k", k), ("v", v)):
-                cache[name] = jax.lax.dynamic_update_slice(
-                    cache[name], new.astype(dt)[None],
-                    (idx, slot, 0, 0, 0))
-            x = _attn_out(layer, p, out)
-        elif tag == "pos":
-            x = _learned_positions(params, op[2], x, jnp.arange(pad_len))
-        elif tag in _PAGED_TAGS:
-            raise _paged_only(op)
-        else:  # "embed" / "point": the layer's own stateless apply
-            _, layer, path = op
-            x, _ = layer.apply(_params_at(params, path), {}, x)
+        with _scope(plan, op):
+            if tag == "res_start":
+                residuals.append(x)
+            elif tag == "res_end":
+                x = _activation(op[1])(residuals.pop() + x)
+            elif tag == "attn":
+                _, layer, path, idx = op
+                p = _params_at(params, path)
+                q, k, v = _qkv(layer, p, x)  # [1, H, pad_len, dk]
+                scale = 1.0 / math.sqrt(layer.key_dim)
+                out = attend(q, k, v, causal=True, scale=scale)
+                dt = cache["k"].dtype
+                for name, new in (("k", k), ("v", v)):
+                    cache[name] = jax.lax.dynamic_update_slice(
+                        cache[name], new.astype(dt)[None],
+                        (idx, slot, 0, 0, 0))
+                x = _attn_out(layer, p, out)
+            elif tag == "pos":
+                x = _learned_positions(params, op[2], x, jnp.arange(pad_len))
+            elif tag in _PAGED_TAGS:
+                raise _paged_only(op)
+            else:  # "embed" / "point": the layer's own stateless apply
+                _, layer, path = op
+                x, _ = layer.apply(_params_at(params, path), {}, x)
     # x: [1, pad_len, vocab]; take the last VALID position's logits.
-    last = jax.lax.dynamic_slice(
-        x, (0, jnp.maximum(length - 1, 0), 0), (1, 1, plan.vocab_size))
+    with profiler.layer_scope("head"):
+        last = jax.lax.dynamic_slice(
+            x, (0, jnp.maximum(length - 1, 0), 0), (1, 1, plan.vocab_size))
     return cache, last[0, 0]
 
 
@@ -456,44 +469,46 @@ def prefill_chunk_step(plan: DecodePlan, params, cache: dict, tokens,
     residuals: list = []
     for op in plan.ops:
         tag = op[0]
-        if tag == "res_start":
-            residuals.append(x)
-        elif tag == "res_end":
-            x = _activation(op[1])(residuals.pop() + x)
-        elif tag == "pos":
-            x = _learned_positions(params, op[2], x, pos)
-        elif tag in _PAGED_TAGS:
-            raise _paged_only(op)
-        elif tag == "attn":
-            _, layer, path, idx = op
-            p = _params_at(params, path)
-            q, k, v = _qkv(layer, p, x)    # [1, H, pad, dk]
-            dt = cache["k"].dtype
-            # Window write at the traced chunk offset, then attend over
-            # the whole row (earlier chunks' K/V plus this one's).
-            for name, new in (("k", k), ("v", v)):
-                cache[name] = jax.lax.dynamic_update_slice(
-                    cache[name], new.astype(dt)[None],
-                    (idx, slot, 0, start, 0))
-            keys = jnp.take(cache["k"][idx], slot, axis=0)  # [H, S, dk]
-            vals = jnp.take(cache["v"][idx], slot, axis=0)
-            scale = 1.0 / math.sqrt(layer.key_dim)
-            s = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
-                           keys.astype(jnp.float32)) * scale
-            # Key j is position j: <= the query's own absolute position
-            # covers causality and prefix validity in one mask.
-            mask = key_pos[None, :] <= pos[:, None]         # [pad, S]
-            s = jnp.where(mask[None], s, -jnp.inf)
-            prob = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("hqk,hkd->hqd", prob,
-                             vals.astype(jnp.float32))
-            x = _attn_out(layer, p, out.astype(q.dtype)[None])
-        else:  # "embed" / "point"
-            _, layer, path = op
-            x, _ = layer.apply(_params_at(params, path), {}, x)
+        with _scope(plan, op):
+            if tag == "res_start":
+                residuals.append(x)
+            elif tag == "res_end":
+                x = _activation(op[1])(residuals.pop() + x)
+            elif tag == "pos":
+                x = _learned_positions(params, op[2], x, pos)
+            elif tag in _PAGED_TAGS:
+                raise _paged_only(op)
+            elif tag == "attn":
+                _, layer, path, idx = op
+                p = _params_at(params, path)
+                q, k, v = _qkv(layer, p, x)    # [1, H, pad, dk]
+                dt = cache["k"].dtype
+                # Window write at the traced chunk offset, then attend over
+                # the whole row (earlier chunks' K/V plus this one's).
+                for name, new in (("k", k), ("v", v)):
+                    cache[name] = jax.lax.dynamic_update_slice(
+                        cache[name], new.astype(dt)[None],
+                        (idx, slot, 0, start, 0))
+                keys = jnp.take(cache["k"][idx], slot, axis=0)  # [H, S, dk]
+                vals = jnp.take(cache["v"][idx], slot, axis=0)
+                scale = 1.0 / math.sqrt(layer.key_dim)
+                s = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
+                               keys.astype(jnp.float32)) * scale
+                # Key j is position j: <= the query's own absolute position
+                # covers causality and prefix validity in one mask.
+                mask = key_pos[None, :] <= pos[:, None]         # [pad, S]
+                s = jnp.where(mask[None], s, -jnp.inf)
+                prob = jax.nn.softmax(s, axis=-1)
+                out = jnp.einsum("hqk,hkd->hqd", prob,
+                                 vals.astype(jnp.float32))
+                x = _attn_out(layer, p, out.astype(q.dtype)[None])
+            else:  # "embed" / "point"
+                _, layer, path = op
+                x, _ = layer.apply(_params_at(params, path), {}, x)
     # x: [1, pad, vocab]; last valid chunk position is valid - 1.
-    last = jax.lax.dynamic_slice(
-        x, (0, jnp.maximum(valid - 1, 0), 0), (1, 1, plan.vocab_size))
+    with profiler.layer_scope("head"):
+        last = jax.lax.dynamic_slice(
+            x, (0, jnp.maximum(valid - 1, 0), 0), (1, 1, plan.vocab_size))
     return cache, last[0, 0]
 
 
@@ -523,42 +538,45 @@ def decode_step(plan: DecodePlan, params, cache: dict, tokens, lengths,
     residuals: list = []
     for op in plan.ops:
         tag = op[0]
-        if tag == "res_start":
-            residuals.append(x)
-        elif tag == "res_end":
-            x = _activation(op[1])(residuals.pop() + x)
-        elif tag == "pos":
-            x = _learned_positions(params, op[2], x, pos)
-        elif tag in _PAGED_TAGS:
-            raise _paged_only(op)
-        elif tag == "attn":
-            _, layer, path, idx = op
-            p = _params_at(params, path)
-            q, k, v = _qkv(layer, p, x)   # [b, H, 1, dk]
-            dt = cache["k"].dtype
-            # Append this position's K/V at each slot's length (batched
-            # scatter; advanced indices around the head slice put the
-            # broadcast [b, H, dk] dims in front, matching the operand).
-            cache["k"] = cache["k"].at[idx, rows, :, pos, :].set(
-                k[:, :, 0, :].astype(dt))
-            cache["v"] = cache["v"].at[idx, rows, :, pos, :].set(
-                v[:, :, 0, :].astype(dt))
-            keys = cache["k"][idx, :bucket]      # [b, H, S, dk]
-            vals = cache["v"][idx, :bucket]
-            scale = 1.0 / math.sqrt(layer.key_dim)
-            s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                           keys.astype(jnp.float32)) * scale
-            # Valid keys: cached prefix plus the just-appended position.
-            valid = jnp.arange(max_len)[None, :] <= pos[:, None]  # [b, S]
-            s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
-            prob = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", prob,
-                             vals.astype(jnp.float32)).astype(q.dtype)
-            x = _attn_out(layer, p, out)
-        else:  # "embed" / "point"
-            _, layer, path = op
-            x, _ = layer.apply(_params_at(params, path), {}, x)
-    return cache, x[:, 0, :].astype(jnp.float32)  # [b, vocab]
+        with _scope(plan, op):
+            if tag == "res_start":
+                residuals.append(x)
+            elif tag == "res_end":
+                x = _activation(op[1])(residuals.pop() + x)
+            elif tag == "pos":
+                x = _learned_positions(params, op[2], x, pos)
+            elif tag in _PAGED_TAGS:
+                raise _paged_only(op)
+            elif tag == "attn":
+                _, layer, path, idx = op
+                p = _params_at(params, path)
+                q, k, v = _qkv(layer, p, x)   # [b, H, 1, dk]
+                dt = cache["k"].dtype
+                # Append this position's K/V at each slot's length (batched
+                # scatter; advanced indices around the head slice put the
+                # broadcast [b, H, dk] dims in front, matching the operand).
+                cache["k"] = cache["k"].at[idx, rows, :, pos, :].set(
+                    k[:, :, 0, :].astype(dt))
+                cache["v"] = cache["v"].at[idx, rows, :, pos, :].set(
+                    v[:, :, 0, :].astype(dt))
+                keys = cache["k"][idx, :bucket]      # [b, H, S, dk]
+                vals = cache["v"][idx, :bucket]
+                scale = 1.0 / math.sqrt(layer.key_dim)
+                s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                               keys.astype(jnp.float32)) * scale
+                # Valid keys: cached prefix plus the just-appended position.
+                valid = jnp.arange(max_len)[None, :] <= pos[:, None]  # [b, S]
+                s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+                prob = jax.nn.softmax(s, axis=-1)
+                out = jnp.einsum("bhqk,bhkd->bhqd", prob,
+                                 vals.astype(jnp.float32)).astype(q.dtype)
+                x = _attn_out(layer, p, out)
+            else:  # "embed" / "point"
+                _, layer, path = op
+                x, _ = layer.apply(_params_at(params, path), {}, x)
+    with profiler.layer_scope("head"):
+        logits = x[:, 0, :].astype(jnp.float32)  # [b, vocab]
+    return cache, logits
 
 
 def swap_slots(cache: dict, i, j):
@@ -870,18 +888,17 @@ def _latent_prefill(op, params, pool, page_row, x, pos, valid_q):
     p = _params_at(params, path)
     num_pages, ps = pool["latent"].shape[1] - 1, pool["latent"].shape[2]
     max_pages = page_row.shape[0]
-    with jax.named_scope("tpu_dist.mla"):
-        q_nope, q_rope, latent = layer.project(p, x, pos)
-        pg = jnp.where(valid_q,
-                       page_row[jnp.minimum(pos // ps, max_pages - 1)],
-                       num_pages)
-        pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
-            latent[0].astype(pool["latent"].dtype))
-        rows = pool["latent"][idx][page_row].reshape(max_pages * ps, -1)
-        # Key j is position j: <= the query's own absolute position.
-        mask = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
-        o = layer.attend_expanded(p, q_nope[0], q_rope[0], rows, mask)
-        return layer.output(p, x, jnp.moveaxis(o, 0, 1)[None])
+    q_nope, q_rope, latent = layer.project(p, x, pos)
+    pg = jnp.where(valid_q,
+                   page_row[jnp.minimum(pos // ps, max_pages - 1)],
+                   num_pages)
+    pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
+        latent[0].astype(pool["latent"].dtype))
+    rows = pool["latent"][idx][page_row].reshape(max_pages * ps, -1)
+    # Key j is position j: <= the query's own absolute position.
+    mask = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
+    o = layer.attend_expanded(p, q_nope[0], q_rope[0], rows, mask)
+    return layer.output(p, x, jnp.moveaxis(o, 0, 1)[None])
 
 
 def _latent_decode(op, params, pool, tables, x, pos, active):
@@ -889,39 +906,37 @@ def _latent_decode(op, params, pool, tables, x, pos, active):
     p = _params_at(params, path)
     num_pages, ps = pool["latent"].shape[1] - 1, pool["latent"].shape[2]
     b, max_pages = tables.shape
-    with jax.named_scope("tpu_dist.mla"):
-        q_nope, q_rope, latent = layer.project(p, x, pos[:, None])
-        pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
-        if active is not None:
-            pg = jnp.where(active, pg, num_pages)
-        pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
-            latent[:, 0].astype(pool["latent"].dtype))
-        rows = pool["latent"][idx][tables].reshape(b, max_pages * ps, -1)
-        valid = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
-        o = layer.attend_absorbed(p, q_nope[:, :, 0], q_rope[:, :, 0], rows,
-                                  valid)
-        return layer.output(p, x, o[:, None])
+    q_nope, q_rope, latent = layer.project(p, x, pos[:, None])
+    pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
+    if active is not None:
+        pg = jnp.where(active, pg, num_pages)
+    pool["latent"] = pool["latent"].at[idx, pg, pos % ps, :].set(
+        latent[:, 0].astype(pool["latent"].dtype))
+    rows = pool["latent"][idx][tables].reshape(b, max_pages * ps, -1)
+    valid = jnp.arange(max_pages * ps)[None, :] <= pos[:, None]
+    o = layer.attend_absorbed(p, q_nope[:, :, 0], q_rope[:, :, 0], rows,
+                              valid)
+    return layer.output(p, x, o[:, None])
 
 
 def _state_prefill(op, params, pool, slot, x, start, valid_q):
     _, layer, path, idx = op
     p = _params_at(params, path)
     taps = layer.conv_size
-    with jax.named_scope("tpu_dist.kda"):
-        qkv, beta, g = layer.project(p, x)
-        fresh = start == 0
-        tail = jnp.where(fresh, 0.0, pool["conv"][idx, slot])
-        s0 = jnp.where(fresh, 0.0, pool["state"][idx, slot])
-        qkv, window = causal_conv(qkv[0], tail, p["conv"])
-        q, k, v = layer.heads(qkv)                         # [pad, H, dk]
-        # A padded position leaves the state as it was.
-        beta = jnp.where(valid_q[:, None], beta[0], 0.0)
-        g = jnp.where(valid_q[:, None, None], g[0], 0.0)
-        o, s = layer.scan(q, k, v, g, beta, s0)
-        pool["state"] = pool["state"].at[idx, slot].set(s)
-        pool["conv"] = pool["conv"].at[idx, slot].set(
-            jax.lax.dynamic_slice_in_dim(window, jnp.sum(valid_q), taps - 1))
-        return layer.output(p, x, o[None])
+    qkv, beta, g = layer.project(p, x)
+    fresh = start == 0
+    tail = jnp.where(fresh, 0.0, pool["conv"][idx, slot])
+    s0 = jnp.where(fresh, 0.0, pool["state"][idx, slot])
+    qkv, window = causal_conv(qkv[0], tail, p["conv"])
+    q, k, v = layer.heads(qkv)                         # [pad, H, dk]
+    # A padded position leaves the state as it was.
+    beta = jnp.where(valid_q[:, None], beta[0], 0.0)
+    g = jnp.where(valid_q[:, None, None], g[0], 0.0)
+    o, s = layer.scan(q, k, v, g, beta, s0)
+    pool["state"] = pool["state"].at[idx, slot].set(s)
+    pool["conv"] = pool["conv"].at[idx, slot].set(
+        jax.lax.dynamic_slice_in_dim(window, jnp.sum(valid_q), taps - 1))
+    return layer.output(p, x, o[None])
 
 
 #: Slots of the state pool one visit of a decode step reads and writes:
@@ -950,46 +965,45 @@ def _state_decode(op, params, pool, x, active):
     p = _params_at(params, path)
     b = x.shape[0]
     block = state_slot_block(b)
-    with jax.named_scope("tpu_dist.kda"):
-        qkv, beta, g = layer.project(p, x)                 # [b, 1, *]
-        # The engine keeps live slots at the front (``_retire`` moves the
-        # last one into a freed slot): nothing decodes behind ``hi``.
-        hi = b if active is None else jnp.max(
-            jnp.where(active, jnp.arange(1, b + 1), 0))
+    qkv, beta, g = layer.project(p, x)                 # [b, 1, *]
+    # The engine keeps live slots at the front (``_retire`` moves the
+    # last one into a freed slot): nothing decodes behind ``hi``.
+    hi = b if active is None else jnp.max(
+        jnp.where(active, jnp.arange(1, b + 1), 0))
 
-        def one(i, carry):
-            state, conv, o = carry
-            at = i * block
-            rows = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block)
-            # Out of the whole pool, not out of a layer's copy of it.
-            old = jax.lax.dynamic_slice(
-                state, (idx, at, 0, 0, 0), (1, block) + state.shape[2:])[0]
-            tail = jax.lax.dynamic_slice(
-                conv, (idx, at, 0, 0), (1, block) + conv.shape[2:])[0]
-            y, window = causal_conv(rows(qkv), tail, p["conv"])
-            q, k, v = layer.heads(y)                       # [block, 1, H, dk]
-            o_new, s = delta_rule_step(q[:, 0], k[:, 0], v[:, 0],
-                                       rows(g)[:, 0], rows(beta)[:, 0], old)
-            tail_new = window[:, 1:]
-            if active is not None:
-                # A slot that is not decoding (empty, or mid-prefill with a
-                # real state) keeps what it holds.
-                live = rows(active)
-                s = jnp.where(live[:, None, None, None], s, old)
-                tail_new = jnp.where(live[:, None, None], tail_new, tail)
-            return (jax.lax.dynamic_update_slice(state, s[None],
-                                                 (idx, at, 0, 0, 0)),
-                    jax.lax.dynamic_update_slice(conv, tail_new[None],
-                                                 (idx, at, 0, 0)),
-                    jax.lax.dynamic_update_slice_in_dim(o, o_new, at, 0))
+    def one(i, carry):
+        state, conv, o = carry
+        at = i * block
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block)
+        # Out of the whole pool, not out of a layer's copy of it.
+        old = jax.lax.dynamic_slice(
+            state, (idx, at, 0, 0, 0), (1, block) + state.shape[2:])[0]
+        tail = jax.lax.dynamic_slice(
+            conv, (idx, at, 0, 0), (1, block) + conv.shape[2:])[0]
+        y, window = causal_conv(rows(qkv), tail, p["conv"])
+        q, k, v = layer.heads(y)                       # [block, 1, H, dk]
+        o_new, s = delta_rule_step(q[:, 0], k[:, 0], v[:, 0],
+                                   rows(g)[:, 0], rows(beta)[:, 0], old)
+        tail_new = window[:, 1:]
+        if active is not None:
+            # A slot that is not decoding (empty, or mid-prefill with a
+            # real state) keeps what it holds.
+            live = rows(active)
+            s = jnp.where(live[:, None, None, None], s, old)
+            tail_new = jnp.where(live[:, None, None], tail_new, tail)
+        return (jax.lax.dynamic_update_slice(state, s[None],
+                                             (idx, at, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(conv, tail_new[None],
+                                             (idx, at, 0, 0)),
+                jax.lax.dynamic_update_slice_in_dim(o, o_new, at, 0))
 
-        # A row behind the last visited block keeps a zero output: it has
-        # no expert and the host reads no token of it.
-        pool["state"], pool["conv"], o = jax.lax.fori_loop(
-            0, state_slots_visited(hi, b) // block, one,
-            (pool["state"], pool["conv"],
-             jnp.zeros((b, layer.num_heads, layer.head_dim), jnp.float32)))
-        return layer.output(p, x, o[:, None])
+    # A row behind the last visited block keeps a zero output: it has
+    # no expert and the host reads no token of it.
+    pool["state"], pool["conv"], o = jax.lax.fori_loop(
+        0, state_slots_visited(hi, b) // block, one,
+        (pool["state"], pool["conv"],
+         jnp.zeros((b, layer.num_heads, layer.head_dim), jnp.float32)))
+    return layer.output(p, x, o[:, None])
 
 
 # -- grouped-query layers: K/V pages by length, window rings by slot ------------
@@ -1044,24 +1058,23 @@ def _gqa_prefill(op, params, pool, page_row, x, pos, valid_q, length):
     max_pages = page_row.shape[0]
     block = prefill_key_block(max_pages, ps)
     ppb = block // ps
-    with jax.named_scope("tpu_dist.gqa.full"):
-        q, k, v = layer.project(p, x, pos)
-        pg = jnp.where(valid_q,
-                       page_row[jnp.minimum(pos // ps, max_pages - 1)],
-                       num_pages)
-        _write_rows(pool, idx, pg, pos % ps, k[0], v[0])
-        # Whole blocks of pages; what lies behind the row is the scratch
-        # page, behind every query's own position like all that it holds.
-        row = jnp.pad(page_row, (0, -max_pages % ppb),
-                      constant_values=num_pages)
+    q, k, v = layer.project(p, x, pos)
+    pg = jnp.where(valid_q,
+                   page_row[jnp.minimum(pos // ps, max_pages - 1)],
+                   num_pages)
+    _write_rows(pool, idx, pg, pos % ps, k[0], v[0])
+    # Whole blocks of pages; what lies behind the row is the scratch
+    # page, behind every query's own position like all that it holds.
+    row = jnp.pad(page_row, (0, -max_pages % ppb),
+                  constant_values=num_pages)
 
-        def fetch(i):
-            pages = jax.lax.dynamic_slice_in_dim(row, i * ppb, ppb)
-            return (pool["k"][idx, pages].reshape(block, -1),
-                    pool["v"][idx, pages].reshape(block, -1))
+    def fetch(i):
+        pages = jax.lax.dynamic_slice_in_dim(row, i * ppb, ppb)
+        return (pool["k"][idx, pages].reshape(block, -1),
+                pool["v"][idx, pages].reshape(block, -1))
 
-        o = layer.attend_blocks(q[0], pos, fetch, -(-length // block), block)
-        return layer.output(p, x, o[None])
+    o = layer.attend_blocks(q[0], pos, fetch, -(-length // block), block)
+    return layer.output(p, x, o[None])
 
 
 def _gqa_decode(op, params, pool, tables, x, pos, active, n_keys, walk):
@@ -1069,67 +1082,64 @@ def _gqa_decode(op, params, pool, tables, x, pos, active, n_keys, walk):
     p = _params_at(params, path)
     num_pages, ps = pool["k"].shape[1] - 1, pool["k"].shape[2]
     b, max_pages = tables.shape
-    with jax.named_scope("tpu_dist.gqa.full"):
-        q, k, v = layer.project(p, x, pos[:, None])      # q [b, H, 1, dk]
-        pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
-        if active is not None:
-            pg = jnp.where(active, pg, num_pages)
-        _write_rows(pool, idx, pg, pos % ps, k[:, 0], v[:, 0])
-        if walk:
-            o = _walked_attention({"k": pool["k"], "v": pool["v"]},
-                                  jnp.int32(idx), tables,
-                                  q.astype(pool["k"].dtype), n_keys)
-        else:
-            rows = lambda name: pool[name][idx][tables].reshape(
-                b, max_pages * ps, -1)
-            valid = jnp.arange(max_pages * ps)[None, :] < n_keys[:, None]
-            o = layer.attend(q, rows("k"), rows("v"), valid[:, None, :])
-        return layer.output(p, x, o)
+    q, k, v = layer.project(p, x, pos[:, None])      # q [b, H, 1, dk]
+    pg = tables[jnp.arange(b), jnp.minimum(pos // ps, max_pages - 1)]
+    if active is not None:
+        pg = jnp.where(active, pg, num_pages)
+    _write_rows(pool, idx, pg, pos % ps, k[:, 0], v[:, 0])
+    if walk:
+        o = _walked_attention({"k": pool["k"], "v": pool["v"]},
+                              jnp.int32(idx), tables,
+                              q.astype(pool["k"].dtype), n_keys)
+    else:
+        rows = lambda name: pool[name][idx][tables].reshape(
+            b, max_pages * ps, -1)
+        valid = jnp.arange(max_pages * ps)[None, :] < n_keys[:, None]
+        o = layer.attend(q, rows("k"), rows("v"), valid[:, None, :])
+    return layer.output(p, x, o)
 
 
 def _window_prefill(op, params, pool, slot, x, pos, start, length):
     _, layer, path, idx = op
     p = _params_at(params, path)
     window, pad = pool["wk"].shape[2], pos.shape[0]
-    with jax.named_scope("tpu_dist.gqa.window"):
-        q, k, v = layer.project(p, x, pos)
-        ring = {n: pool[n][idx, slot] for n in ("wk", "wv")}
-        new = {"wk": k[0].astype(ring["wk"].dtype),
-               "wv": v[0].astype(ring["wv"].dtype)}
-        # The ring as earlier chunks left it, then the chunk's own rows: a
-        # padded row keeps its position, which no valid query reaches.
-        k_pos = jnp.concatenate([_ring_positions(start - 1, window), pos])
-        o = layer.attend(q[0], jnp.concatenate([ring["wk"], new["wk"]]),
-                         jnp.concatenate([ring["wv"], new["wv"]]),
-                         layer.sees(pos, k_pos))
-        # What the chunk leaves behind: each ring row's newest position up
-        # to the chunk's last valid one, from the chunk where it is there.
-        held = _ring_positions(length - 1, window)
-        src = jnp.clip(held - start, 0, pad - 1)
-        for n in ("wk", "wv"):
-            pool[n] = pool[n].at[idx, slot].set(
-                jnp.where((held >= start)[:, None], new[n][src], ring[n]))
-        return layer.output(p, x, o[None])
+    q, k, v = layer.project(p, x, pos)
+    ring = {n: pool[n][idx, slot] for n in ("wk", "wv")}
+    new = {"wk": k[0].astype(ring["wk"].dtype),
+           "wv": v[0].astype(ring["wv"].dtype)}
+    # The ring as earlier chunks left it, then the chunk's own rows: a
+    # padded row keeps its position, which no valid query reaches.
+    k_pos = jnp.concatenate([_ring_positions(start - 1, window), pos])
+    o = layer.attend(q[0], jnp.concatenate([ring["wk"], new["wk"]]),
+                     jnp.concatenate([ring["wv"], new["wv"]]),
+                     layer.sees(pos, k_pos))
+    # What the chunk leaves behind: each ring row's newest position up
+    # to the chunk's last valid one, from the chunk where it is there.
+    held = _ring_positions(length - 1, window)
+    src = jnp.clip(held - start, 0, pad - 1)
+    for n in ("wk", "wv"):
+        pool[n] = pool[n].at[idx, slot].set(
+            jnp.where((held >= start)[:, None], new[n][src], ring[n]))
+    return layer.output(p, x, o[None])
 
 
 def _window_decode(op, params, pool, x, pos, active):
     _, layer, path, idx = op
     p = _params_at(params, path)
     window, b = pool["wk"].shape[2], x.shape[0]
-    with jax.named_scope("tpu_dist.gqa.window"):
-        q, k, v = layer.project(p, x, pos[:, None])
-        # A slot that is not decoding (empty, or mid-prefill with a real
-        # ring) writes nowhere: a row behind the ring is dropped.
-        at = pos % window
-        if active is not None:
-            at = jnp.where(active, at, window)
-        for name, new in (("wk", k), ("wv", v)):
-            pool[name] = pool[name].at[idx, jnp.arange(b), at].set(
-                new[:, 0].astype(pool[name].dtype), mode="drop")
-        held = _ring_positions(pos, window)                  # [b, window]
-        o = layer.attend(q, pool["wk"][idx, :b], pool["wv"][idx, :b],
-                         layer.sees(pos[:, None], held))
-        return layer.output(p, x, o)
+    q, k, v = layer.project(p, x, pos[:, None])
+    # A slot that is not decoding (empty, or mid-prefill with a real
+    # ring) writes nowhere: a row behind the ring is dropped.
+    at = pos % window
+    if active is not None:
+        at = jnp.where(active, at, window)
+    for name, new in (("wk", k), ("wv", v)):
+        pool[name] = pool[name].at[idx, jnp.arange(b), at].set(
+            new[:, 0].astype(pool[name].dtype), mode="drop")
+    held = _ring_positions(pos, window)                  # [b, window]
+    o = layer.attend(q, pool["wk"][idx, :b], pool["wv"][idx, :b],
+                     layer.sees(pos[:, None], held))
+    return layer.output(p, x, o)
 
 
 def _keys_attended(plan: DecodePlan, pos, active):
@@ -1190,62 +1200,65 @@ def paged_prefill(plan: DecodePlan, params, pool: dict, page_row, tokens,
     residuals: list = []
     for op in plan.ops:
         tag = op[0]
-        if tag == "res_start":
-            residuals.append(x)
-        elif tag == "res_end":
-            x = _activation(op[1])(residuals.pop() + x)
-        elif tag == "pos":
-            x = _learned_positions(params, op[2], x, pos)
-        elif tag == "latent":
-            x = _latent_prefill(op, params, pool, page_row, x, pos, valid_q)
-        elif tag == "state":
-            x = _state_prefill(op, params, pool, slot, x, start, valid_q)
-        elif tag == "gqa":
-            x = _gqa_prefill(op, params, pool, page_row, x, pos, valid_q,
-                             length)
-        elif tag == "window":
-            x = _window_prefill(op, params, pool, slot, x, pos, start, length)
-        elif tag == "moe":
-            _, layer, path = op
-            with jax.named_scope("tpu_dist.moe"):
+        with _scope(plan, op):
+            if tag == "res_start":
+                residuals.append(x)
+            elif tag == "res_end":
+                x = _activation(op[1])(residuals.pop() + x)
+            elif tag == "pos":
+                x = _learned_positions(params, op[2], x, pos)
+            elif tag == "latent":
+                x = _latent_prefill(op, params, pool, page_row, x, pos,
+                                    valid_q)
+            elif tag == "state":
+                x = _state_prefill(op, params, pool, slot, x, start, valid_q)
+            elif tag == "gqa":
+                x = _gqa_prefill(op, params, pool, page_row, x, pos, valid_q,
+                                 length)
+            elif tag == "window":
+                x = _window_prefill(op, params, pool, slot, x, pos, start,
+                                    length)
+            elif tag == "moe":
+                _, layer, path = op
                 x, _ = layer.forward(_params_at(params, path), x, valid_q)
-        elif tag == "attn":
-            _, layer, path, idx = op
-            p = _params_at(params, path)
-            q, k, v = _qkv(layer, p, x)    # [1, H, pad, dk]
-            # Scatter each suffix position into (its page, its offset);
-            # padding positions are routed to the scratch page.
-            pg = jnp.where(
-                valid_q,
-                page_row[jnp.minimum(pos // ps, max_pages - 1)],
-                num_pages)                 # [pad]
-            off = pos % ps
-            err = _write_rows(pool, idx, pg, off,
-                              jnp.moveaxis(k[0], 1, 0),      # [pad, H, dk]
-                              jnp.moveaxis(v[0], 1, 0))
-            if err is not None:
-                qerr = jnp.maximum(
-                    qerr, jnp.max(jnp.where(valid_q, err, 0.0)))
-            keys = _gather_kv(pool, "k", idx, page_row,
-                              plan.num_heads)               # [H, S, dk]
-            vals = _gather_kv(pool, "v", idx, page_row, plan.num_heads)
-            scale = 1.0 / math.sqrt(layer.key_dim)
-            s = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
-                           keys.astype(jnp.float32)) * scale
-            # Key j is position j: <= the query's own absolute position
-            # covers both causality and prefix validity in one mask.
-            mask = key_pos[None, :] <= pos[:, None]         # [pad, S]
-            s = jnp.where(mask[None], s, -jnp.inf)
-            prob = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("hqk,hkd->hqd", prob,
-                             vals.astype(jnp.float32))
-            x = _attn_out(layer, p, out.astype(q.dtype)[None])
-        else:  # "embed" / "point"
-            _, layer, path = op
-            x, _ = layer.apply(_params_at(params, path), {}, x)
+            elif tag == "attn":
+                _, layer, path, idx = op
+                p = _params_at(params, path)
+                q, k, v = _qkv(layer, p, x)    # [1, H, pad, dk]
+                # Scatter each suffix position into (its page, its offset);
+                # padding positions are routed to the scratch page.
+                pg = jnp.where(
+                    valid_q,
+                    page_row[jnp.minimum(pos // ps, max_pages - 1)],
+                    num_pages)                 # [pad]
+                off = pos % ps
+                err = _write_rows(pool, idx, pg, off,
+                                  jnp.moveaxis(k[0], 1, 0),      # [pad, H, dk]
+                                  jnp.moveaxis(v[0], 1, 0))
+                if err is not None:
+                    qerr = jnp.maximum(
+                        qerr, jnp.max(jnp.where(valid_q, err, 0.0)))
+                keys = _gather_kv(pool, "k", idx, page_row,
+                                  plan.num_heads)               # [H, S, dk]
+                vals = _gather_kv(pool, "v", idx, page_row, plan.num_heads)
+                scale = 1.0 / math.sqrt(layer.key_dim)
+                s = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
+                               keys.astype(jnp.float32)) * scale
+                # Key j is position j: <= the query's own absolute position
+                # covers both causality and prefix validity in one mask.
+                mask = key_pos[None, :] <= pos[:, None]         # [pad, S]
+                s = jnp.where(mask[None], s, -jnp.inf)
+                prob = jax.nn.softmax(s, axis=-1)
+                out = jnp.einsum("hqk,hkd->hqd", prob,
+                                 vals.astype(jnp.float32))
+                x = _attn_out(layer, p, out.astype(q.dtype)[None])
+            else:  # "embed" / "point"
+                _, layer, path = op
+                x, _ = layer.apply(_params_at(params, path), {}, x)
     # x: [1, pad, vocab]; last valid suffix position is suffix - 1.
-    last = jax.lax.dynamic_slice(
-        x, (0, jnp.maximum(suffix - 1, 0), 0), (1, 1, plan.vocab_size))
+    with profiler.layer_scope("head"):
+        last = jax.lax.dynamic_slice(
+            x, (0, jnp.maximum(suffix - 1, 0), 0), (1, 1, plan.vocab_size))
     if _quantized(pool):
         return pool, last[0, 0], qerr
     return pool, last[0, 0]
@@ -1338,46 +1351,49 @@ def _paged_decode_core(plan: DecodePlan, params, pool: dict, tables,
     moe_stats: list = []
     for op in plan.ops:
         tag = op[0]
-        if tag == "res_start":
-            residuals.append(x)
-        elif tag == "res_end":
-            x = _activation(op[1])(residuals.pop() + x)
-        elif tag == "pos":
-            x = _learned_positions(params, op[2], x, pos)
-        elif tag == "latent":
-            x = _latent_decode(op, params, pool, tables, x, pos, active)
-        elif tag == "state":
-            x = _state_decode(op, params, pool, x, active)
-        elif tag == "gqa":
-            x = _gqa_decode(op, params, pool, tables, x, pos, active, n_keys,
-                            walk)
-        elif tag == "window":
-            x = _window_decode(op, params, pool, x, pos, active)
-        elif tag == "moe":
-            _, layer, path = op
-            with jax.named_scope("tpu_dist.moe"):
+        with _scope(plan, op):
+            if tag == "res_start":
+                residuals.append(x)
+            elif tag == "res_end":
+                x = _activation(op[1])(residuals.pop() + x)
+            elif tag == "pos":
+                x = _learned_positions(params, op[2], x, pos)
+            elif tag == "latent":
+                x = _latent_decode(op, params, pool, tables, x, pos, active)
+            elif tag == "state":
+                x = _state_decode(op, params, pool, x, active)
+            elif tag == "gqa":
+                x = _gqa_decode(op, params, pool, tables, x, pos, active,
+                                n_keys, walk)
+            elif tag == "window":
+                x = _window_decode(op, params, pool, x, pos, active)
+            elif tag == "moe":
+                _, layer, path = op
                 x, stats = layer.forward(_params_at(params, path), x, active)
-            moe_stats.append(stats)
-        elif tag == "attn":
-            _, layer, path, idx = op
-            p = _params_at(params, path)
-            q, k, v = _qkv(layer, p, x)    # [b, H, 1, dk]
-            # Tail-page append: clamping the page-table column keeps the
-            # gather in range.
-            pg = tables[rows, jnp.minimum(pos // ps, max_pages - 1)]  # [b]
-            if active is not None:
-                pg = jnp.where(active, pg, num_pages)
-            _write_rows(pool, idx, pg, pos % ps, k[:, :, 0, :], v[:, :, 0, :])
-            if walk:
-                out = _walked_attention(pool, jnp.int32(idx), tables, q,
-                                        n_keys)
-            else:
-                out = _gathered_attention(plan, pool, idx, tables, q, n_keys)
-            x = _attn_out(layer, p, out)
-        else:  # "embed" / "point"
-            _, layer, path = op
-            x, _ = layer.apply(_params_at(params, path), {}, x)
-    logits = x[:, 0, :].astype(jnp.float32)      # [b, vocab]
+                moe_stats.append(stats)
+            elif tag == "attn":
+                _, layer, path, idx = op
+                p = _params_at(params, path)
+                q, k, v = _qkv(layer, p, x)    # [b, H, 1, dk]
+                # Tail-page append: clamping the page-table column keeps the
+                # gather in range.
+                pg = tables[rows, jnp.minimum(pos // ps, max_pages - 1)]  # [b]
+                if active is not None:
+                    pg = jnp.where(active, pg, num_pages)
+                _write_rows(pool, idx, pg, pos % ps, k[:, :, 0, :],
+                            v[:, :, 0, :])
+                if walk:
+                    out = _walked_attention(pool, jnp.int32(idx), tables, q,
+                                            n_keys)
+                else:
+                    out = _gathered_attention(plan, pool, idx, tables, q,
+                                              n_keys)
+                x = _attn_out(layer, p, out)
+            else:  # "embed" / "point"
+                _, layer, path = op
+                x, _ = layer.apply(_params_at(params, path), {}, x)
+    with profiler.layer_scope("head"):
+        logits = x[:, 0, :].astype(jnp.float32)  # [b, vocab]
     counts = None
     if moe_stats:
         # Summed over the expert layers; the fullest expert is a maximum.

@@ -325,7 +325,9 @@ class Trainer:
 
             (loss, (logits, new_state)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with profiler.layer_scope("optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
             new_metrics = tuple(
                 m.update(ms, logits, y) for m, ms in zip(metrics, metric_states))
             # Device-side epoch-loss accumulator — the epoch 'loss' reported to
@@ -340,7 +342,9 @@ class Trainer:
             # guard reuses the SAME compiled program as an unarmed fit.
             from tpu_dist.training.integrity import health_summary
 
-            health = health_summary(loss, grads, params, new_params)
+            # XLA fuses the health sums with the update: one scope.
+            with profiler.layer_scope("optimizer"):
+                health = health_summary(loss, grads, params, new_params)
             return (loss, new_params, new_state, new_opt, new_metrics,
                     new_acc, health)
 
@@ -419,14 +423,17 @@ class Trainer:
             if device_transform is not None:
                 x = device_transform(x)
             loss, grads, logits, new_state = sharded(params, state, x, y, rng)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with profiler.layer_scope("optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
             new_metrics = tuple(
                 m.update(ms, logits, y)
                 for m, ms in zip(metrics, metric_states))
             new_acc = (loss_acc[0] + loss, loss_acc[1] + 1.0)
             from tpu_dist.training.integrity import health_summary
 
-            health = health_summary(loss, grads, params, new_params)
+            with profiler.layer_scope("optimizer"):
+                health = health_summary(loss, grads, params, new_params)
             return (loss, new_params, new_state, new_opt, new_metrics,
                     new_acc, health)
 

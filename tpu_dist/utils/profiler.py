@@ -34,8 +34,39 @@ _ACTIVE = False
 TRACE_PREFIX = "tpu_dist."
 
 
+#: The device's layer scopes, by the kind of layer (a decode plan op's
+#: tag, a layer's ``scope_kind``): every instruction of a layer's work
+#: carries ``tpu_dist.<scope>`` in its name stack, where the trace reads
+#: it (``jit(serve_prefill_chunk)/tpu_dist.moe/dot_general``).
+LAYER_SCOPES = {
+    "attn": "attn", "latent": "mla", "state": "kda", "gqa": "gqa.full",
+    "window": "gqa.window", "moe": "moe", "embed": "embed", "pos": "embed",
+    "point": "dense", "head": "head", "optimizer": "optimizer"}
+
+
+def layer_scope(kind: Optional[str]):
+    """``jax.named_scope`` of one layer kind of :data:`LAYER_SCOPES` (a
+    null context for ``None``: a container, whose layers carry their own).
+    Metadata only: the lowered program is the same text."""
+    if kind is None:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.named_scope(TRACE_PREFIX + LAYER_SCOPES[kind])
+
+
 def is_active() -> bool:
     return _ACTIVE
+
+
+def _profiler_records() -> bool:
+    """A ``jax.profiler`` session records: a :func:`trace`, or one its
+    caller started with ``jax.profiler.start_trace``."""
+    if _ACTIVE:
+        return True
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation.is_enabled()
 
 
 @contextlib.contextmanager
@@ -70,8 +101,8 @@ def trace(logdir: str | os.PathLike, *, chief_only: bool = True) -> Iterator[Non
 def step_annotation(step: int):
     """Context manager annotating one train step in the trace timeline.
 
-    Free when no trace is active (returns a null context)."""
-    if not _ACTIVE:
+    Free when no profiler session records (returns a null context)."""
+    if not _profiler_records():
         return contextlib.nullcontext()
     import jax
 
@@ -146,15 +177,16 @@ class _Span:
 def span(name: str, ident=None):
     """Context manager around one phase of the host's work.
 
-    While the default registry is disabled and no :func:`trace` is open it
-    returns :data:`NULL_SPAN`. Otherwise the phase lies on the host line of
+    While the default registry is disabled and no profiler session
+    records (:func:`trace`, ``jax.profiler.start_trace``) it returns
+    :data:`NULL_SPAN`. Otherwise the phase lies on the host line of
     the profiler's trace as ``tpu_dist.<name>`` and, where the registry is
     enabled, its wall time is observed as ``span.<name>.s`` and appended to
     the registry's span ring with its parent (the enclosing span of this
     thread) and ``ident`` (the round, the step or the request the spans of
     one piece of work share). ``seconds`` holds the duration after exit
     (0.0 on the null span)."""
-    if not (metrics.enabled() or _ACTIVE):
+    if not (metrics.enabled() or _profiler_records()):
         return NULL_SPAN
     return _Span(name, ident, metrics.get_registry())
 
